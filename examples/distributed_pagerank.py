@@ -18,6 +18,7 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 from repro.core import power_method  # noqa: E402
 from repro.core.batch import ita_batch, one_hot_personalizations  # noqa: E402
@@ -27,6 +28,12 @@ from repro.core.distributed import (  # noqa: E402
     ita_distributed_2d,
 )
 from repro.graph import paper_dataset  # noqa: E402
+
+
+def auto_mesh(shape, axes):
+    # Auto axes: the solvers rely on sharding propagation, which the
+    # Explicit axes that jax.make_mesh defaults to refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def main(argv=None):
@@ -46,14 +53,14 @@ def main(argv=None):
 
     pi_ref = power_method(g, tol=1e-13, max_iter=300).pi
 
-    mesh1 = jax.make_mesh((n_dev,), ("data",))
+    mesh1 = auto_mesh((n_dev,), ("data",))
     r1 = ita_distributed_1d(g, mesh1, xi=xi)
     print(f"1-D: iters={r1.iterations} "
           f"err={float(jnp.max(jnp.abs(r1.pi - pi_ref))):.2e}")
 
     if n_dev >= 2:
         rows = max(2, n_dev // 2)
-        mesh2 = jax.make_mesh((rows, n_dev // rows), ("data", "model"))
+        mesh2 = auto_mesh((rows, n_dev // rows), ("data", "model"))
         r2 = ita_distributed_2d(g, mesh2, xi=xi)
         print(f"2-D ({rows}x{n_dev//rows}): iters={r2.iterations} "
               f"err={float(jnp.max(jnp.abs(r2.pi - pi_ref))):.2e}")
@@ -62,13 +69,13 @@ def main(argv=None):
     seeds = [1, 5, 11, 17, 23, 29]
     P = one_hot_personalizations(g, seeds)
     ref_b = ita_batch(g, P, xi=xi)
-    mesh_b = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh_b = auto_mesh((n_dev, 1), ("data", "model"))
     rb = ita_batch_distributed(g, P, mesh_b, xi=xi)
     bitwise = bool(jnp.array_equal(ref_b.pi, rb.pi))
     print(f"batched PPR ({n_dev}x1, B={len(seeds)}): iters={rb.iterations} "
           f"bit-identical={bitwise}")
     if n_dev >= 2:
-        mesh_bc = jax.make_mesh((n_dev // 2, 2), ("data", "model"))
+        mesh_bc = auto_mesh((n_dev // 2, 2), ("data", "model"))
         rb2 = ita_batch_distributed(g, P, mesh_bc, xi=xi)
         err = float(jnp.max(jnp.abs(ref_b.pi - rb2.pi)))
         print(f"batched PPR ({n_dev//2}x2, vertex-sharded): "

@@ -9,17 +9,19 @@ backends so the solvers pick a layout/schedule without changing numerics
 (the paper's §IV commutativity result is exactly the licence to do this —
 same commutative sum, different grouping):
 
-  * ``"dense"``    — masked SpMV over all m COO edges via sorted
-                     ``segment_sum`` (paper-faithful synchronous baseline).
+  * ``"dense"``    — masked SpMV over all m dst-sorted COO edges, summed
+                     per vertex by a segmented scan (paper-faithful
+                     synchronous baseline).
   * ``"frontier"`` — active-set compression: each round gathers only the
                      out-edges of currently-active vertices into a
                      power-of-two-padded bucket, so the per-iteration edge
                      working set shrinks with the frontier.  Host-driven
                      (data-dependent shapes), bounded recompiles.
   * ``"ell"``      — bucketed-ELL layout driven by the Pallas kernel
-                     ``repro.kernels.spmv_ell`` (interpret-mode on CPU,
-                     compiled Mosaic on TPU).  Conversion is cached on the
-                     :class:`Graph` via ``Graph.ell()``.
+                     ``repro.kernels.spmv_ell`` (interpret-mode on CPU;
+                     Mosaic refuses it on TPU, see ``refused_on``).
+                     Conversion is cached on the :class:`Graph` via
+                     ``Graph.ell()``.
   * ``"frontier_priority"`` — the frontier machinery with the D-Iteration
                      descending-residual emission order (arXiv 1501.06350)
                      and a declared cost discount on undirected graphs
@@ -58,7 +60,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -177,6 +179,14 @@ class SolverBackend:
         """Per-graph context (pytree), built once outside the loop."""
         return None
 
+    def refused_on(self, platform: str) -> Optional[str]:
+        """Why this backend's push cannot compile on ``platform``, or None.
+
+        ``choose_backend`` drops a refused backend from the "auto" pool and
+        :func:`resolve_step_impl` raises on an explicit request for it.
+        """
+        return None
+
     def push(self, g: Graph, ctx, w: jnp.ndarray) -> jnp.ndarray:
         raise NotImplementedError
 
@@ -282,11 +292,9 @@ def choose_backend(stats: Optional[dict] = None, cfg=None, *,
     ``"undirected"`` — ``Graph.is_undirected`` — which backends declaring
     an ``undirected_cost_factor`` fold into their estimate; when such a
     backend wins on a symmetric edge set the reason names the
-    undirected-schedule rule).  This
-    replaces the hard-coded platform switch: on TPU the Mosaic ELL
-    kernel's declared cost undercuts dense, elsewhere the interpret-mode
-    penalty keeps dense cheapest — same answers, but now derived from
-    declarations a new backend can participate in.
+    undirected-schedule rule).  Backends refused on the deciding platform
+    (:meth:`SolverBackend.refused_on`: the ELL kernel on TPU) never enter
+    the pool.
 
     When the process-wide roofline cost table
     (``repro.roofline.planner_costs``) holds a measured sample for EVERY
@@ -296,6 +304,7 @@ def choose_backend(stats: Optional[dict] = None, cfg=None, *,
     measured seconds with declared units would compare incommensurable
     numbers).  See docs/ROOFLINE.md.
     """
+    platform = (stats or {}).get("platform") or jax.default_backend()
     cands = []
     for name, b in STEP_IMPLS.items():
         caps = b.capabilities()
@@ -303,26 +312,23 @@ def choose_backend(stats: Optional[dict] = None, cfg=None, *,
             continue
         if any(not getattr(caps, r) for r in require):
             continue
+        if b.refused_on(platform) is not None:
+            continue
         cands.append((b.cost(stats, cfg), 0 if name == "dense" else 1, name))
     if not cands:
         raise RuntimeError(
             "no eligible backend registered"
             + (f" (require={list(require)})" if require else ""))
-    platform = (stats or {}).get("platform") or jax.default_backend()
     mesh = (stats or {}).get("mesh")
     undirected = bool((stats or {}).get("undirected"))
     suffix = (f"platform={platform}"
               + (f"; mesh={tuple(mesh)}" if mesh else "")
               + ("; undirected=True" if undirected else "")
               + (f"; require={list(require)}" if require else "") + ")")
-    measured = None
-    try:
-        from ..roofline.planner_costs import rank_measured
-        measured = rank_measured([n for _, _, n in cands], stats, cfg)
-    except Exception:
-        # the planner must keep planning on any roofline-layer failure —
-        # a broken/stale table degrades to the declared constants.
-        measured = None
+    from ..roofline.planner_costs import rank_measured
+
+    # None when the table is empty or misses a candidate: declared costs.
+    measured = rank_measured([n for _, _, n in cands], stats, cfg)
     if measured is not None:
         m_cands = [(measured[n], 0 if n == "dense" else 1, n)
                    for _, _, n in cands]
@@ -346,39 +352,97 @@ def choose_backend(stats: Optional[dict] = None, cfg=None, *,
 def resolve_step_impl(name: Optional[str]) -> str:
     """Map ``None``/"auto" to the cost-chosen default, else validate ``name``.
 
-    The bucketed-ELL Pallas kernel compiles to Mosaic on TPU — that is
-    where its layout pays; everywhere else it runs interpret-mode
-    (Python-slow), so the sorted-segment-sum dense pass wins the cost
-    comparison (see :func:`choose_backend`).
+    An explicit backend that declares itself refused on this platform
+    (:meth:`SolverBackend.refused_on`) raises ``ValueError`` here, before
+    any graph is prepared for it.
     """
     if name is None or name == "auto":
         return choose_backend()[0]
-    get_step_impl(name)  # raise KeyError early for unknown names
+    refusal = get_step_impl(name).refused_on(jax.default_backend())
+    if refusal is not None:
+        raise ValueError(f"step_impl={name!r} cannot run on "
+                         f"{jax.default_backend()}: {refusal}")
     return name
 
 
 # ---------------------------------------------------------------------------
 # Backends
 # ---------------------------------------------------------------------------
+class DenseRuns(NamedTuple):
+    """Where each vertex's in-edges sit in the dst-sorted edge list.
+
+    ``start[e]`` marks the first edge of a run of equal ``dst``;
+    ``last[v]`` is the position of vertex v's last in-edge, -1 when it has
+    none.  The dense backend's per-graph context.
+    """
+
+    start: jnp.ndarray  # bool[m]
+    last: jnp.ndarray   # int32[n]
+
+
+def _dense_runs(g: Graph) -> DenseRuns:
+    """Host-side :class:`DenseRuns` of a concrete graph."""
+    dst = np.asarray(g.dst)
+    start = np.ones(dst.shape, bool)
+    start[1:] = dst[1:] != dst[:-1]
+    in_deg = np.asarray(g.in_deg, np.int64)
+    last = np.where(in_deg > 0, np.cumsum(in_deg) - 1, -1)
+    return DenseRuns(start=jnp.asarray(start),
+                     last=jnp.asarray(last.astype(np.int32)))
+
+
+def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
+    """Sum ``vals`` (edges on the last axis) over each vertex's run.
+
+    A segmented Hillis-Steele scan: log2(m) passes of shift-and-add
+    along the edge axis, then one gather at each run's last edge.  It
+    reads the edge axis log2(m) times but issues no scatter.
+    """
+    x, f = vals, runs.start
+    lead = [(0, 0)] * (x.ndim - 1)
+    k = 1
+    while k < x.shape[-1]:
+        x = jnp.where(f, x, x + jnp.pad(x[..., :-k], lead + [(k, 0)]))
+        f = f | jnp.pad(f[:-k], (k, 0), constant_values=True)
+        k *= 2
+    if x.shape[-1] == 0:
+        return jnp.zeros(vals.shape[:-1] + runs.last.shape, vals.dtype)
+    return jnp.where(runs.last >= 0, x[..., jnp.maximum(runs.last, 0)], 0)
+
+
 @register_step_impl("dense")
 class DenseBackend(StepBackend):
-    """Sorted segment-sum over the full dst-sorted COO edge list."""
+    """Sorted segment-sum over the full dst-sorted COO edge list.
+
+    The segment-sum is a segmented scan over each vertex's run of
+    in-edges (:func:`_run_sums`), not a scatter-add: XLA's float64
+    scatter-add on a TPU v5e takes about 0.58 s per push of web-Google's
+    5.06M edges, the scan about 0.09 s.  ``ctx`` is the graph's
+    :class:`DenseRuns`; a push called with ``ctx=None`` builds it on the
+    host from the (concrete) graph.
+
+    In float64 both agree with a numpy sum to 1e-12 relative on a v5e.
+    In float32 there, a ``[16, n]`` push_batch at web-Google size
+    returned a few sums too large by up to 9 in 3e3, though one vector
+    and ``[2, n]`` were right (PERF.md, "Findings"; cause open).
+    """
 
     # the paper-faithful C>1 column-sharded schedule (partition_cols
     # COO blocks + segment-sum, core/distributed.py), hence
     # vertex_sharded_mesh.
     capabilities_decl = BackendCapabilities(vertex_sharded_mesh=True)
 
+    def prepare(self, g: Graph) -> DenseRuns:
+        return _dense_runs(g)
+
     def push(self, g: Graph, ctx, w: jnp.ndarray) -> jnp.ndarray:
-        return jax.ops.segment_sum(w[g.src], g.dst, num_segments=g.n,
-                                   indices_are_sorted=True)
+        return _run_sums(w[g.src], ctx if ctx is not None else _dense_runs(g))
 
     def push_batch(self, g: Graph, ctx, W: jnp.ndarray) -> jnp.ndarray:
-        # one gather + one segment-sum over the trailing axis beats B
-        # separate scans: the edge index stream is read once per batch.
-        contrib = W[:, g.src]                                   # [B, m]
-        return jax.ops.segment_sum(contrib.T, g.dst, num_segments=g.n,
-                                   indices_are_sorted=True).T   # [B, n]
+        # one gather + one scan over the trailing axis beats B separate
+        # scans: the edge index stream is read once per batch.
+        return _run_sums(W[:, g.src],
+                         ctx if ctx is not None else _dense_runs(g))  # [B, n]
 
 
 @register_step_impl("ell")
@@ -390,24 +454,20 @@ class EllBackend(StepBackend):
     # core/distributed.py — so the layout serves every mesh shape.
     capabilities_decl = BackendCapabilities(vertex_sharded_mesh=True)
 
+    def refused_on(self, platform: str) -> Optional[str]:
+        from ..kernels.spmv_ell.kernel import TPU_REFUSAL
+        return TPU_REFUSAL if platform == "tpu" else None
+
     def cost(self, stats: Optional[dict] = None, cfg=None) -> float:
-        # Mosaic-compiled tiles undercut the gather+segment-sum per edge;
-        # off-TPU the kernel runs interpret-mode (Python-slow) — a large
-        # declared penalty keeps "auto" away from it there.  On a C-way
-        # vertex-sharded mesh (stats carries the normalized (R, C)) the
-        # kernel factor is declared unconditionally: that layout exists
-        # for scale-out serving where the per-block tiles are streamed
-        # once per round for the whole batch shard, and the production
-        # target is the compiled kernel — a CPU host mesh is a CI
-        # simulation of it, so "auto" plans for the hardware the layout
-        # is for rather than the interpreter that fakes it.
+        # Only ranked off-TPU (refused_on), where the kernel runs
+        # interpret-mode (Python-slow): a large declared penalty keeps
+        # "auto" away from it on one device.  On a C-way vertex-sharded
+        # host mesh (stats carries the normalized (R, C)) the declared
+        # x0.35 keeps the sharded-ELL schedule on the CPU suite's "auto"
+        # path; no chip measurement backs it.
         mesh = (stats or {}).get("mesh")
         C = int(mesh[1]) if mesh is not None and len(tuple(mesh)) == 2 else 1
-        platform = (stats or {}).get("platform") or jax.default_backend()
-        if C > 1 or platform == "tpu":
-            factor = 0.35
-        else:
-            factor = 50.0
+        factor = 0.35 if C > 1 else 50.0
         return super().cost(stats, cfg) * factor
 
     def prepare(self, g: Graph):

@@ -83,6 +83,17 @@ def one_hot_personalizations(g: Graph, seeds, dtype=jnp.float64) -> jnp.ndarray:
     return jax.nn.one_hot(seeds, g.n, dtype=dtype)
 
 
+def normalize_rows(U: jnp.ndarray) -> jnp.ndarray:
+    """``U`` with each row scaled to sum to 1.
+
+    Each row is summed as its own 1-D array, so a row's answer does not
+    depend on how many rows share its batch: a TPU tiles a 2-D reduction
+    by the row count, and the (R, 1) mesh must match one device bit for
+    bit with a quarter of the rows per chip.
+    """
+    return U / jax.lax.map(jnp.sum, U)[:, None]
+
+
 def _batch_ita_step(backend, g, ctx, H, PiBar, c, xi, inv_deg, non_dangling):
     active = jnp.logical_and(H > xi, non_dangling[None, :])
     H_act = jnp.where(active, H, 0)
@@ -93,9 +104,7 @@ def _batch_ita_step(backend, g, ctx, H, PiBar, c, xi, inv_deg, non_dangling):
     return H, PiBar, n_active
 
 
-# static key is the backend instance, so re-registration invalidates traces
-@partial(jax.jit, static_argnames=("max_iter", "backend"))
-def _ita_batch_loop(g: Graph, ctx, H0, c, xi, max_iter: int, backend):
+def _ita_batch_loop_impl(g: Graph, ctx, H0, c, xi, max_iter: int, backend):
     inv_deg = g.inv_out_deg(H0.dtype)
     non_dangling = jnp.logical_not(g.dangling_mask)
 
@@ -112,6 +121,17 @@ def _ita_batch_loop(g: Graph, ctx, H0, c, xi, max_iter: int, backend):
     init = (H0, jnp.zeros_like(H0), jnp.asarray(1, jnp.int32),
             jnp.asarray(0, jnp.int32))
     return jax.lax.while_loop(cond, body, init)
+
+
+# static key is the backend instance, so re-registration invalidates traces
+_ita_batch_loop = jax.jit(_ita_batch_loop_impl,
+                          static_argnames=("max_iter", "backend"))
+# The engine's accelerator path: the same loop with the [B, n] information
+# buffer donated.  The graph and ctx are arguments, never closure constants,
+# so the compiled program holds no edge arrays.
+_ita_batch_loop_donated = jax.jit(_ita_batch_loop_impl,
+                                  static_argnames=("max_iter", "backend"),
+                                  donate_argnames=("H0",))
 
 
 def ita_batch(
@@ -165,9 +185,7 @@ def ita_batch(
             it += 1
             if int(n_active) == 0:
                 break
-    U = PiBar + H
-    Pi = U / jnp.sum(U, axis=1, keepdims=True)
-    Pi = jax.block_until_ready(Pi)
+    Pi = jax.block_until_ready(normalize_rows(PiBar + H))
     result = BatchSolverResult(
         pi=Pi, iterations=int(it), residual=float(xi),
         converged=bool(int(n_active) == 0), method=f"ita_batch[{step_impl}]",

@@ -65,8 +65,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..graph.partition import partition_1d, partition_2d, partition_cols
 from ..graph.structure import Graph
@@ -75,7 +75,7 @@ from .backends import (
     choose_backend,
     get_step_impl,
 )
-from .batch import BatchSolverResult, _batch_ita_step
+from .batch import BatchSolverResult, _batch_ita_step, normalize_rows
 from .metrics import SolverResult
 
 __all__ = ["ita_distributed_1d", "ita_distributed_2d", "build_pagerank_job",
@@ -136,7 +136,32 @@ def resolve_mesh(spec, *, batch_axis: str = "data",
                          f"{n_have} are available (set XLA_FLAGS="
                          f"--xla_force_host_platform_device_count=N for a "
                          f"simulated host mesh)")
-    return jax.make_mesh(shape, (batch_axis, col_axis))
+    # Auto axes: the solvers' post-loop slices and concatenations rely on
+    # sharding propagation, which Explicit axes (make_mesh's default) refuse.
+    return jax.make_mesh(shape, (batch_axis, col_axis),
+                         axis_types=(AxisType.Auto,) * 2)
+
+
+def _psum_scatter_rows(x, axis: str):
+    """``psum_scatter`` of ``x`` over ``axis`` along dim 0 (tiled).
+
+    XLA on TPU has no 64-bit reduce-scatter.  There a 64-bit ``x`` goes as
+    an all-to-all of its row blocks followed by a local sum: the same bytes
+    on the wire, the sum in full precision.  Every other platform and dtype
+    lowers the psum_scatter itself.
+    """
+    def scatter(v):
+        return jax.lax.psum_scatter(v, axis, scatter_dimension=0, tiled=True)
+
+    if jnp.dtype(x.dtype).itemsize < 8:
+        return scatter(x)
+
+    def exchange(v):
+        C = jax.lax.axis_size(axis)
+        blocks = v.reshape(C, v.shape[0] // C, *v.shape[1:])
+        return jnp.sum(jax.lax.all_to_all(blocks, axis, 0, 0), axis=0)
+
+    return jax.lax.platform_dependent(x, tpu=exchange, default=scatter)
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +189,7 @@ def ita_distributed_1d(g: Graph, mesh: Mesh, *, c: float = 0.85,
     @partial(shard_map, mesh=mesh,
              in_specs=(rep, rep, specs_edges, specs_edges, rep, rep),
              out_specs=(rep, rep, rep),
-             check_rep=False)
+             check_vma=False)
     def step(h, pi_bar, src_blk, dst_blk, inv_deg_a, nd_a):
         src_blk, dst_blk = src_blk[0], dst_blk[0]
         active = jnp.logical_and(h > xi, nd_a)
@@ -223,8 +248,7 @@ def make_ita_2d_step(mesh: Mesh, part_shapes: dict, c: float, xi: float,
         contrib = wp[src_blk]
         partial_r = jax.ops.segment_sum(contrib, dst_blk, num_segments=nr + 1)[:nr]
         # reduce over columns; each column keeps its sub-chunk of the row block
-        y_sub = jax.lax.psum_scatter(partial_r, col_axis, scatter_dimension=0,
-                                     tiled=True)                    # [sub]
+        y_sub = _psum_scatter_rows(partial_r, col_axis)             # [sub]
         # assemble this column's next block from all row groups
         h_new = jax.lax.all_gather(y_sub, row_axis, axis=0, tiled=True)  # [nc]
         h = jnp.where(active, 0, h) + h_new
@@ -236,7 +260,7 @@ def make_ita_2d_step(mesh: Mesh, part_shapes: dict, c: float, xi: float,
         step, mesh=mesh,
         in_specs=(col_spec, col_spec, edge_spec, edge_spec, col_spec, col_spec),
         out_specs=(col_spec, col_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -300,8 +324,7 @@ def _ita_batch_2d_body(nr: int, c: float, xi: float, batch_axis: str,
         partial_r = jax.ops.segment_sum(contrib.T, dst_e,
                                         num_segments=nr + 1)[:nr]  # [nr, B_loc]
         # reduce over columns; each column keeps its vertex block
-        Y = jax.lax.psum_scatter(partial_r, col_axis, scatter_dimension=0,
-                                 tiled=True)                   # [nc, B_loc]
+        Y = _psum_scatter_rows(partial_r, col_axis)            # [nc, B_loc]
         H = jnp.where(active, 0, H) + Y.T
         n_active = jax.lax.psum(jnp.sum(active, dtype=jnp.int32),
                                 (batch_axis, col_axis))
@@ -338,7 +361,7 @@ def make_ita_batch_step(mesh: Mesh, part_shapes: dict, c: float, xi: float,
         in_specs=(state_spec, state_spec, edge_spec, edge_spec, vec_spec,
                   vec_spec),
         out_specs=(state_spec, state_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -396,8 +419,7 @@ def _ita_batch_2d_ell_body(sig, c: float, xi: float, batch_axis: str,
         Wp = jnp.concatenate([W, jnp.zeros((W.shape[0], 1), W.dtype)], axis=1)
         partial_r = spmv_ell_cols_local_batch(
             Wp, buckets, ovf_src, ovf_dst, n_pad)          # [B_loc, n_pad]
-        Y = jax.lax.psum_scatter(partial_r.T, col_axis, scatter_dimension=0,
-                                 tiled=True)               # [nc, B_loc]
+        Y = _psum_scatter_rows(partial_r.T, col_axis)      # [nc, B_loc]
         H = jnp.where(active, 0, H) + Y.T
         n_active = jax.lax.psum(jnp.sum(active, dtype=jnp.int32),
                                 (batch_axis, col_axis))
@@ -426,7 +448,7 @@ def make_ita_batch_ell_step(mesh: Mesh, ellc, c: float, xi: float,
         in_specs=(state_spec, state_spec, vec_spec, vec_spec,
                   *_ell_spec_list(sig, col_axis)),
         out_specs=(state_spec, state_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -450,7 +472,11 @@ def _batch_dp_loop(mesh: Mesh, backend, c: float, xi: float, max_iter: int,
     state_spec = P(batch_axis, None)
     rep = P()
 
-    def local_loop(g, ctx, H0, inv_deg, nd):
+    def local_loop(g, ctx, H0):
+        # computed in the program, as core/batch._ita_batch_loop does
+        inv_deg = g.inv_out_deg(H0.dtype)
+        nd = jnp.logical_not(g.dangling_mask)
+
         def cond(state):
             _, _, n_active, it = state
             return jnp.logical_and(n_active > 0, it < max_iter)
@@ -467,10 +493,18 @@ def _batch_dp_loop(mesh: Mesh, backend, c: float, xi: float, max_iter: int,
 
     return jax.jit(shard_map(
         local_loop, mesh=mesh,
-        in_specs=(rep, rep, state_spec, rep, rep),
+        in_specs=(rep, rep, state_spec),
         out_specs=(state_spec, state_spec, rep, rep),
-        check_rep=False,
+        check_vma=False,
     ))
+
+
+@lru_cache(maxsize=None)
+def _normalize_local(mesh: Mesh, batch_axis: str):
+    """``normalize_rows`` on each device's rows of a P(batch_axis) batch."""
+    spec = P(batch_axis, None)
+    return jax.jit(shard_map(normalize_rows, mesh=mesh, in_specs=spec,
+                             out_specs=spec))
 
 
 @lru_cache(maxsize=None)
@@ -500,7 +534,7 @@ def _batch_2d_loop(mesh: Mesh, nr: int, c: float, xi: float, max_iter: int,
         local_loop, mesh=mesh,
         in_specs=(state_spec, edge_spec, edge_spec, vec_spec, vec_spec),
         out_specs=(state_spec, state_spec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -536,7 +570,7 @@ def _batch_2d_ell_loop(mesh: Mesh, sig, c: float, xi: float, max_iter: int,
         in_specs=(state_spec, vec_spec, vec_spec,
                   *_ell_spec_list(sig, col_axis)),
         out_specs=(state_spec, state_spec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -694,9 +728,7 @@ def ita_batch_distributed(
         run = _batch_dp_loop(mesh, backend, float(c), float(xi),
                              int(max_iter), batch_axis)
         H0 = jax.device_put(H0, NamedSharding(mesh, P(batch_axis, None)))
-        inv_deg = g.inv_out_deg(dtype)
-        nd = jnp.logical_not(g.dangling_mask)
-        H, PiBar, n_active, it = run(g, ctx, H0, inv_deg, nd)
+        H, PiBar, n_active, it = run(g, ctx, H0)
         method = f"ita_batch_dist[{step_impl}|{R}x1]"
     else:
         if step_impl in (None, "auto"):
@@ -740,9 +772,12 @@ def ita_batch_distributed(
         method = f"ita_batch_dist[{impl}|{R}x{C}]"
 
     it = int(it)
-    U = PiBar + H
-    Pi = U[:B, : g.n]
-    Pi = Pi / jnp.sum(Pi, axis=1, keepdims=True)
+    if C == 1:
+        # each device normalizes its own whole rows, as one device does
+        Pi = _normalize_local(mesh, batch_axis)(PiBar + H)[:B]
+    else:
+        # a row spans the C column blocks: normalized after the slice
+        Pi = normalize_rows((PiBar + H)[:B, : g.n])
     Pi = jax.block_until_ready(Pi)
     result = BatchSolverResult(
         pi=Pi, iterations=int(it), residual=float(xi),
@@ -798,7 +833,7 @@ def build_pagerank_job(spec, cell, mesh: Mesh):
                    in_specs=(col_spec, col_spec, edge_spec, edge_spec,
                              col_spec, col_spec),
                    out_specs=(col_spec, col_spec, P()),
-                   check_rep=False)
+                   check_vma=False)
 
     args = (
         jax.ShapeDtypeStruct((n_pad,), dtype),
@@ -870,7 +905,7 @@ def make_ita_2d_step_compressed(mesh: Mesh, part_shapes: dict, c: float,
         in_specs=(col_spec, col_spec, P(row_axis, col_axis), edge_spec,
                   edge_spec, col_spec, col_spec),
         out_specs=(col_spec, col_spec, P(row_axis, col_axis), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -67,8 +67,9 @@ from .backends import choose_backend, get_step_impl, resolve_step_impl
 from .cache import CachePolicy, ResultCache
 from .batch import (
     BatchSolverResult,
-    _ita_batch_loop,
+    _ita_batch_loop_donated,
     ita_batch,
+    normalize_rows,
     one_hot_personalizations,
     power_method_batch,
 )
@@ -150,7 +151,6 @@ class PageRankEngine:
         # (construction + each update), never per query.
         self.prepare_count = 0
         self._state = None        # (pi_bar, h) residual pair for DeltaQuery
-        self._compiled = {}       # static_key -> donated jitted solve
         self._donate = jax.default_backend() != "cpu"
         policy = self.engine_plan.cache
         if policy is True:
@@ -252,7 +252,6 @@ class PageRankEngine:
                 cache = getattr(g, attr, None)
                 if cache is not None:
                     object.__setattr__(self.graph, attr, cache)
-        self._compiled.clear()  # traces close over the old graph's buffers
         self.prepare_count += 1
 
     def describe(self, include_plan: bool = True) -> dict:
@@ -271,6 +270,8 @@ class PageRankEngine:
             jittable=self.caps.jittable,
             capabilities=self.caps.summary(),
             mesh=self._mesh_shape,
+            # ids of the devices holding the graph's edge arrays
+            devices=sorted(d.id for d in self.graph.src.devices()),
             prepare_count=self.prepare_count,
             has_residual_state=self._state is not None,
             graph_version=self.graph_version,
@@ -436,28 +437,18 @@ class PageRankEngine:
 
     def _solve_batch_donated(self, p_batch, cfg: BatchConfig,
                              return_state: bool = False):
-        """Accelerator path: per-engine compiled batched-ITA loop with the
-        [B, n] information buffer donated — the serving loop then updates
-        in place instead of allocating per micro-batch.  Numerics are the
-        shared ``_ita_batch_loop``, so results match ``ita_batch`` exactly.
+        """Accelerator path: the batched-ITA loop with the [B, n]
+        information buffer donated — the serving loop then updates in
+        place instead of allocating per micro-batch.  It runs the body of
+        ``ita_batch``'s loop with the same arguments, so results match
+        ``ita_batch`` bit for bit.
         """
-        key = ("ita_batch", cfg.static_key(), p_batch.shape)
-        fn = self._compiled.get(key)
-        if fn is None:
-            g, ctx, backend = self.graph, self._ctx, self.backend
-            c, xi, max_iter = float(cfg.c), float(cfg.xi), int(cfg.max_iter)
-
-            def run(H0):
-                return _ita_batch_loop(g, ctx, H0, c, xi, max_iter, backend)
-
-            fn = jax.jit(run, donate_argnums=(0,))
-            self._compiled[key] = fn
         t0 = time.perf_counter()
         H0 = (p_batch.astype(cfg.dtype) * self.graph.n).astype(cfg.dtype)
-        H, PiBar, n_active, it = fn(H0)
-        U = PiBar + H
-        Pi = U / jnp.sum(U, axis=1, keepdims=True)
-        Pi = jax.block_until_ready(Pi)
+        H, PiBar, n_active, it = _ita_batch_loop_donated(
+            self.graph, self._ctx, H0, float(cfg.c), float(cfg.xi),
+            int(cfg.max_iter), self.backend)
+        Pi = jax.block_until_ready(normalize_rows(PiBar + H))
         result = BatchSolverResult(
             pi=Pi, iterations=int(it), residual=float(cfg.xi),
             converged=bool(int(n_active) == 0),

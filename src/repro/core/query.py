@@ -272,20 +272,12 @@ def _price(backend_name: str, stats: dict, cfg, batch: int = 1) -> dict:
 
     Returns ``PlanCost.as_dict()`` — ``cost`` in declared edge-traversal
     units × batch, ``source`` "measured"/"declared", and the provenance
-    ``reason`` ``ExecutionPlan.explain()`` quotes.  Planning must survive
-    a broken measured-cost layer, so any failure there degrades to the
-    declared constants instead of raising.
+    ``reason`` ``ExecutionPlan.explain()`` quotes.  With no table, or no
+    sample for this backend, ``plan_cost`` prices by the declared
+    constants; any other failure there is a bug and raises.
     """
-    try:
-        from ..roofline.planner_costs import plan_cost
-        return plan_cost(backend_name, stats, cfg, batch=batch).as_dict()
-    except Exception:
-        from .backends import get_step_impl
-        cost = (get_step_impl(backend_name).cost(stats, cfg)
-                * max(1, int(batch)))
-        return dict(cost=cost, source="declared",
-                    reason="declared backend cost constants "
-                           "(measured-cost layer unavailable)")
+    from ..roofline.planner_costs import plan_cost
+    return plan_cost(backend_name, stats, cfg, batch=batch).as_dict()
 
 
 def _check_step_compat(state: PlannerState, cfg) -> None:

@@ -20,6 +20,10 @@ TPU mapping (DESIGN.md §2, kernel-level adaptation):
 Grid: 1-D over row blocks.  No cross-block accumulation — each dst row
 lives in exactly one bucket row, so blocks are independent (embarrassingly
 parallel, matching the paper's "completely parallel" property).
+
+Status on the chip: Mosaic refuses both kernels (``TPU_REFUSAL``;
+tests/test_tpu_compile.py holds that refusal as a strict xfail).  They run
+only in interpret mode, which every caller must ask for explicitly.
 """
 from __future__ import annotations
 
@@ -29,9 +33,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["spmv_ell_bucket", "spmv_ell_bucket_batch", "DEFAULT_BLOCK_ROWS"]
+__all__ = ["spmv_ell_bucket", "spmv_ell_bucket_batch", "DEFAULT_BLOCK_ROWS",
+           "TPU_REFUSAL"]
 
 DEFAULT_BLOCK_ROWS = 256
+
+TPU_REFUSAL = ("the bucketed-ELL Pallas kernel does not lower on TPU: Mosaic "
+               "raises 'Only 2D gather is supported' on its whole-vector "
+               "gather w[idx] (and has no 64-bit types); use step_impl="
+               "'dense' or 'auto'")
 
 
 def _spmv_ell_kernel(w_ref, idx_ref, out_ref):
@@ -50,7 +60,7 @@ def spmv_ell_bucket(
     src_idx: jnp.ndarray,    # int32[rows, k], rows % block_rows == 0 not required
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     rows, k = src_idx.shape
     block_rows = min(block_rows, rows)
@@ -91,7 +101,7 @@ def spmv_ell_bucket_batch(
     src_idx: jnp.ndarray,    # int32[rows, k]
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Multi-source variant: one index-tile stream serves B operand rows.
 

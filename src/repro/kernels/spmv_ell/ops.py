@@ -1,9 +1,9 @@
 """Jitted wrapper: full-graph ELL SpMV + the fused ITA step built on it.
 
-``use_pallas`` selects the Pallas path (interpret=True on CPU; compiled
-Mosaic on TPU).  The default follows the backend: Pallas kernels cannot be
+``interpret=None`` follows the backend: Pallas kernels cannot be
 *compiled* by the CPU backend, so CPU runs interpret the kernel body —
-correct but slow — while the dry-run / production path on TPU compiles it.
+correct but slow.  On TPU, Mosaic refuses the kernel (``TPU_REFUSAL``),
+so the default raises there instead of running anything.
 """
 from __future__ import annotations
 
@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from ...sparse.ell import ELLGraph
-from .kernel import spmv_ell_bucket, spmv_ell_bucket_batch
+from .kernel import TPU_REFUSAL, spmv_ell_bucket, spmv_ell_bucket_batch
 
 __all__ = ["DEFAULT_BLOCK_ROWS", "spmv_ell", "spmv_ell_batch",
            "spmv_ell_cols_local_batch", "ita_step_ell"]
@@ -26,7 +26,10 @@ DEFAULT_BLOCK_ROWS = 256
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
+    platform = jax.default_backend()
+    if platform == "tpu":
+        raise NotImplementedError(TPU_REFUSAL)
+    return platform == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

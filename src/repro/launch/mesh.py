@@ -20,7 +20,7 @@ from __future__ import annotations
 
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from .sharding import AxisRules
 
@@ -32,12 +32,12 @@ __all__ = ["make_production_mesh", "make_smoke_mesh", "lm_axis_rules",
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_smoke_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     """Tiny mesh for the in-suite distributed tests (8 host devices)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh: Mesh):

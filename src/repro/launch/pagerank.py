@@ -47,6 +47,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     jax.config.update("jax_enable_x64", True)
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
     from ..core import (
         BatchConfig,
         EnginePlan,
@@ -73,14 +75,18 @@ def main(argv=None) -> int:
     print(f"graph: {g.stats()}")
 
     if args.partition != "none":
+        from jax.sharding import AxisType
+
         from ..core.distributed import ita_distributed_1d, ita_distributed_2d
         n_dev = len(jax.devices())
         if args.partition == "1d":
-            mesh = jax.make_mesh((n_dev,), ("data",))
+            mesh = jax.make_mesh((n_dev,), ("data",),
+                                 axis_types=(AxisType.Auto,))
             r = ita_distributed_1d(g, mesh, c=args.c, xi=args.xi)
         else:
             rows = max(1, n_dev // 2)
-            mesh = jax.make_mesh((rows, n_dev // rows), ("data", "model"))
+            mesh = jax.make_mesh((rows, n_dev // rows), ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
             r = ita_distributed_2d(g, mesh, c=args.c, xi=args.xi)
         print(f"method={r.method} iterations={r.iterations} ops={r.ops:.3e} "
               f"wall={r.wall_time_s}s converged={r.converged}")

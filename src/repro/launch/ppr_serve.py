@@ -114,6 +114,8 @@ def main(argv=None) -> int:
         ap.error("--qps must be > 0 (omit it for the closed loop)")
 
     jax.config.update("jax_enable_x64", True)
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
     import numpy as np
 
     from ..core import (BatchConfig, CachePolicy, EnginePlan, PageRankEngine,

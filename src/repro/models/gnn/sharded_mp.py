@@ -36,7 +36,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..layers import mlp
@@ -145,7 +145,7 @@ def gc2d_loss(params, cfg: GraphCastConfig, geom: dict, mesh: Mesh, batch: dict)
         in_specs=(sub_spec, col_spec, row_spec, edge_spec, edge_spec,
                   sub_spec, sub_spec),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     loss = sm(batch["nodes_sub"], batch["pos_col"], batch["pos_row"],
               batch["src"], batch["dst"], batch["targets_sub"],

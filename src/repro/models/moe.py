@@ -178,7 +178,7 @@ def moe_apply_sharded(p: dict, x: jnp.ndarray, cfg: MoEConfig, ffn_type: str,
     size with never-routed dummy experts (router bias -inf), e.g. 40 -> 48
     for granite-moe on a 16-wide model axis (pad slots noted in DESIGN.md).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = rules.mesh
@@ -263,7 +263,7 @@ def moe_apply_sharded(p: dict, x: jnp.ndarray, cfg: MoEConfig, ffn_type: str,
                   (P("model", None, None) if w_gate is not None else P())),
         out_specs=(P(token_axes, None),
                    {"load_balance": P(), "router_z": P()}),
-        check_rep=False,
+        check_vma=False,
     )
     return sm(x, p["router"]["w"], pad_experts(p["w_up"]),
               pad_experts(p["w_down"]), w_gate)

@@ -2,7 +2,7 @@
 
 from .analysis import RooflineReport, analyze_compiled
 from .hlo_costs import HloCosts, parse_hlo_costs
-from .hw import HW, CPUHost, TPUv5e, spec_for_platform
+from .hw import HW, CPULowering, TPUv5e, device_kind, spec_for_device_kind
 from .planner_costs import (
     CostTable,
     PlanCost,
@@ -18,7 +18,7 @@ from .planner_costs import (
 
 __all__ = [
     "HW",
-    "CPUHost",
+    "CPULowering",
     "CostTable",
     "HloCosts",
     "PlanCost",
@@ -26,6 +26,7 @@ __all__ = [
     "StepCostSample",
     "TPUv5e",
     "analyze_compiled",
+    "device_kind",
     "get_cost_table",
     "measure_sharded_step",
     "measure_step",
@@ -34,5 +35,5 @@ __all__ = [
     "rank_measured",
     "roofline_seconds",
     "set_cost_table",
-    "spec_for_platform",
+    "spec_for_device_kind",
 ]

@@ -1,21 +1,28 @@
-"""Per-platform hardware specs — the roofline denominators.
+"""Per-device hardware specs — the roofline denominators, keyed by device kind.
 
-``TPUv5e`` is the dry-run's production target; ``CPUHost`` is a deliberately
-round model of the CI container (one NUMA-ish host with a loopback
-"interconnect" standing in for ICI on the simulated host mesh).  The CPU
-numbers are order-of-magnitude — they only have to rank backends and convert
-measured bytes/FLOPs into comparable seconds, not predict wall time.
+``SPECS`` is keyed by ``jax.devices()[0].device_kind``:
 
-``spec_for_platform`` maps a ``jax.default_backend()`` platform string onto
-a spec; the measured-cost layer (``roofline/planner_costs.py``) prices every
-sample through it.
+  * ``"TPU v5 lite"`` (TPU v5e) — published peaks of one chip (Google
+    Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 16 GB of HBM at
+    819 GB/s, 1,600 Gbit/s of interconnect over four ICI links.
+  * ``"cpu"`` — a CPU-lowering model for tests: round order-of-magnitude
+    numbers that only rank backends priced from CPU lowerings.  It
+    predicts no wall time.
+
+A device kind missing from the table is an error, never a default: a new
+chip gets its own row with its own source.  The measured-cost layer
+(``roofline/planner_costs.py``) prices every sample through
+:func:`spec_for_device_kind`.
 """
 
 from __future__ import annotations
 
 import dataclasses
 
-__all__ = ["TPUChip", "TPUv5e", "CPUHost", "HW", "SPECS", "spec_for_platform"]
+import jax
+
+__all__ = ["TPUChip", "TPUv5e", "CPULowering", "HW", "SPECS",
+           "device_kind", "spec_for_device_kind"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,8 +42,8 @@ TPUv5e = TPUChip(
     hbm_bytes=16e9,
 )
 
-CPUHost = TPUChip(
-    name="cpu-host",
+CPULowering = TPUChip(
+    name="cpu-lowering-model",
     peak_bf16_flops=1e12,
     hbm_bandwidth=100e9,
     ici_link_bandwidth=25e9,
@@ -45,9 +52,18 @@ CPUHost = TPUChip(
 
 HW = TPUv5e
 
-SPECS = {"tpu": TPUv5e, "cpu": CPUHost}
+SPECS = {"TPU v5 lite": TPUv5e, "cpu": CPULowering}
 
 
-def spec_for_platform(platform: str) -> TPUChip:
-    """Spec for a ``jax.default_backend()`` name; unknown platforms get CPUHost."""
-    return SPECS.get(str(platform), CPUHost)
+def device_kind() -> str:
+    """``device_kind`` of the first device JAX sees ("cpu" on the host)."""
+    return jax.devices()[0].device_kind
+
+
+def spec_for_device_kind(kind: str) -> TPUChip:
+    """Spec for a ``device_kind``; raises ``KeyError`` for an unknown kind."""
+    try:
+        return SPECS[str(kind)]
+    except KeyError:
+        raise KeyError(f"no hardware spec for device kind {kind!r}; "
+                       f"known: {sorted(SPECS)}") from None

@@ -7,11 +7,12 @@ concrete (graph stats, batch, mesh, dtype) point, FLOPs and bytes are read
 from ``compiled.cost_analysis()`` (the text parser in ``hlo_costs`` inflates
 CPU scatter loops, but it is the only source of collective bytes, which
 cost_analysis does not report), and the sample is priced in seconds against
-the per-platform spec in ``hw.py``.
+the per-device-kind spec in ``hw.py``.
 
-Samples live in a versioned :class:`CostTable` keyed by platform, persistable
-as JSON (``CostTable.save`` / ``CostTable.load``; ``REPRO_ROOFLINE_TABLE``
-names a table to auto-load).  Consumers:
+Samples live in a versioned :class:`CostTable` keyed by device kind (the
+``platform`` field holds ``jax.devices()[0].device_kind``, "cpu" on the
+host), persistable as JSON (``CostTable.save`` / ``CostTable.load``;
+``REPRO_ROOFLINE_TABLE`` names a table to auto-load).  Consumers:
 
   * ``choose_backend`` (core/backends.py) re-ranks candidates by measured
     seconds when — and only when — every candidate has a sample for the
@@ -40,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .hlo_costs import parse_hlo_costs
-from .hw import spec_for_platform
+from .hw import device_kind, spec_for_device_kind
 
 __all__ = [
     "TABLE_VERSION",
@@ -78,7 +79,7 @@ def roofline_seconds(
     platform: str,
 ) -> float:
     """Roofline time for one step: max(compute, memory) + interconnect."""
-    spec = spec_for_platform(platform)
+    spec = spec_for_device_kind(platform)
     compute_s = float(flops) / spec.peak_bf16_flops
     memory_s = float(bytes_accessed) / spec.hbm_bandwidth
     collective_s = float(collective_bytes) / spec.ici_link_bandwidth
@@ -166,13 +167,13 @@ def measure_step(
     (``_frontier_coo_push``) is lowered at the worst-case full-frontier
     shape instead, scaled by ``batch`` (its batch is sequential rows).
     The sample's platform is always the lowering platform
-    (``jax.default_backend()``); ``platform`` only overrides the label/
+    (``jax.devices()[0].device_kind``); ``platform`` only overrides the label/
     pricing spec for what-if tables and must be used knowingly.
     """
     from ..core.backends import get_step_impl
 
     backend = get_step_impl(backend_name)
-    platform = platform or jax.default_backend()
+    platform = platform or device_kind()
     dt = np.dtype(dtype).name
     batch = max(1, int(batch))
     if not backend.capabilities().jittable:
@@ -237,7 +238,7 @@ def sharded_round_step(
     the same lowering against docs/SHARDING.md, rule RL104).  Needs R*C
     live devices (``resolve_mesh`` raises otherwise).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..core.backends import get_step_impl
@@ -275,7 +276,7 @@ def sharded_round_step(
             mesh=mesh,
             in_specs=(P("data", None), P("data", None)),
             out_specs=(P("data", None), P("data", None), P()),
-            check_rep=False,
+            check_vma=False,
         )
         state = jax.ShapeDtypeStruct((B_pad, g.n), dt)
         args = (state, state)
@@ -318,7 +319,7 @@ def measure_sharded_step(
     device runs the backend's own ``push_batch``; docs table: collective
     "none" beyond the scalar n_active psum).
     """
-    platform = jax.default_backend()
+    platform = device_kind()
     dt = np.dtype(dtype).name
     step, args, (R, C, B_pad) = sharded_round_step(
         backend_name,
@@ -381,8 +382,10 @@ class CostTable:
         """Nearest matching sample, or None when the family has no point.
 
         Batched requests prefer "push_batch"/"sharded-round" samples but
-        fall back to a "push" point (scaled by B at estimate time); an
-        (R, C) mesh with C > 1 prefers "sharded-round" samples.
+        fall back to a "push" point, and a batch of one prefers "push" but
+        falls back to a "push_batch" point (either is scaled by B at
+        estimate time); an (R, C) mesh with C > 1 prefers "sharded-round"
+        samples.
         """
         dt = np.dtype(dtype).name
         C = int(mesh[1]) if mesh is not None and len(tuple(mesh)) == 2 else 1
@@ -391,7 +394,7 @@ class CostTable:
         elif batch > 1:
             preferred = ("push_batch", "push")
         else:
-            preferred = ("push",)
+            preferred = ("push", "push_batch")
         cands = [
             s
             for s in self.samples
@@ -428,7 +431,7 @@ class CostTable:
         """
         if not stats or "m" not in stats or "n" not in stats:
             return None
-        platform = platform or stats.get("platform") or jax.default_backend()
+        platform = platform or stats.get("platform") or device_kind()
         n, m = int(stats["n"]), int(stats["m"])
         dtype = str(stats.get("dtype", "float64"))
         mesh = stats.get("mesh")
@@ -554,7 +557,7 @@ def plan_cost(
 
     batch = max(1, int(batch))
     declared = get_step_impl(backend_name).cost(stats, cfg) * batch
-    platform = (stats or {}).get("platform") or jax.default_backend()
+    platform = (stats or {}).get("platform") or device_kind()
     table = table if table is not None else get_cost_table()
     est = table.estimate(backend_name, stats, cfg, batch=batch, platform=platform)
     if est is None:
@@ -603,7 +606,7 @@ def rank_measured(
     table = table if table is not None else get_cost_table()
     if not len(table):
         return None
-    platform = stats.get("platform") or jax.default_backend()
+    platform = stats.get("platform") or device_kind()
     out = {}
     for name in names:
         est = table.estimate(name, stats, cfg, batch=batch, platform=platform)

@@ -68,6 +68,10 @@ class WallClock(Clock):
     def __init__(self):
         self._t0 = time.perf_counter()
 
+    def restart(self) -> None:
+        """Zero the clock again: the served window starts now."""
+        self._t0 = time.perf_counter()
+
     def now(self) -> float:
         return time.perf_counter() - self._t0
 

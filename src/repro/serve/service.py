@@ -243,7 +243,9 @@ class PPRService:
     def calibrate(self, seeds=None) -> dict:
         """Run one warmup micro-batch (compile + measure) and seed the
         cost model with the observed seconds-per-unit.  Returns the
-        measurement; the CLI prints it as the compile/warmup line."""
+        measurement; the CLI prints it as the compile/warmup line.  A wall
+        clock restarts afterwards, so the workload's schedule (which starts
+        at 0) and its latencies leave the warmup out."""
         B = self.config.batch_size
         if seeds is None:
             seeds = np.zeros(B, dtype=np.int64)
@@ -260,6 +262,8 @@ class PPRService:
             self.cost_model.seconds_per_unit = wall / units
             self._calibrated = True
         spu = self.cost_model.seconds_per_unit
+        if isinstance(self.clock, WallClock):
+            self.clock.restart()
         return dict(warm_batch_s=wall, cost_units=units, seconds_per_unit=spu)
 
     # ------------------------------------------------------------------ #

@@ -26,7 +26,8 @@ from repro.core import (
     power_method_batch,
     solve_pagerank_batch,
 )
-from repro.core.backends import STEP_IMPLS, StepBackend, register_step_impl
+from repro.core.backends import (STEP_IMPLS, StepBackend, choose_backend,
+                                 register_step_impl)
 from repro.graph import graph_from_edges, web_graph
 
 ALL_IMPLS = available_step_impls()
@@ -100,6 +101,28 @@ class TestPushContract:
         for i in range(5):
             np.testing.assert_allclose(Y[i], backend.push(g, ctx, W[i]),
                                        atol=1e-12)
+
+    @pytest.mark.parametrize("edges", ["web", "no-edges", "one-vertex-all"])
+    def test_dense_push_equals_numpy_segment_sum(self, edges):
+        # the segmented scan of the dense push against np.bincount
+        if edges == "web":
+            g = web_graph(700, 5000, dangling_frac=0.2, seed=12)
+        elif edges == "no-edges":
+            g = graph_from_edges(np.zeros(0, int), np.zeros(0, int), 6)
+        else:  # every edge into vertex 3, vertices 0..2 and 4.. unreferenced
+            g = graph_from_edges(np.arange(9), np.full(9, 3), 9)
+        backend = get_step_impl("dense")
+        ctx = backend.prepare(g)
+        W = np.random.default_rng(2).random((3, g.n))
+        src, dst = np.asarray(g.src), np.asarray(g.dst)
+        ref = np.stack([np.bincount(dst, weights=w[src], minlength=g.n)
+                        for w in W])
+        np.testing.assert_allclose(backend.push_batch(g, ctx, jnp.asarray(W)),
+                                   ref, rtol=1e-14, atol=1e-12)
+        np.testing.assert_allclose(backend.push(g, ctx, jnp.asarray(W[0])),
+                                   ref[0], rtol=1e-14, atol=1e-12)
+        np.testing.assert_array_equal(backend.push(g, None, jnp.asarray(W[0])),
+                                      backend.push(g, ctx, jnp.asarray(W[0])))
 
     def test_frontier_push_empty_frontier(self):
         g = web_graph(50, 300, dangling_frac=0.1, seed=11)
@@ -391,3 +414,35 @@ class TestEllCache:
         g = web_graph(150, 900, dangling_frac=0.1, seed=41)
         backend = get_step_impl("ell")
         assert backend.prepare(g) is g.ell()
+
+
+class TestTpuRefusal:
+    """Mosaic refuses the ELL kernel; a TPU must never run or pick it."""
+
+    @pytest.fixture
+    def on_tpu(self, monkeypatch):
+        import importlib
+
+        for name in ("repro.core.backends", "repro.kernels.spmv_ell.ops"):
+            mod = importlib.import_module(name)
+            monkeypatch.setattr(mod.jax, "default_backend", lambda: "tpu")
+
+    def test_auto_never_picks_ell_on_tpu(self):
+        for mesh in (None, (4, 1), (2, 2)):
+            stats = dict(n=875_713, m=5_105_039, platform="tpu", mesh=mesh)
+            name, reason = choose_backend(stats)
+            assert name == "dense" and "ell" not in reason, (mesh, reason)
+
+    def test_explicit_ell_raises_naming_the_refusal(self, on_tpu):
+        from repro.core import EnginePlan, PageRankEngine
+
+        g = web_graph(60, 400, dangling_frac=0.1, seed=13)
+        with pytest.raises(ValueError, match="Only 2D gather is supported"):
+            PageRankEngine(g, EnginePlan(step_impl="ell"))
+
+    def test_kernel_default_never_interprets_on_tpu(self, on_tpu):
+        from repro.kernels.spmv_ell import spmv_ell
+
+        g = web_graph(60, 400, dangling_frac=0.1, seed=13)
+        with pytest.raises(NotImplementedError, match="does not lower on TPU"):
+            spmv_ell(g.ell(), jnp.ones((g.n,)))

@@ -68,6 +68,43 @@ class TestParity:
                                       step_impl=impl)
         assert np.array_equal(np.asarray(rb_eng.pi), np.asarray(rb_leg.pi))
 
+    @pytest.mark.parametrize("impl", available_step_impls(jittable_only=True))
+    def test_donated_batch_path_matches_ita_batch(self, g, impl):
+        # the path every accelerator engine takes, forced on the CPU
+        from repro.core import PPRQuery, ita_batch, one_hot_personalizations
+
+        P = one_hot_personalizations(g, [1, 5, 9, 5])
+        cfg = BatchConfig(xi=1e-12)
+        plain = PageRankEngine(g, EnginePlan(step_impl=impl))
+        donated = PageRankEngine(g, EnginePlan(step_impl=impl))
+        donated._donate = True
+        assert donated.plan(PPRQuery(p_batch=P, cfg=cfg)).path == "donated-batch"
+        assert plain.plan(PPRQuery(p_batch=P, cfg=cfg)).path != "donated-batch"
+        ref = ita_batch(g, P, xi=1e-12, step_impl=impl)
+        undonated = plain.solve_batch(P, cfg)
+        for _ in range(2):  # the second call reuses the compiled loop
+            got = donated.solve_batch(P, cfg)
+            assert np.array_equal(np.asarray(got.pi), np.asarray(ref.pi))
+            assert np.array_equal(np.asarray(got.pi),
+                                  np.asarray(undonated.pi))
+            assert got.iterations == ref.iterations and got.converged
+
+    def test_donated_rows_match_one_device_share_of_a_batch_grid(self, g):
+        # a (4, 1) grid runs each chip's 4 rows through the program of a
+        # (1, 1) mesh; those rows must equal the donated 16-row solve's
+        from repro.core import one_hot_personalizations
+        from repro.core.distributed import ita_batch_distributed, resolve_mesh
+
+        P = one_hot_personalizations(g, [3, 1, 4, 1, 5, 9, 2, 6,
+                                         5, 3, 5, 8, 9, 7, 9, 3])
+        cfg = BatchConfig(xi=1e-12)
+        eng = PageRankEngine(g, EnginePlan(step_impl="dense"))
+        eng._donate = True
+        whole = eng.solve_batch(P, cfg)
+        share = ita_batch_distributed(g, P[:4], resolve_mesh((1, 1)),
+                                      xi=1e-12, ctx=eng._ctx)
+        assert np.array_equal(np.asarray(whole.pi)[:4], np.asarray(share.pi))
+
     def test_batch_power_matches_legacy(self, g):
         from repro.core import one_hot_personalizations
 
@@ -140,6 +177,7 @@ class TestPrepareReuse:
         d = eng.describe()
         assert d["n"] == g.n and d["m"] == g.m
         assert d["step_impl"] == "dense" and d["prepare_count"] == 1
+        assert d["devices"] == [0]
         assert d["n_dangling"] == int(jnp.sum(g.dangling_mask))
         assert d["n_unreferenced"] == int(jnp.sum(g.unreferenced_mask))
 

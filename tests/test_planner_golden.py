@@ -36,7 +36,7 @@ from repro.core.backends import STEP_IMPLS, choose_backend, get_step_impl
 from repro.core.engine import EnginePlan, PageRankEngine
 from repro.core.query import PPRQuery, RankQuery
 from repro.graph import web_graph
-from repro.roofline.hw import spec_for_platform
+from repro.roofline.hw import spec_for_device_kind
 from repro.roofline.planner_costs import (
     CostTable,
     StepCostSample,
@@ -198,7 +198,7 @@ def _sample(backend, seconds, platform="cpu", **kw):
     # estimate() re-prices each lookup from bytes/FLOPs on the platform
     # roofline, so encode the intended per-round seconds as memory bytes
     # (per-round time = bytes / hbm_bandwidth when compute is negligible).
-    spec = spec_for_platform(platform)
+    spec = spec_for_device_kind(platform)
     base = dict(
         backend=backend,
         platform=platform,

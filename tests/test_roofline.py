@@ -7,6 +7,8 @@ bytes of the vertex-sharded schedules against the table in
 docs/SHARDING.md ("`psum_scatter` over model: `(B/R)·(n/C)·d` sent per
 device" for both the dense and sharded-ELL rows)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -14,7 +16,7 @@ from _mesh_env import MESH, needs_devices, run_py
 
 from repro.roofline.analysis import analyze_compiled, parse_shape_bytes
 from repro.roofline.hlo_costs import parse_hlo_costs
-from repro.roofline.hw import HW
+from repro.roofline.hw import HW, TPUv5e, spec_for_device_kind
 from repro.roofline.planner_costs import measure_step, roofline_seconds
 
 SIMPLE_HLO = """
@@ -121,13 +123,16 @@ class TestMeasuredSamples:
         return web_graph(400, 3200, dangling_frac=0.25, seed=17)
 
     def test_dense_bytes_match_analytic_band(self, g):
-        """One dense push streams the edge list and the vertex vectors:
-        analytic per-round traffic is (m reads + m index reads + n write
-        + n operand read) x d ~ 2(m + n)·d.  cost_analysis sees the
-        XLA realisation (fused gathers, scratch) — hold it to a stated
-        factor-2 band of the analytic figure, both directions."""
+        """One dense push streams the edge list and the vertex vectors,
+        (m reads + m index reads + n write + n operand read) x d
+        ~ 2(m + n)·d, then sums runs with a segmented scan: ceil(log2 m)
+        passes, each reading the values and run flags twice (plain and
+        shifted) and writing both, m·(3d + 3) bytes.  cost_analysis sees
+        the XLA realisation (fused gathers, scratch) — hold it to a
+        stated factor-2 band of the analytic figure, both directions."""
         s = measure_step("dense", g, dtype="float64")
-        analytic = 2 * (g.m + g.n) * 8
+        passes = math.ceil(math.log2(g.m))
+        analytic = 2 * (g.m + g.n) * 8 + passes * g.m * (3 * 8 + 3)
         assert analytic / 2 <= s.bytes_accessed <= analytic * 2, (
             s.bytes_accessed,
             analytic,
@@ -223,3 +228,12 @@ def test_batch_only_mesh_has_no_vertex_collective():
     out = run_py(_SHARDED_BODY.format(mesh=(2, 1)))
     for backend in ("dense", "ell"):
         assert out[backend]["coll"] <= 64, out[backend]
+
+
+def test_specs_are_keyed_by_device_kind():
+    assert spec_for_device_kind("TPU v5 lite") is TPUv5e
+    assert TPUv5e.peak_bf16_flops == 197e12 and TPUv5e.hbm_bandwidth == 819e9
+    assert spec_for_device_kind(jax.devices()[0].device_kind) is not None
+    for unknown in ("tpu", "TPU v6 lite", "gpu"):
+        with pytest.raises(KeyError, match="no hardware spec"):
+            spec_for_device_kind(unknown)

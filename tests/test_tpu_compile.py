@@ -1,0 +1,176 @@
+"""Compile the chip's main path for a described TPU v5e, at web-Google size.
+
+Nothing here runs on a chip: each test lowers and compiles one program of
+the engine's main path for a ``v5e:2x2`` topology that is described, not
+attached, at the widths of the paper's Table-3 web-Google preset
+(n = 875,713, m = 5,105,039) and in the engine's dtype (float64).  What the
+TPU compiler refuses fails here, at no chip time.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.  The persistent compile cache is off around these
+compiles, since a compile for a described chip cannot be read back here.
+
+The bucketed-ELL kernel is refused by Mosaic today; its test is a strict
+xfail, so a change that makes it lower must flip it (and lift
+``EllBackend.refused_on``).
+"""
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.core.backends import DenseRuns, _ita_loop_jit, get_step_impl
+from repro.core.batch import _ita_batch_loop_donated
+from repro.core.distributed import _batch_2d_loop, _batch_dp_loop
+from repro.graph import Graph
+from repro.graph.generators import TABLE3_PRESETS
+from repro.kernels.spmv_ell.kernel import TPU_REFUSAL, spmv_ell_bucket
+
+N = TABLE3_PRESETS["web-Google"]["n"]
+M = TABLE3_PRESETS["web-Google"]["m"]
+B = 16
+DTYPE = jnp.float64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.fixture(scope="module")
+def graph(one_chip):
+    """Abstract web-Google graph on one described chip (shapes only)."""
+    return Graph(src=_struct((M,), jnp.int32, one_chip),
+                 dst=_struct((M,), jnp.int32, one_chip),
+                 out_deg=_struct((N,), jnp.int32, one_chip),
+                 in_deg=_struct((N,), jnp.int32, one_chip), n=N, m=M)
+
+
+@pytest.fixture(scope="module")
+def runs(one_chip):
+    """Abstract ``DenseBackend.prepare`` context of that graph."""
+    return DenseRuns(start=_struct((M,), jnp.bool_, one_chip),
+                     last=_struct((N,), jnp.int32, one_chip))
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem is not None
+    return compiled, mem
+
+
+@pytest.mark.parametrize("batch", [1, B], ids=["push", "push_batch"])
+def test_dense_push_compiles(graph, runs, one_chip, batch):
+    dense = get_step_impl("dense")
+    if batch == 1:
+        compiled, mem = _compile(lambda g, r, w: dense.push(g, r, w), graph,
+                                 runs, _struct((N,), DTYPE, one_chip))
+    else:
+        compiled, mem = _compile(lambda g, r, W: dense.push_batch(g, r, W),
+                                 graph, runs, _struct((B, N), DTYPE, one_chip))
+    # the [B, m] gather is the largest buffer: it must fit one chip
+    assert mem.temp_size_in_bytes < 16e9
+    # no float64 scatter on TPU: XLA runs it one update at a time
+    assert "scatter" not in compiled.as_text()
+
+
+def test_ita_solve_compiles(graph, runs, one_chip):
+    """One whole device-resident ITA solve, as RankQuery runs it."""
+    h = _struct((N,), DTYPE, one_chip)
+    lowered = _ita_loop_jit.lower(graph, runs, h, h, 0.85, 1e-10,
+                                  max_iter=10_000,
+                                  backend=get_step_impl("dense"),
+                                  signed=False)
+    assert lowered.compile().memory_analysis() is not None
+
+
+def test_donated_batch_loop_compiles(graph, runs, one_chip):
+    """The accelerator serving path: the batched loop with H0 donated and
+    the graph as an argument, so no edge array is a constant."""
+    H0 = _struct((B, N), DTYPE, one_chip)
+    lowered = _ita_batch_loop_donated.lower(
+        graph, runs, H0, 0.85, 1e-10, max_iter=10_000,
+        backend=get_step_impl("dense"))
+    args = [(a.shape, a.donated) for a in jax.tree.leaves(lowered.args_info)]
+    assert args[:2] == [((M,), False)] * 2  # src, dst are arguments
+    assert ((B, N), True) in args           # the [B, n] buffer is donated
+    assert "tf.aliasing_output" in lowered.as_text()
+    assert lowered.compile().memory_analysis() is not None
+
+
+def test_batch_parallel_loop_compiles_on_4x1(topo):
+    """The (4, 1) batch-parallel loop: each chip runs the dense push_batch
+    on its rows against replicated graph operands."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    rep = NamedSharding(mesh, P())
+    g = Graph(src=_struct((M,), jnp.int32, rep),
+              dst=_struct((M,), jnp.int32, rep),
+              out_deg=_struct((N,), jnp.int32, rep),
+              in_deg=_struct((N,), jnp.int32, rep), n=N, m=M)
+    runs = DenseRuns(start=_struct((M,), jnp.bool_, rep),
+                     last=_struct((N,), jnp.int32, rep))
+    run = _batch_dp_loop(mesh, get_step_impl("dense"), 0.85, 1e-10, 10_000,
+                         "data")
+    compiled = run.lower(
+        g, runs, _struct((B, N), DTYPE, NamedSharding(mesh, P("data", None)))
+    ).compile()
+    assert "scatter" not in compiled.as_text()
+
+
+def test_sharded_dense_round_compiles_on_2x2(topo):
+    """The (2, 2) vertex-sharded dense loop of ``ita_batch_distributed``."""
+    R, C = 2, 2
+    mesh = Mesh(np.asarray(topo.devices).reshape(R, C), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    n_pad = -(-N // C) * C
+    e_pad = -(-int(M / C * 1.05) // 8) * 8
+    run = _batch_2d_loop(mesh, n_pad, 0.85, 1e-10, 10_000, "data", "model")
+    sh = partial(NamedSharding, mesh)
+    args = (_struct((B, n_pad), DTYPE, sh(P("data", "model"))),
+            _struct((C, e_pad), jnp.int32, sh(P("model", None))),
+            _struct((C, e_pad), jnp.int32, sh(P("model", None))),
+            _struct((n_pad,), DTYPE, sh(P("model"))),
+            _struct((n_pad,), jnp.bool_, sh(P("model"))))
+    compiled = run.lower(*args).compile()
+    # TPU has no 64-bit reduce-scatter: the column exchange is an all-to-all
+    assert "all-to-all" in compiled.as_text()
+
+
+@pytest.mark.xfail(strict=True, raises=NotImplementedError,
+                   reason=f"Mosaic: 'Only 2D gather is supported' "
+                          f"({TPU_REFUSAL})")
+def test_ell_kernel_lowers_at_web_google_width(one_chip):
+    rows, k = 1 << 19, 8
+    w = _struct((N + 1,), jnp.float32, one_chip)
+    idx = _struct((rows, k), jnp.int32, one_chip)
+    jax.jit(partial(spmv_ell_bucket, interpret=False)).lower(w, idx).compile()
