@@ -401,13 +401,21 @@ def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
     x, f = vals, runs.start
     lead = [(0, 0)] * (x.ndim - 1)
     k = 1
-    while k < x.shape[-1]:
-        x = jnp.where(f, x, x + jnp.pad(x[..., :-k], lead + [(k, 0)]))
-        f = f | jnp.pad(f[:-k], (k, 0), constant_values=True)
-        k *= 2
+    with jax.named_scope("scan"):
+        while k < x.shape[-1]:
+            x = jnp.where(f, x, x + jnp.pad(x[..., :-k], lead + [(k, 0)]))
+            f = f | jnp.pad(f[:-k], (k, 0), constant_values=True)
+            k *= 2
     if x.shape[-1] == 0:
         return jnp.zeros(vals.shape[:-1] + runs.last.shape, vals.dtype)
-    return jnp.where(runs.last >= 0, x[..., jnp.maximum(runs.last, 0)], 0)
+    with jax.named_scope("readout"):
+        return jnp.where(runs.last >= 0, x[..., jnp.maximum(runs.last, 0)], 0)
+
+
+def _gather_sources(vals: jnp.ndarray, g: Graph) -> jnp.ndarray:
+    """Each edge's source value, ``vals[..., src]``."""
+    with jax.named_scope("gather"):
+        return vals[..., g.src]
 
 
 @register_step_impl("dense")
@@ -436,12 +444,13 @@ class DenseBackend(StepBackend):
         return _dense_runs(g)
 
     def push(self, g: Graph, ctx, w: jnp.ndarray) -> jnp.ndarray:
-        return _run_sums(w[g.src], ctx if ctx is not None else _dense_runs(g))
+        return _run_sums(_gather_sources(w, g),
+                         ctx if ctx is not None else _dense_runs(g))
 
     def push_batch(self, g: Graph, ctx, W: jnp.ndarray) -> jnp.ndarray:
         # one gather + one scan over the trailing axis beats B separate
         # scans: the edge index stream is read once per batch.
-        return _run_sums(W[:, g.src],
+        return _run_sums(_gather_sources(W, g),
                          ctx if ctx is not None else _dense_runs(g))  # [B, n]
 
 
@@ -625,16 +634,18 @@ def _ita_round(backend: StepBackend, g: Graph, ctx, h, pi_bar, c, xi,
     ops and the Management-thread CNT — is identical by construction, so a
     fix here reaches the plain, signed and batched solvers alike.
     """
-    mag = jnp.abs(h) if signed else h
-    active = jnp.logical_and(mag > xi, non_dangling)
-    h_act = jnp.where(active, h, 0)
-    pi_bar = pi_bar + h_act
-    pushed = backend.push(g, ctx, h_act * inv_deg * c)
-    h = jnp.where(active, 0, h) + pushed
-    n_active = jnp.sum(active, dtype=jnp.int32)
-    ops = jnp.sum(jnp.where(active, g.out_deg, 0).astype(jnp.float32),
-                  dtype=jnp.float32)
-    return h, pi_bar, n_active, ops
+    with jax.named_scope("ita_round"):
+        mag = jnp.abs(h) if signed else h
+        active = jnp.logical_and(mag > xi, non_dangling)
+        h_act = jnp.where(active, h, 0)
+        pi_bar = pi_bar + h_act
+        with jax.named_scope("push"):
+            pushed = backend.push(g, ctx, h_act * inv_deg * c)
+        h = jnp.where(active, 0, h) + pushed
+        n_active = jnp.sum(active, dtype=jnp.int32)
+        ops = jnp.sum(jnp.where(active, g.out_deg, 0).astype(jnp.float32),
+                      dtype=jnp.float32)
+        return h, pi_bar, n_active, ops
 
 
 def ita_step_impl(backend: StepBackend, g: Graph, ctx, h, pi_bar, c, xi,

@@ -32,6 +32,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..graph.structure import Graph
 from .backends import get_step_impl
@@ -49,8 +50,10 @@ class BatchSolverResult:
     shared synchronous-round count (all rows step together), ``residual``
     the stopping threshold the solve ran to (``xi`` for ITA, max row
     residual for power), ``converged`` whether every row met it within
-    ``max_iter``, and ``method`` a tag like ``"ita_batch[dense]"`` naming
-    solver family and ``step_impl``.
+    ``max_iter``, ``method`` a tag like ``"ita_batch[dense]"`` naming
+    solver family and ``step_impl``, and ``ops`` the Formula-15 edge
+    operations summed over rows and rounds (``None`` where a path does
+    not count them: the power family, the mesh and the result cache).
     """
 
     pi: jnp.ndarray
@@ -60,13 +63,14 @@ class BatchSolverResult:
     method: str
     batch: int
     wall_time_s: Optional[float] = None
+    ops: Optional[float] = None
 
     def stats(self) -> dict:
         return dict(method=self.method, batch=self.batch,
                     iterations=int(self.iterations),
                     residual=float(self.residual),
                     converged=bool(self.converged),
-                    wall_time_s=self.wall_time_s)
+                    wall_time_s=self.wall_time_s, ops=self.ops)
 
 
 def one_hot_personalizations(g: Graph, seeds, dtype=jnp.float64) -> jnp.ndarray:
@@ -95,13 +99,19 @@ def normalize_rows(U: jnp.ndarray) -> jnp.ndarray:
 
 
 def _batch_ita_step(backend, g, ctx, H, PiBar, c, xi, inv_deg, non_dangling):
-    active = jnp.logical_and(H > xi, non_dangling[None, :])
-    H_act = jnp.where(active, H, 0)
-    PiBar = PiBar + H_act
-    pushed = backend.push_batch(g, ctx, H_act * inv_deg[None, :] * c)
-    H = jnp.where(active, 0, H) + pushed
-    n_active = jnp.sum(active, dtype=jnp.int32)
-    return H, PiBar, n_active
+    """One batched ITA round; returns ``(H', PiBar', n_active, ops)``,
+    ``ops`` being Formula 15 summed over the rows."""
+    with jax.named_scope("ita_round"):
+        active = jnp.logical_and(H > xi, non_dangling[None, :])
+        H_act = jnp.where(active, H, 0)
+        PiBar = PiBar + H_act
+        with jax.named_scope("push"):
+            pushed = backend.push_batch(g, ctx, H_act * inv_deg[None, :] * c)
+        H = jnp.where(active, 0, H) + pushed
+        n_active = jnp.sum(active, dtype=jnp.int32)
+        ops = jnp.sum(jnp.where(active, g.out_deg[None, :], 0)
+                      .astype(jnp.float32), dtype=jnp.float32)
+        return H, PiBar, n_active, ops
 
 
 def _ita_batch_loop_impl(g: Graph, ctx, H0, c, xi, max_iter: int, backend):
@@ -109,17 +119,17 @@ def _ita_batch_loop_impl(g: Graph, ctx, H0, c, xi, max_iter: int, backend):
     non_dangling = jnp.logical_not(g.dangling_mask)
 
     def cond(state):
-        _, _, n_active, it = state
+        _, _, n_active, it, _ = state
         return jnp.logical_and(n_active > 0, it < max_iter)
 
     def body(state):
-        H, PiBar, _, it = state
-        H, PiBar, n_active = _batch_ita_step(backend, g, ctx, H, PiBar, c, xi,
-                                             inv_deg, non_dangling)
-        return H, PiBar, n_active, it + 1
+        H, PiBar, _, it, ops_total = state
+        H, PiBar, n_active, ops = _batch_ita_step(
+            backend, g, ctx, H, PiBar, c, xi, inv_deg, non_dangling)
+        return H, PiBar, n_active, it + 1, ops_total + ops
 
     init = (H0, jnp.zeros_like(H0), jnp.asarray(1, jnp.int32),
-            jnp.asarray(0, jnp.int32))
+            jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32))
     return jax.lax.while_loop(cond, body, init)
 
 
@@ -172,24 +182,33 @@ def ita_batch(
     H0 = (jnp.asarray(p_batch, dtype) * g.n).astype(dtype)
     t0 = time.perf_counter()
     if backend.capabilities().jittable:
-        H, PiBar, n_active, it = _ita_batch_loop(
+        # the Formula-15 counter is the loop's last output; a stand-in
+        # loop with the four outputs of old (bench/tests/test_control.py
+        # swaps one in) counts none
+        H, PiBar, n_active, it, *ops = _ita_batch_loop(
             g, ctx, H0, float(c), float(xi), int(max_iter), backend)
+        ops = ops[0] if ops else None
     else:
         inv_deg = g.inv_out_deg(dtype)
         non_dangling = jnp.logical_not(g.dangling_mask)
         H, PiBar = H0, jnp.zeros_like(H0)
-        it, n_active = 0, jnp.asarray(1, jnp.int32)
+        it, ops, n_active = 0, 0.0, jnp.asarray(1, jnp.int32)
         while it < max_iter:
-            H, PiBar, n_active = _batch_ita_step(
+            H, PiBar, n_active, ops_round = _batch_ita_step(
                 backend, g, ctx, H, PiBar, c, xi, inv_deg, non_dangling)
+            ops += float(ops_round)
             it += 1
             if int(n_active) == 0:
                 break
-    Pi = jax.block_until_ready(normalize_rows(PiBar + H))
-    result = BatchSolverResult(
-        pi=Pi, iterations=int(it), residual=float(xi),
-        converged=bool(int(n_active) == 0), method=f"ita_batch[{step_impl}]",
-        batch=int(p_batch.shape[0]), wall_time_s=time.perf_counter() - t0)
+    Pi = normalize_rows(PiBar + H)
+    with TraceAnnotation("solve.wait"):
+        Pi = jax.block_until_ready(Pi)
+        result = BatchSolverResult(
+            pi=Pi, iterations=int(it), residual=float(xi),
+            converged=bool(int(n_active) == 0),
+            method=f"ita_batch[{step_impl}]", batch=int(p_batch.shape[0]),
+            wall_time_s=time.perf_counter() - t0,
+            ops=None if ops is None else float(ops))
     if return_state:
         return result, (PiBar, H)
     return result

@@ -483,8 +483,8 @@ def _batch_dp_loop(mesh: Mesh, backend, c: float, xi: float, max_iter: int,
 
         def body(state):
             H, PiBar, _, it = state
-            H, PiBar, n_loc = _batch_ita_step(backend, g, ctx, H, PiBar, c,
-                                              xi, inv_deg, nd)
+            H, PiBar, n_loc, _ = _batch_ita_step(backend, g, ctx, H, PiBar,
+                                                 c, xi, inv_deg, nd)
             return H, PiBar, jax.lax.psum(n_loc, batch_axis), it + 1
 
         init = (H0, jnp.zeros_like(H0), jnp.asarray(1, jnp.int32),

@@ -60,6 +60,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..graph.structure import Graph, apply_edge_delta
@@ -345,22 +346,25 @@ class PageRankEngine:
                 return env
             # None: not cacheable (dense rows, power family, ...) — run
             # exactly as an uncached engine would.
-        ep = self.plan(query)
+        with TraceAnnotation("engine.plan"):
+            ep = self.plan(query)
         t0 = time.perf_counter()
-        if isinstance(query, RankQuery):
-            res = self._exec_rank(ep)
-            values = res.pi
-        elif isinstance(query, PPRQuery):
-            res = self._exec_ppr(query.p_batch, ep)
-            values = res.pi
-        elif isinstance(query, TopKQuery):
-            res = self._exec_topk(query, ep)
-            values = (res.indices, res.scores)
-        elif isinstance(query, DeltaQuery):
-            res = self._exec_delta(query)
-            values = res.pi
-        else:  # plan_query would have raised already; defensive
-            raise TypeError(f"not a runnable Query: {type(query).__name__}")
+        with TraceAnnotation("engine.exec"):
+            if isinstance(query, RankQuery):
+                res = self._exec_rank(ep)
+                values = res.pi
+            elif isinstance(query, PPRQuery):
+                res = self._exec_ppr(query.p_batch, ep)
+                values = res.pi
+            elif isinstance(query, TopKQuery):
+                res = self._exec_topk(query, ep)
+                values = (res.indices, res.scores)
+            elif isinstance(query, DeltaQuery):
+                res = self._exec_delta(query)
+                values = res.pi
+            else:  # plan_query would have raised already; defensive
+                raise TypeError(
+                    f"not a runnable Query: {type(query).__name__}")
         counters = res.result if isinstance(res, TopKResult) else res
         return ResultEnvelope(
             result=res, plan=ep, values=values,
@@ -445,16 +449,18 @@ class PageRankEngine:
         """
         t0 = time.perf_counter()
         H0 = (p_batch.astype(cfg.dtype) * self.graph.n).astype(cfg.dtype)
-        H, PiBar, n_active, it = _ita_batch_loop_donated(
+        H, PiBar, n_active, it, ops = _ita_batch_loop_donated(
             self.graph, self._ctx, H0, float(cfg.c), float(cfg.xi),
             int(cfg.max_iter), self.backend)
-        Pi = jax.block_until_ready(normalize_rows(PiBar + H))
-        result = BatchSolverResult(
-            pi=Pi, iterations=int(it), residual=float(cfg.xi),
-            converged=bool(int(n_active) == 0),
-            method=f"ita_batch[{self.step_impl}]",
-            batch=int(p_batch.shape[0]),
-            wall_time_s=time.perf_counter() - t0)
+        Pi = normalize_rows(PiBar + H)
+        with TraceAnnotation("solve.wait"):
+            Pi = jax.block_until_ready(Pi)
+            result = BatchSolverResult(
+                pi=Pi, iterations=int(it), residual=float(cfg.xi),
+                converged=bool(int(n_active) == 0),
+                method=f"ita_batch[{self.step_impl}]",
+                batch=int(p_batch.shape[0]),
+                wall_time_s=time.perf_counter() - t0, ops=float(ops))
         if return_state:
             return result, (PiBar, H)
         return result

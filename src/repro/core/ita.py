@@ -36,6 +36,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..graph.structure import Graph
 from .backends import get_step_impl, ita_step_impl, run_ita_loop
@@ -98,17 +99,18 @@ def ita(
     # vertices — then normalize (Algorithm 3 final step).
     pi_bar = pi_bar + h
     pi = pi_bar / jnp.sum(pi_bar)
-    pi = jax.block_until_ready(pi)
-    wall = time.perf_counter() - t0
-    return SolverResult(
-        pi=pi,
-        iterations=int(it),
-        residual=float(xi),
-        ops=float(ops),
-        converged=bool(int(n_active) == 0),
-        method="ita" if step_impl == "dense" else f"ita[{step_impl}]",
-        wall_time_s=wall,
-    )
+    with TraceAnnotation("solve.wait"):
+        pi = jax.block_until_ready(pi)
+        wall = time.perf_counter() - t0
+        return SolverResult(
+            pi=pi,
+            iterations=int(it),
+            residual=float(xi),
+            ops=float(ops),
+            converged=bool(int(n_active) == 0),
+            method="ita" if step_impl == "dense" else f"ita[{step_impl}]",
+            wall_time_s=wall,
+        )
 
 
 def ita_traced(

@@ -22,7 +22,7 @@ from .admission import AdmissionController, AdmissionPolicy, TokenBucket
 from .batcher import CostModel, DeadlineBatcher
 from .clock import Clock, VirtualClock, WallClock
 from .degrade import DegradeLevel, DegradePolicy
-from .metrics import latency_summary, per_query_latency_ms, weighted_percentile
+from .metrics import latency_summary
 from .queue import BoundedQueue, Overload
 from .service import PPRService, Served, ServiceConfig, ServiceReport
 from .workload import (
@@ -53,7 +53,5 @@ __all__ = [
     "VirtualClock",
     "WallClock",
     "latency_summary",
-    "per_query_latency_ms",
-    "weighted_percentile",
     "zipf_seeds",
 ]

@@ -1,86 +1,15 @@
-"""Latency accounting shared by the serving tier and the CLI driver.
+"""Latency summary of the serving tier.
 
-One implementation of the percentile arithmetic, because the subtle part
-has already been wrong once: a fixed-shape micro-batcher pads its tail
-batch to the compiled ``[B, n]`` shape, so the padded batch costs the
-same device pass as a full one — dividing its wall time by ``B`` (instead
-of by the real queries it answered) understated those queries' latency
-and skewed the p50 (the PR 6 serving bugfix).  The weighting lives here
-exactly once: :func:`per_query_latency_ms` attributes each batch's wall
-time to its *real* queries, and :func:`weighted_percentile` is the
-general n-real-weighted quantile both the CLI and
-``serve/service.py`` report through.
+:func:`latency_summary` reduces per-request latencies (arrival to
+answer, queue wait included: ``Served.latency_s``) to the percentiles
+``ServiceReport.summary`` and the ``ppr_serve`` CLI report.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-__all__ = [
-    "per_query_latency_ms",
-    "weighted_percentile",
-    "latency_summary",
-]
-
-
-def per_query_latency_ms(lat_batch_s, n_real) -> np.ndarray:
-    """Expand per-batch wall times into per-query latencies (ms).
-
-    ``lat_batch_s`` is the wall time of each micro-batch (seconds);
-    ``n_real`` the count of *real* (non-padding) queries each answered.
-    Each batch's time is attributed evenly across its real queries —
-    a padded tail batch costs the same device pass as a full one, so its
-    few real queries each carry a full share of that pass, not ``1/B``
-    of it.  Returns one entry per real query.
-    """
-    lat_ms = np.asarray(lat_batch_s, dtype=np.float64) * 1e3
-    n_real = np.asarray(n_real, dtype=np.int64)
-    if lat_ms.shape != n_real.shape:
-        raise ValueError(
-            f"lat_batch_s and n_real must align; got shapes {lat_ms.shape} vs {n_real.shape}"
-        )
-    if lat_ms.size == 0:
-        return np.zeros((0,), dtype=np.float64)
-    if np.any(n_real < 1):
-        raise ValueError("every batch must have answered >= 1 real query")
-    return np.repeat(lat_ms / n_real, n_real)
-
-
-def weighted_percentile(values, weights, q) -> float:
-    """Percentile of ``values`` where each value counts ``weights`` times.
-
-    Integer weights reproduce ``np.percentile`` on the expanded array
-    exactly (the padded-tail case: each batch latency weighted by its
-    real-query count); fractional weights interpolate on the cumulative
-    weight axis the same way ``np.percentile(..., method="linear")``
-    does on ranks.
-    """
-    v = np.asarray(values, dtype=np.float64)
-    w = np.asarray(weights, dtype=np.float64)
-    if v.shape != w.shape:
-        raise ValueError(f"values and weights must align; got shapes {v.shape} vs {w.shape}")
-    if v.size == 0:
-        raise ValueError("weighted_percentile of an empty sample")
-    if np.any(w <= 0):
-        raise ValueError("weights must be > 0")
-    order = np.argsort(v, kind="stable")
-    v, w = v[order], w[order]
-    # rank of each value in the expanded multiset, linear-interpolated:
-    # the i-th (0-based) expanded sample sits at cumulative position i,
-    # and a value with weight w_j spans ranks [cum_{j-1}, cum_j - 1].
-    cum = np.cumsum(w)
-    total = cum[-1]
-    target = float(q) / 100.0 * (total - 1.0)
-    hi_ranks = cum - 1.0
-    lo_ranks = cum - w
-    j = int(np.searchsorted(hi_ranks, target, side="left"))
-    j = min(j, v.size - 1)
-    if target >= lo_ranks[j] or j == 0:
-        return float(v[j])
-    # target falls between value j-1's last rank and value j's first
-    span = lo_ranks[j] - hi_ranks[j - 1]
-    frac = (target - hi_ranks[j - 1]) / span
-    return float(v[j - 1] + frac * (v[j] - v[j - 1]))
+__all__ = ["latency_summary"]
 
 
 def latency_summary(per_query_ms) -> dict:

@@ -14,8 +14,11 @@ answers served through the tier are **bit-identical** to direct
 and what to run, never how; tests/test_serving.py pins it).
 
 Latency is accounted **per request** (arrival to completion, queue wait
-included), not per batch — the padded tail batch's device pass is
-attributed to the real queries it answered via ``serve/metrics.py``.
+included), not per batch: ``Served.t_dispatch`` splits it into the wait
+in the queue and the service of the request's micro-batch.  Under a
+running ``jax.profiler`` trace the loop's stages show as host spans:
+``serve.ingest``, ``serve.batch`` (one per micro-batch, with its index)
+and, inside it, ``serve.assemble``.
 """
 
 from __future__ import annotations
@@ -25,6 +28,7 @@ import time
 from typing import Any, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .admission import AdmissionController, AdmissionPolicy
 from .batcher import CostModel, DeadlineBatcher
@@ -76,12 +80,19 @@ class ServiceConfig:
 
 @dataclasses.dataclass
 class Served:
-    """One completed request: timing, fidelity and (optionally) values."""
+    """One completed request: timing, fidelity and (optionally) values.
+
+    ``t_dispatch`` is the service clock's time when the request's
+    micro-batch went to the executor: ``t_dispatch - req.t_arrival`` is
+    its wait in the queue and ``latency_s`` that wait plus
+    ``t_done - t_dispatch``.
+    """
 
     req: Request
     t_done: float
     latency_s: float
     deadline_met: bool
+    t_dispatch: float = None
     level: int = 0
     degraded: bool = False
     cache_hit: bool = False
@@ -312,18 +323,19 @@ class PPRService:
     # stages
     # ------------------------------------------------------------------ #
     def _ingest(self, req: Request, now: float, workload, served, shed):
-        decision = self.admission.admit(req, now, self.cfg)
-        if isinstance(decision, Overload):
-            shed.append(decision)
-            workload.on_reject(req, now)
-            return
-        if decision == "bypass":
-            self._serve_bypass(req, workload, served)
-            return
-        ov = self.queue.offer(req, now, retry_after_s=self.batcher.predicted_batch_s())
-        if ov is not None:
-            shed.append(ov)
-            workload.on_reject(req, now)
+        with TraceAnnotation("serve.ingest"):
+            decision = self.admission.admit(req, now, self.cfg)
+            if isinstance(decision, Overload):
+                shed.append(decision)
+                workload.on_reject(req, now)
+                return
+            if decision == "bypass":
+                self._serve_bypass(req, workload, served)
+                return
+            ov = self.queue.offer(req, now, retry_after_s=self.batcher.predicted_batch_s())
+            if ov is not None:
+                shed.append(ov)
+                workload.on_reject(req, now)
 
     def _serve_bypass(self, req: Request, workload, served):
         """Fresh cache entry: answer now, skipping queue and batcher.
@@ -332,6 +344,7 @@ class PPRService:
         so the only cost is assembly — charged as zero model time (wall
         time passes on its own under a WallClock)."""
         eng, cfg, _ = self._level_state(0)
+        t_dispatch = self.clock.now()
         env = self.executor(eng, np.asarray([req.seed]), self.config.k, cfg)
         t_done = self.clock.now()
         if env is not None:
@@ -344,6 +357,7 @@ class PPRService:
             t_done=t_done,
             latency_s=t_done - req.t_arrival,
             deadline_met=t_done <= req.deadline,
+            t_dispatch=t_dispatch,
             level=0,
             degraded=False,
             cache_hit=True,
@@ -354,6 +368,10 @@ class PPRService:
         workload.on_complete(req, t_done)
 
     def _dispatch(self, workload, served, batches):
+        with TraceAnnotation("serve.batch", batch=len(batches)):
+            self._run_batch(workload, served, batches)
+
+    def _run_batch(self, workload, served, batches):
         reqs = self.queue.pop_batch(self.config.batch_size)
         # the degrade signal is the backlog LEFT BEHIND by this batch: a
         # healthy service pops its batch and leaves ~nothing (so depth
@@ -364,10 +382,10 @@ class PPRService:
         n_real = len(reqs)
         sources = np.asarray([r.seed for r in reqs], dtype=np.int64)
         if n_real < self.config.batch_size:
-            # pad the tail to the compiled [B, n] shape (metrics attribute
-            # the full pass to the real queries; see serve/metrics.py)
+            # pad the tail to the compiled [B, n] shape
             pad = np.full(self.config.batch_size - n_real, sources[-1], dtype=np.int64)
             sources = np.concatenate([sources, pad])
+        t_dispatch = self.clock.now()
         t0 = time.perf_counter()
         env = self.executor(eng, sources, self.config.k, cfg)
         wall = time.perf_counter() - t0
@@ -382,22 +400,24 @@ class PPRService:
         if env is not None:
             env.degraded = degraded  # every degraded answer says so
         batches.append((service_s, n_real, level))
-        for i, req in enumerate(reqs):
-            if env is not None:
-                indices = np.asarray(env.result.indices[i])
-                scores = np.asarray(env.result.scores[i])
-            else:
-                indices = scores = None
-            s = Served(
-                req=req,
-                t_done=t_done,
-                latency_s=t_done - req.t_arrival,
-                deadline_met=t_done <= req.deadline,
-                level=level,
-                degraded=degraded,
-                cache_hit=False,
-                indices=indices,
-                scores=scores,
-            )
-            served.append(s)
-            workload.on_complete(req, t_done)
+        with TraceAnnotation("serve.assemble"):
+            for i, req in enumerate(reqs):
+                if env is not None:
+                    indices = np.asarray(env.result.indices[i])
+                    scores = np.asarray(env.result.scores[i])
+                else:
+                    indices = scores = None
+                s = Served(
+                    req=req,
+                    t_done=t_done,
+                    latency_s=t_done - req.t_arrival,
+                    deadline_met=t_done <= req.deadline,
+                    t_dispatch=t_dispatch,
+                    level=level,
+                    degraded=degraded,
+                    cache_hit=False,
+                    indices=indices,
+                    scores=scores,
+                )
+                served.append(s)
+                workload.on_complete(req, t_done)

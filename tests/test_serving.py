@@ -2,9 +2,8 @@
 
 The contracts under test, per docs/SERVING.md:
 
-  * **metrics** — one shared implementation of padded-tail per-query
-    latency attribution and weighted percentiles (the PR 6 fix, pinned
-    here as a regression test) used by the CLI and the service alike;
+  * **metrics** — ``latency_summary``, the percentiles the CLI and
+    ``ServiceReport.summary`` report;
   * **workload determinism** — ``zipf_seeds`` requires an explicit RNG,
     ties in the in-degree ranking break by vertex id (stable sort), and
     identical seeds give identical streams;
@@ -55,8 +54,6 @@ from repro.serve import (
     TokenBucket,
     VirtualClock,
     latency_summary,
-    per_query_latency_ms,
-    weighted_percentile,
     zipf_seeds,
 )
 from repro.serve.service import EngineExecutor
@@ -84,33 +81,9 @@ def _svc_cfg(engine, **kw):
 
 
 # --------------------------------------------------------------------- #
-# metrics — the single shared implementation (satellite 1)
+# metrics
 # --------------------------------------------------------------------- #
 class TestMetrics:
-    def test_padded_tail_weighting_pinned(self):
-        # The PR 6 regression, pinned: two batches take 100 ms each; the
-        # first answered 8 real queries, the second only 2 (padded to 8).
-        # The tail batch's queries each cost a FULL device pass over 2,
-        # i.e. 50 ms — not 100/8 = 12.5 ms.
-        per_q = per_query_latency_ms(np.array([0.1, 0.1]), np.array([8, 2]))
-        assert per_q.shape == (10,)
-        assert np.allclose(per_q[:8], 12.5)
-        assert np.allclose(per_q[8:], 50.0)
-        # and the naive division would have reported 12.5 for everyone
-        assert np.percentile(per_q, 99) > 12.5
-
-    def test_weighted_percentile_matches_expansion(self):
-        rng = np.random.default_rng(0)
-        for _ in range(20):
-            k = int(rng.integers(1, 8))
-            vals = rng.uniform(0.1, 100.0, size=k)
-            wts = rng.integers(1, 9, size=k)
-            expanded = np.repeat(vals, wts)
-            for q in (0, 25, 50, 90, 99, 100):
-                assert weighted_percentile(vals, wts, q) == pytest.approx(
-                    np.percentile(expanded, q), rel=1e-12
-                )
-
     def test_latency_summary_keys(self):
         s = latency_summary(np.array([1.0, 2.0, 3.0, 4.0]))
         assert s["count"] == 4
@@ -118,12 +91,6 @@ class TestMetrics:
         assert s["max_ms"] == 4.0
         assert set(s) >= {"count", "p50_ms", "p90_ms", "p99_ms", "mean_ms", "max_ms"}
         assert latency_summary(np.array([]))["count"] == 0
-
-    def test_per_query_latency_validates(self):
-        with pytest.raises(ValueError):
-            per_query_latency_ms(np.array([0.1]), np.array([0]))
-        with pytest.raises(ValueError):
-            per_query_latency_ms(np.array([0.1, 0.2]), np.array([1]))
 
 
 # --------------------------------------------------------------------- #
