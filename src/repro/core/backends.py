@@ -9,9 +9,11 @@ backends so the solvers pick a layout/schedule without changing numerics
 (the paper's §IV commutativity result is exactly the licence to do this —
 same commutative sum, different grouping):
 
-  * ``"dense"``    — masked SpMV over all m dst-sorted COO edges, summed
+  * ``"dense"``    — masked SpMV over the dst-sorted COO edges, summed
                      per vertex by a segmented scan (paper-faithful
-                     synchronous baseline).
+                     synchronous baseline); a round whose input is zero
+                     off the referenced core (paper §III) walks only the
+                     core's out-edges, with the same bits.
   * ``"frontier"`` — active-set compression: each round gathers only the
                      out-edges of currently-active vertices into a
                      power-of-two-padded bucket, so the per-iteration edge
@@ -34,6 +36,10 @@ A backend is a :class:`SolverBackend` with
   ``prepare(g) -> ctx``           one-time per-graph context (a pytree);
   ``push(g, ctx, w) -> y``        y[dst] = Σ_{(src,dst)∈E} w[src], [n]→[n];
   ``push_batch(g, ctx, W) -> Y``  the same over a [B, n] batch;
+  ``push_counted`` / ``push_batch_counted``  the same, and whether the
+                                  push walked a prepared core edge list
+                                  (None where the layout keeps none);
+  ``core_edges(ctx)``             that list's length, or None;
   ``capabilities()``              a :class:`BackendCapabilities` record —
                                   what this layout can do (trace inside
                                   jit, batch, donate, mesh-shard, update);
@@ -193,6 +199,20 @@ class SolverBackend:
     def push_batch(self, g: Graph, ctx, W: jnp.ndarray) -> jnp.ndarray:
         """[B, n] → [B, n]; default is a vmap of ``push``."""
         return jax.vmap(lambda w: self.push(g, ctx, w))(W)
+
+    def push_counted(self, g: Graph, ctx, w: jnp.ndarray):
+        """``push``, and whether it walked a prepared core edge list in
+        place of all m edges: a traced bool, or None where ``ctx`` holds
+        no such list (the solver loops sum it as ``core_rounds``)."""
+        return self.push(g, ctx, w), None
+
+    def push_batch_counted(self, g: Graph, ctx, W: jnp.ndarray):
+        """``push_batch``, counted as :meth:`push_counted` counts."""
+        return self.push_batch(g, ctx, W), None
+
+    def core_edges(self, ctx) -> Optional[int]:
+        """Edges in ``ctx``'s core edge list, or None where it has none."""
+        return None
 
     def capabilities(self) -> BackendCapabilities:
         """Declared capability row: the class-level ``capabilities_decl``
@@ -369,34 +389,64 @@ def resolve_step_impl(name: Optional[str]) -> str:
 # Backends
 # ---------------------------------------------------------------------------
 class DenseRuns(NamedTuple):
-    """Where each vertex's in-edges sit in the dst-sorted edge list.
+    """A dst-sorted edge list as runs of each vertex's in-edges: the dense
+    backend's per-graph context.
 
-    ``start[e]`` marks the first edge of a run of equal ``dst``;
-    ``last[v]`` is the position of vertex v's last in-edge, -1 when it has
-    none.  The dense backend's per-graph context.
+    ``src[e]`` is edge e's source; ``start[e]`` marks the first edge of a
+    run of equal ``dst``; ``last[v]`` is the position of vertex v's last
+    in-edge, -1 when it has none.
+
+    ``core`` is the referenced core's own edge list (paper §III, see
+    ``Graph.reference_levels``): the out-edges of the vertices of no
+    finite weak-unreferenced level, which are the tail of every run, since
+    each run lists its edges from outside the core first.  ``in_core``
+    marks those vertices.  Both are None when the core holds every edge.
     """
 
-    start: jnp.ndarray  # bool[m]
+    src: jnp.ndarray    # int32[e]
+    start: jnp.ndarray  # bool[e]
     last: jnp.ndarray   # int32[n]
+    core: Optional["DenseRuns"] = None
+    in_core: Optional[jnp.ndarray] = None  # bool[n]
+
+
+def _runs(src: np.ndarray, dst: np.ndarray, n: int) -> DenseRuns:
+    """:class:`DenseRuns` of a host edge list sorted by ``dst``."""
+    start = np.ones(dst.shape, bool)
+    start[1:] = dst[1:] != dst[:-1]
+    in_deg = np.bincount(dst, minlength=n)
+    last = np.where(in_deg > 0, np.cumsum(in_deg) - 1, -1)
+    return DenseRuns(src=jnp.asarray(src.astype(np.int32)),
+                     start=jnp.asarray(start),
+                     last=jnp.asarray(last.astype(np.int32)))
 
 
 def _dense_runs(g: Graph) -> DenseRuns:
-    """Host-side :class:`DenseRuns` of a concrete graph."""
-    dst = np.asarray(g.dst)
-    start = np.ones(dst.shape, bool)
-    start[1:] = dst[1:] != dst[:-1]
-    in_deg = np.asarray(g.in_deg, np.int64)
-    last = np.where(in_deg > 0, np.cumsum(in_deg) - 1, -1)
-    return DenseRuns(start=jnp.asarray(start),
-                     last=jnp.asarray(last.astype(np.int32)))
+    """Host-side :class:`DenseRuns` of a concrete graph, with the
+    referenced core's list beside the full one when the core leaves some
+    edges out."""
+    src, dst = np.asarray(g.src), np.asarray(g.dst)
+    in_core = g.reference_levels < 0
+    from_core = in_core[src]
+    if from_core.all():
+        return _runs(src, dst, g.n)
+    # within each run, the edges from outside the core first
+    order = np.argsort(2 * dst.astype(np.int64) + from_core, kind="stable")
+    src, dst, from_core = src[order], dst[order], from_core[order]
+    return _runs(src, dst, g.n)._replace(
+        core=_runs(src[from_core], dst[from_core], g.n),
+        in_core=jnp.asarray(in_core))
 
 
 def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
     """Sum ``vals`` (edges on the last axis) over each vertex's run.
 
-    A segmented Hillis-Steele scan: log2(m) passes of shift-and-add
+    A segmented Hillis-Steele scan: log2(e) passes of shift-and-add
     along the edge axis, then one gather at each run's last edge.  It
-    reads the edge axis log2(m) times but issues no scatter.
+    reads the edge axis log2(e) times but scatters nothing.  The sum at
+    a run's last edge depends only on the run's values counted back from
+    that edge, so exact zeros leading a run leave it bit for bit as the
+    run without them gives it.
     """
     x, f = vals, runs.start
     lead = [(0, 0)] * (x.ndim - 1)
@@ -412,15 +462,46 @@ def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
         return jnp.where(runs.last >= 0, x[..., jnp.maximum(runs.last, 0)], 0)
 
 
-def _gather_sources(vals: jnp.ndarray, g: Graph) -> jnp.ndarray:
-    """Each edge's source value, ``vals[..., src]``."""
+def _walk(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
+    """Push ``vals`` (vertices on the last axis) along one edge list."""
     with jax.named_scope("gather"):
-        return vals[..., g.src]
+        gathered = vals[..., runs.src]
+    return _run_sums(gathered, runs)
+
+
+def _walk_branch(runs: DenseRuns):
+    # lax.cond files each branch's operations under "cond/branch_<i>_fun";
+    # a "push" scope inside keeps push/gather, push/scan and push/readout
+    # contiguous in the operations' names, where a profile reads them.
+    def branch(vals):
+        with jax.named_scope("push"):
+            return _walk(vals, runs)
+    return branch
+
+
+def _dense_push(vals: jnp.ndarray, runs: DenseRuns):
+    """The dense push of ``vals`` ([n] or [B, n]), and whether it walked
+    the core list: a traced bool, or None where there is none.
+
+    A vertex outside the core can hold information only in the first
+    rounds of a solve; once ``vals`` is zero off the core, every edge
+    outside the core list carries an exact zero.  The push checks that on
+    ``vals`` itself and then walks the core list, whose sums equal the
+    full list's bit for bit (:func:`_run_sums`).  Where it does not hold,
+    it walks every edge, so the result never rests on the level theory.
+    """
+    if runs.core is None:
+        return _walk(vals, runs), None
+    off_core = jnp.any(jnp.logical_and(vals != 0,
+                                       jnp.logical_not(runs.in_core)))
+    y = jax.lax.cond(off_core, _walk_branch(runs), _walk_branch(runs.core),
+                     vals)
+    return y, jnp.logical_not(off_core)
 
 
 @register_step_impl("dense")
 class DenseBackend(StepBackend):
-    """Sorted segment-sum over the full dst-sorted COO edge list.
+    """Sorted segment-sum over the dst-sorted COO edge list.
 
     The segment-sum is a segmented scan over each vertex's run of
     in-edges (:func:`_run_sums`), not a scatter-add: XLA's float64
@@ -428,6 +509,12 @@ class DenseBackend(StepBackend):
     5.06M edges, the scan about 0.09 s.  ``ctx`` is the graph's
     :class:`DenseRuns`; a push called with ``ctx=None`` builds it on the
     host from the (concrete) graph.
+
+    Each push walks either all m edges or, when its input is zero on
+    every vertex outside the referenced core, only the core's out-edges
+    (:func:`_dense_push`).  On a rank solve that holds from round K + 2
+    for a deepest weak-unreferenced level K, and on a PPR row seeded in
+    the core from round 1.  The result is the same bit for bit either way.
 
     In float64 both agree with a numpy sum to 1e-12 relative on a v5e.
     In float32 there, a ``[16, n]`` push_batch at web-Google size
@@ -443,15 +530,23 @@ class DenseBackend(StepBackend):
     def prepare(self, g: Graph) -> DenseRuns:
         return _dense_runs(g)
 
+    def core_edges(self, ctx: DenseRuns) -> Optional[int]:
+        return None if ctx.core is None else int(ctx.core.src.shape[-1])
+
+    def push_counted(self, g: Graph, ctx, w: jnp.ndarray):
+        return _dense_push(w, ctx if ctx is not None else _dense_runs(g))
+
+    def push_batch_counted(self, g: Graph, ctx, W: jnp.ndarray):
+        # one gather + one scan over the trailing axis beats B separate
+        # scans: the edge index stream is read once per batch, and one
+        # test over all B rows picks the list.
+        return _dense_push(W, ctx if ctx is not None else _dense_runs(g))
+
     def push(self, g: Graph, ctx, w: jnp.ndarray) -> jnp.ndarray:
-        return _run_sums(_gather_sources(w, g),
-                         ctx if ctx is not None else _dense_runs(g))
+        return self.push_counted(g, ctx, w)[0]
 
     def push_batch(self, g: Graph, ctx, W: jnp.ndarray) -> jnp.ndarray:
-        # one gather + one scan over the trailing axis beats B separate
-        # scans: the edge index stream is read once per batch.
-        return _run_sums(_gather_sources(W, g),
-                         ctx if ctx is not None else _dense_runs(g))  # [B, n]
+        return self.push_batch_counted(g, ctx, W)[0]  # [B, n]
 
 
 @register_step_impl("ell")
@@ -632,7 +727,9 @@ def _ita_round(backend: StepBackend, g: Graph, ctx, h, pi_bar, c, xi,
     ``signed`` selects the |h| activity threshold (incremental updates push
     negative corrections); everything else — accumulate, push, Formula-15
     ops and the Management-thread CNT — is identical by construction, so a
-    fix here reaches the plain, signed and batched solvers alike.
+    fix here reaches the plain, signed and batched solvers alike.  Returns
+    ``(h', pi_bar', n_active, ops, core)``, ``core`` as
+    :meth:`SolverBackend.push_counted` gives it.
     """
     with jax.named_scope("ita_round"):
         mag = jnp.abs(h) if signed else h
@@ -640,12 +737,17 @@ def _ita_round(backend: StepBackend, g: Graph, ctx, h, pi_bar, c, xi,
         h_act = jnp.where(active, h, 0)
         pi_bar = pi_bar + h_act
         with jax.named_scope("push"):
-            pushed = backend.push(g, ctx, h_act * inv_deg * c)
+            pushed, core = backend.push_counted(g, ctx, h_act * inv_deg * c)
         h = jnp.where(active, 0, h) + pushed
         n_active = jnp.sum(active, dtype=jnp.int32)
         ops = jnp.sum(jnp.where(active, g.out_deg, 0).astype(jnp.float32),
                       dtype=jnp.float32)
-        return h, pi_bar, n_active, ops
+        return h, pi_bar, n_active, ops, core
+
+
+def count_core(total, core):
+    """``total`` plus one if a round's push walked the core list."""
+    return total if core is None else total + core.astype(jnp.int32)
 
 
 def ita_step_impl(backend: StepBackend, g: Graph, ctx, h, pi_bar, c, xi,
@@ -656,14 +758,14 @@ def ita_step_impl(backend: StepBackend, g: Graph, ctx, h, pi_bar, c, xi,
     returns ``(h', pi_bar', n_active, ops)``.
     """
     return _ita_round(backend, g, ctx, h, pi_bar, c, xi, inv_deg,
-                      non_dangling, signed=False)
+                      non_dangling, signed=False)[:4]
 
 
 def signed_ita_step_impl(backend: StepBackend, g: Graph, ctx, h, pi_bar, c,
                          xi, inv_deg, non_dangling):
     """Signed variant (|h| threshold) used by the incremental solver."""
     return _ita_round(backend, g, ctx, h, pi_bar, c, xi, inv_deg,
-                      non_dangling, signed=True)
+                      non_dangling, signed=True)[:4]
 
 
 # NOTE: the backend INSTANCE is the static jit key (not its registry name):
@@ -676,18 +778,19 @@ def _ita_loop_jit(g: Graph, ctx, h0, pi_bar0, c, xi, max_iter: int,
     non_dangling = jnp.logical_not(g.dangling_mask)
 
     def cond(state):
-        _, _, n_active, _, it = state
+        _, _, n_active, _, it, _ = state
         return jnp.logical_and(n_active > 0, it < max_iter)
 
     def body(state):
-        h, pi_bar, _, ops_total, it = state
-        h, pi_bar, n_active, ops = _ita_round(backend, g, ctx, h, pi_bar, c,
-                                              xi, inv_deg, non_dangling,
-                                              signed)
-        return h, pi_bar, n_active, ops_total + ops, it + 1
+        h, pi_bar, _, ops_total, it, core_total = state
+        h, pi_bar, n_active, ops, core = _ita_round(
+            backend, g, ctx, h, pi_bar, c, xi, inv_deg, non_dangling, signed)
+        return (h, pi_bar, n_active, ops_total + ops, it + 1,
+                count_core(core_total, core))
 
     init = (h0, pi_bar0, jnp.asarray(1, jnp.int32),
-            jnp.asarray(0.0, jnp.float32), jnp.asarray(0, jnp.int32))
+            jnp.asarray(0.0, jnp.float32), jnp.asarray(0, jnp.int32),
+            jnp.asarray(0, jnp.int32))
     return jax.lax.while_loop(cond, body, init)
 
 
@@ -698,26 +801,33 @@ def run_ita_loop(g: Graph, h0, pi_bar0, *, c: float, xi: float,
 
     Jittable backends get the device-resident ``while_loop``; host-driven
     backends (frontier) run the same step in a python loop.  Returns
-    ``(h, pi_bar, n_active, ops_total, iterations)``.
+    ``(h, pi_bar, n_active, ops_total, iterations, core_rounds)``,
+    ``core_rounds`` None where the backend's ctx holds no core list.
     """
     backend = get_step_impl(impl)
     if ctx is None:
         ctx = backend.prepare(g)
     if backend.capabilities().jittable:
-        return _ita_loop_jit(g, ctx, h0, pi_bar0, float(c), float(xi),
-                             int(max_iter), backend, signed)
-    inv_deg = g.inv_out_deg(h0.dtype)
-    non_dangling = jnp.logical_not(g.dangling_mask)
-    h, pi_bar = h0, pi_bar0
-    ops_total, it = 0.0, 0
-    n_active = jnp.asarray(1, jnp.int32)
-    while it < max_iter:
-        h, pi_bar, n_active, ops = _ita_round(backend, g, ctx, h, pi_bar, c,
-                                              xi, inv_deg, non_dangling,
-                                              signed)
-        ops_total += float(ops)
-        it += 1
-        if int(n_active) == 0:
-            break
-    return h, pi_bar, n_active, jnp.asarray(ops_total, jnp.float32), \
-        jnp.asarray(it, jnp.int32)
+        *out, core_rounds = _ita_loop_jit(g, ctx, h0, pi_bar0, float(c),
+                                          float(xi), int(max_iter), backend,
+                                          signed)
+    else:
+        inv_deg = g.inv_out_deg(h0.dtype)
+        non_dangling = jnp.logical_not(g.dangling_mask)
+        h, pi_bar = h0, pi_bar0
+        ops_total, it, core_rounds = 0.0, 0, 0
+        n_active = jnp.asarray(1, jnp.int32)
+        while it < max_iter:
+            h, pi_bar, n_active, ops, core = _ita_round(
+                backend, g, ctx, h, pi_bar, c, xi, inv_deg, non_dangling,
+                signed)
+            ops_total += float(ops)
+            core_rounds = count_core(core_rounds, core)
+            it += 1
+            if int(n_active) == 0:
+                break
+        out = [h, pi_bar, n_active, jnp.asarray(ops_total, jnp.float32),
+               jnp.asarray(it, jnp.int32)]
+    if backend.core_edges(ctx) is None:
+        core_rounds = None
+    return (*out, core_rounds)

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
 from ..graph.structure import Graph
-from .backends import get_step_impl
+from .backends import count_core, get_step_impl
 
 __all__ = ["BatchSolverResult", "ita_batch", "power_method_batch",
            "solve_pagerank_batch", "one_hot_personalizations"]
@@ -53,7 +53,9 @@ class BatchSolverResult:
     ``max_iter``, ``method`` a tag like ``"ita_batch[dense]"`` naming
     solver family and ``step_impl``, and ``ops`` the Formula-15 edge
     operations summed over rows and rounds (``None`` where a path does
-    not count them: the power family, the mesh and the result cache).
+    not count them: the power family, the mesh and the result cache), and
+    ``core_rounds`` the rounds whose push walked the referenced core's
+    edge list (``None`` there too, and where the backend keeps no list).
     """
 
     pi: jnp.ndarray
@@ -64,13 +66,15 @@ class BatchSolverResult:
     batch: int
     wall_time_s: Optional[float] = None
     ops: Optional[float] = None
+    core_rounds: Optional[int] = None
 
     def stats(self) -> dict:
         return dict(method=self.method, batch=self.batch,
                     iterations=int(self.iterations),
                     residual=float(self.residual),
                     converged=bool(self.converged),
-                    wall_time_s=self.wall_time_s, ops=self.ops)
+                    wall_time_s=self.wall_time_s, ops=self.ops,
+                    core_rounds=self.core_rounds)
 
 
 def one_hot_personalizations(g: Graph, seeds, dtype=jnp.float64) -> jnp.ndarray:
@@ -99,19 +103,21 @@ def normalize_rows(U: jnp.ndarray) -> jnp.ndarray:
 
 
 def _batch_ita_step(backend, g, ctx, H, PiBar, c, xi, inv_deg, non_dangling):
-    """One batched ITA round; returns ``(H', PiBar', n_active, ops)``,
-    ``ops`` being Formula 15 summed over the rows."""
+    """One batched ITA round; returns ``(H', PiBar', n_active, ops, core)``,
+    ``ops`` being Formula 15 summed over the rows and ``core`` as
+    ``SolverBackend.push_batch_counted`` gives it."""
     with jax.named_scope("ita_round"):
         active = jnp.logical_and(H > xi, non_dangling[None, :])
         H_act = jnp.where(active, H, 0)
         PiBar = PiBar + H_act
         with jax.named_scope("push"):
-            pushed = backend.push_batch(g, ctx, H_act * inv_deg[None, :] * c)
+            pushed, core = backend.push_batch_counted(
+                g, ctx, H_act * inv_deg[None, :] * c)
         H = jnp.where(active, 0, H) + pushed
         n_active = jnp.sum(active, dtype=jnp.int32)
         ops = jnp.sum(jnp.where(active, g.out_deg[None, :], 0)
                       .astype(jnp.float32), dtype=jnp.float32)
-        return H, PiBar, n_active, ops
+        return H, PiBar, n_active, ops, core
 
 
 def _ita_batch_loop_impl(g: Graph, ctx, H0, c, xi, max_iter: int, backend):
@@ -119,18 +125,28 @@ def _ita_batch_loop_impl(g: Graph, ctx, H0, c, xi, max_iter: int, backend):
     non_dangling = jnp.logical_not(g.dangling_mask)
 
     def cond(state):
-        _, _, n_active, it, _ = state
+        _, _, n_active, it, _, _ = state
         return jnp.logical_and(n_active > 0, it < max_iter)
 
     def body(state):
-        H, PiBar, _, it, ops_total = state
-        H, PiBar, n_active, ops = _batch_ita_step(
+        H, PiBar, _, it, ops_total, core_total = state
+        H, PiBar, n_active, ops, core = _batch_ita_step(
             backend, g, ctx, H, PiBar, c, xi, inv_deg, non_dangling)
-        return H, PiBar, n_active, it + 1, ops_total + ops
+        return (H, PiBar, n_active, it + 1, ops_total + ops,
+                count_core(core_total, core))
 
     init = (H0, jnp.zeros_like(H0), jnp.asarray(1, jnp.int32),
-            jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32))
+            jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32),
+            jnp.asarray(0, jnp.int32))
     return jax.lax.while_loop(cond, body, init)
+
+
+def _counters(backend, ctx, ops, core_rounds) -> dict:
+    """A batched loop's counters as :class:`BatchSolverResult` fields."""
+    if backend.core_edges(ctx) is None:
+        core_rounds = None
+    return dict(ops=None if ops is None else float(ops),
+                core_rounds=None if core_rounds is None else int(core_rounds))
 
 
 # static key is the backend instance, so re-registration invalidates traces
@@ -182,21 +198,23 @@ def ita_batch(
     H0 = (jnp.asarray(p_batch, dtype) * g.n).astype(dtype)
     t0 = time.perf_counter()
     if backend.capabilities().jittable:
-        # the Formula-15 counter is the loop's last output; a stand-in
-        # loop with the four outputs of old (bench/tests/test_control.py
-        # swaps one in) counts none
-        H, PiBar, n_active, it, *ops = _ita_batch_loop(
+        # the counters are the loop's last outputs; a stand-in loop with
+        # the four outputs of old (bench/tests/test_control.py swaps one
+        # in) counts none
+        H, PiBar, n_active, it, *counters = _ita_batch_loop(
             g, ctx, H0, float(c), float(xi), int(max_iter), backend)
-        ops = ops[0] if ops else None
+        ops, core_rounds = (*counters, None, None)[:2]
     else:
         inv_deg = g.inv_out_deg(dtype)
         non_dangling = jnp.logical_not(g.dangling_mask)
         H, PiBar = H0, jnp.zeros_like(H0)
-        it, ops, n_active = 0, 0.0, jnp.asarray(1, jnp.int32)
+        it, ops, core_rounds = 0, 0.0, 0
+        n_active = jnp.asarray(1, jnp.int32)
         while it < max_iter:
-            H, PiBar, n_active, ops_round = _batch_ita_step(
+            H, PiBar, n_active, ops_round, core = _batch_ita_step(
                 backend, g, ctx, H, PiBar, c, xi, inv_deg, non_dangling)
             ops += float(ops_round)
+            core_rounds = count_core(core_rounds, core)
             it += 1
             if int(n_active) == 0:
                 break
@@ -208,7 +226,7 @@ def ita_batch(
             converged=bool(int(n_active) == 0),
             method=f"ita_batch[{step_impl}]", batch=int(p_batch.shape[0]),
             wall_time_s=time.perf_counter() - t0,
-            ops=None if ops is None else float(ops))
+            **_counters(backend, ctx, ops, core_rounds))
     if return_state:
         return result, (PiBar, H)
     return result

@@ -483,7 +483,7 @@ def _batch_dp_loop(mesh: Mesh, backend, c: float, xi: float, max_iter: int,
 
         def body(state):
             H, PiBar, _, it = state
-            H, PiBar, n_loc, _ = _batch_ita_step(backend, g, ctx, H, PiBar,
+            H, PiBar, n_loc, *_ = _batch_ita_step(backend, g, ctx, H, PiBar,
                                                  c, xi, inv_deg, nd)
             return H, PiBar, jax.lax.psum(n_loc, batch_axis), it + 1
 

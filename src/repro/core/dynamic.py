@@ -54,7 +54,7 @@ def ita_residual_state(g: Graph, *, c: float = 0.85, xi: float = 1e-12,
     """
     h0 = jnp.ones((g.n,), dtype)
     pi0 = jnp.zeros((g.n,), dtype)
-    h, pi_bar, n_active, ops, it = run_ita_loop(
+    h, pi_bar, n_active, ops, it, _ = run_ita_loop(
         g, h0, pi0, c=c, xi=xi, max_iter=100_000, impl=step_impl, signed=True,
         ctx=ctx)
     return pi_bar, h, float(ops), int(it)
@@ -112,7 +112,7 @@ def ita_incremental(
         p_vec = jnp.asarray(p, dtype)
     r = p_vec + push(g_new, pi_bar_old) - pi_bar_old
 
-    h, pi_bar, n_active, ops, it = run_ita_loop(
+    h, pi_bar, n_active, ops, it, core = run_ita_loop(
         g_new, r, pi_bar_old, c=c, xi=xi, max_iter=max_iter, impl=step_impl,
         signed=True, ctx=ctx)
     folded = pi_bar + h
@@ -122,6 +122,7 @@ def ita_incremental(
         pi=pi, iterations=int(it), residual=float(xi), ops=float(ops),
         converged=bool(int(n_active) == 0), method="ita_incremental",
         wall_time_s=time.perf_counter() - t0,
+        core_rounds=None if core is None else int(core),
     )
     if return_state:
         return result, (pi_bar, h)

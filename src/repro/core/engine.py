@@ -2,7 +2,14 @@
 
 The paper's central observation (§III) is that dangling and (weakly)
 unreferenced vertices are *structure*: classify them once and every solve
-afterwards exploits the classification for free.  The one-shot entry point
+afterwards exploits the classification for free.  Prepare peels the graph
+by weak-unreferenced level (``Graph.reference_levels``); the dense push
+keeps the referenced core's out-edges as a second edge list and walks it
+in every round whose input is zero outside the core, from round K + 2 of
+a rank solve with deepest level K and from round 1 of a PPR row seeded in
+the core.  It checks that on the input each round, so the classification
+only ever saves work, and the sums are the full list's bit for bit.  The
+one-shot entry point
 ``solve_pagerank(g, method, **kwargs)`` re-derived all of that per call —
 vertex masks, the ELL bucketing, the frontier CSR plan, the backend choice.
 This module turns the derivation into an explicit **prepare** phase and the
@@ -21,7 +28,8 @@ D-Iteration and forward-push serving papers assume:
 
 Prepare phase (one-time, at construction and after a ``DeltaQuery``):
   * vertex classification per §III — dangling / unreferenced masks and
-    counts, materialized on device;
+    counts, materialized on device, and the weak-unreferenced levels,
+    whose deepest finite level is ``level_depth``;
   * backend selection: ``EnginePlan.step_impl="auto"`` resolves by the
     declared :meth:`~repro.core.backends.SolverBackend.cost` estimates
     (``choose_backend``), an explicit name is validated; the per-graph
@@ -68,6 +76,7 @@ from .backends import choose_backend, get_step_impl, resolve_step_impl
 from .cache import CachePolicy, ResultCache
 from .batch import (
     BatchSolverResult,
+    _counters,
     _ita_batch_loop_donated,
     ita_batch,
     normalize_rows,
@@ -210,6 +219,9 @@ class PageRankEngine:
         self.n_dangling = int(jax.device_get(jnp.sum(self.dangling_mask)))
         self.n_unreferenced = int(
             jax.device_get(jnp.sum(self.unreferenced_mask)))
+        # deepest finite weak-unreferenced level K, -1 when every vertex
+        # lies in the referenced core
+        self.level_depth = int(g.reference_levels.max(initial=-1))
         if self.step_impl == "ell":
             # honor the plan's bucketing; Graph.ell caches per (widths,
             # align) so the EllBackend default prepare() would otherwise
@@ -218,6 +230,7 @@ class PageRankEngine:
                               row_align=plan.row_align)
         else:
             self._ctx = self.backend.prepare(g)
+        self.core_edges = self.backend.core_edges(self._ctx)
         if self.mesh is not None:
             if not self.caps.batch_parallel_mesh:
                 raise ValueError(
@@ -249,7 +262,7 @@ class PageRankEngine:
             # prepare-time warming above actually serves the queries.
             for attr in ("_ell_cache", "_ell_part_cache",
                          "_part_cols_cache", "_undirected_cache",
-                         "_graph_version"):
+                         "_levels_cache", "_graph_version"):
                 cache = getattr(g, attr, None)
                 if cache is not None:
                     object.__setattr__(self.graph, attr, cache)
@@ -267,6 +280,8 @@ class PageRankEngine:
             n=self.graph.n, m=self.graph.m,
             n_dangling=self.n_dangling,
             n_unreferenced=self.n_unreferenced,
+            level_depth=self.level_depth,
+            core_edges=self.core_edges,
             step_impl=self.step_impl,
             jittable=self.caps.jittable,
             capabilities=self.caps.summary(),
@@ -301,6 +316,8 @@ class PageRankEngine:
             graph_version=self.graph_version,
             cache=self.cache_policy,
             undirected=self.graph.is_undirected,
+            core_edges=self.core_edges,
+            level_depth=self.level_depth,
         )
 
     def plan(self, query: Query) -> ExecutionPlan:
@@ -449,7 +466,7 @@ class PageRankEngine:
         """
         t0 = time.perf_counter()
         H0 = (p_batch.astype(cfg.dtype) * self.graph.n).astype(cfg.dtype)
-        H, PiBar, n_active, it, ops = _ita_batch_loop_donated(
+        H, PiBar, n_active, it, ops, core_rounds = _ita_batch_loop_donated(
             self.graph, self._ctx, H0, float(cfg.c), float(cfg.xi),
             int(cfg.max_iter), self.backend)
         Pi = normalize_rows(PiBar + H)
@@ -460,7 +477,8 @@ class PageRankEngine:
                 converged=bool(int(n_active) == 0),
                 method=f"ita_batch[{self.step_impl}]",
                 batch=int(p_batch.shape[0]),
-                wall_time_s=time.perf_counter() - t0, ops=float(ops))
+                wall_time_s=time.perf_counter() - t0,
+                **_counters(self.backend, self._ctx, ops, core_rounds))
         if return_state:
             return result, (PiBar, H)
         return result

@@ -22,7 +22,12 @@ Operation accounting reproduces Formula (15):
 and the active-vertex counter is the Management-thread CNT of Algorithm 3.
 
 Beyond-paper fast paths (selected by ``step_impl``; see core/backends.py):
-  * "dense"    — masked SpMV over all m edges (paper-faithful baseline).
+  * "dense"    — masked SpMV over the edge list (paper-faithful baseline);
+                 once h is zero outside the referenced core (§III: a
+                 vertex of weak-unreferenced level k receives nothing
+                 after round k), each round walks only the core's
+                 out-edges.  The round checks that on h itself, and the
+                 core list's sums equal the full list's bit for bit.
   * "frontier" — frontier compression: gathers the active sub-frontier into
                  fixed-size buckets so the per-iteration edge working set
                  shrinks with the active set (attacks the memory term).
@@ -39,7 +44,8 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
 from ..graph.structure import Graph
-from .backends import get_step_impl, ita_step_impl, run_ita_loop
+from .backends import (_ita_round, count_core, get_step_impl,
+                       ita_step_impl, run_ita_loop)
 from .metrics import SolverResult, err_max_rel, res_l2
 
 __all__ = ["ita", "ita_traced", "ita_step", "ita_fixed_point"]
@@ -92,9 +98,10 @@ def ita(
     :class:`repro.core.engine.PageRankEngine` — skips re-preparation."""
     h0 = _default_h0(g, p, dtype)
     t0 = time.perf_counter()
-    h, pi_bar, n_active, ops, it = run_ita_loop(
+    h, pi_bar, n_active, ops, it, *core = run_ita_loop(
         g, h0, jnp.zeros_like(h0), c=c, xi=xi, max_iter=max_iter,
         impl=step_impl, ctx=ctx)
+    core = core[0] if core else None
     # Fold the in-flight residual — including everything parked on dangling
     # vertices — then normalize (Algorithm 3 final step).
     pi_bar = pi_bar + h
@@ -110,6 +117,7 @@ def ita(
             converged=bool(int(n_active) == 0),
             method="ita" if step_impl == "dense" else f"ita[{step_impl}]",
             wall_time_s=wall,
+            core_rounds=None if core is None else int(core),
         )
 
 
@@ -138,18 +146,18 @@ def ita_traced(
     non_dangling = jnp.logical_not(g.dangling_mask)
 
     def _step(h, pb):
-        return ita_step_impl(backend, g, ctx, h, pb, c, xi, inv_deg,
-                             non_dangling)
+        return _ita_round(backend, g, ctx, h, pb, c, xi, inv_deg,
+                          non_dangling, signed=False)
 
     step = jax.jit(_step) if backend.capabilities().jittable else _step
 
     res_hist, active_hist, ops_hist, err_hist = [], [], [], []
     est_prev = None
     ops_total = 0.0
-    it = 0
+    it = core_rounds = 0
     t0 = time.perf_counter()
     while it < max_iter:
-        h, pi_bar, n_active, ops = step(h, pi_bar)
+        h, pi_bar, n_active, ops, core = step(h, pi_bar)
         n_active = int(n_active)
         if n_active == 0 and it > 0:
             break
@@ -163,6 +171,7 @@ def ita_traced(
         active_hist.append(n_active)
         ops_hist.append(float(ops))
         ops_total += float(ops)
+        core_rounds = int(count_core(core_rounds, core))
         it += 1
         if n_active == 0:
             break
@@ -181,6 +190,8 @@ def ita_traced(
         active_history=active_hist,
         ops_history=ops_hist,
         wall_time_s=wall,
+        core_rounds=(core_rounds if backend.core_edges(ctx) is not None
+                     else None),
     )
     if pi_true is not None:
         out.err_history = err_hist  # type: ignore[attr-defined]

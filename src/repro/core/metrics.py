@@ -42,7 +42,9 @@ class SolverResult:
     ``ops`` is the paper's operation count M(T): for the power method
     (2m+n) per iteration; for ITA the sum over iterations of the out-degree
     of the *active* frontier (Formula 15) — the quantity behind the paper's
-    "special vertices decrease ITA's calculations" claim.
+    "special vertices decrease ITA's calculations" claim.  ``core_rounds``
+    counts the rounds whose push walked the referenced core's edge list in
+    place of all m edges (``None`` where the backend keeps no such list).
     """
 
     pi: jnp.ndarray
@@ -56,6 +58,7 @@ class SolverResult:
     active_history: Optional[list] = None
     ops_history: Optional[list] = None
     wall_time_s: Optional[float] = None
+    core_rounds: Optional[int] = None
 
     def stats(self) -> dict:
         return dict(
@@ -65,4 +68,5 @@ class SolverResult:
             ops=float(self.ops),
             converged=bool(self.converged),
             wall_time_s=self.wall_time_s,
+            core_rounds=self.core_rounds,
         )

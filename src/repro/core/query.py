@@ -265,6 +265,18 @@ class PlannerState:
     graph_version: int = 0          # monotone edge-set version (deltas bump)
     cache: Any = None               # CachePolicy when a result cache is on
     undirected: bool = False        # Graph.is_undirected (symmetric edges)
+    core_edges: Optional[int] = None  # the push's core edge list, if any
+    level_depth: int = -1           # deepest weak-unreferenced level K
+
+
+def _core_reason(state: PlannerState) -> list:
+    """The why-line for a push that keeps a core edge list, if it does."""
+    if state.core_edges is None:
+        return []
+    return [f"referenced core (paper §III): {state.core_edges} of "
+            f"{state.m} edges, deepest weak-unreferenced level "
+            f"K={state.level_depth}; a round whose input is zero off the "
+            f"core walks only those, bit for bit the full push"]
 
 
 def _price(backend_name: str, stats: dict, cfg, batch: int = 1) -> dict:
@@ -346,6 +358,7 @@ def _plan_rank(state: PlannerState, q: RankQuery) -> ExecutionPlan:
             mesh=None, cfg=cfg, cost=float("nan"),
             reasons=(f"solver {method!r} consumes no push backend "
                      f"(its own schedule)",))
+    reasons += _core_reason(state)
     if caps.jittable:
         path = "while-loop"
         reasons.append("jittable push -> device-resident jitted solve loop")
@@ -371,7 +384,7 @@ def _plan_batch_common(state: PlannerState, cfg, B: int, kind: str
     caps = state.capabilities
     reasons = [f"engine prepared step_impl={state.step_impl!r} "
                f"({state.backend_reason})",
-               f"capabilities: {caps.summary()}"]
+               f"capabilities: {caps.summary()}", *_core_reason(state)]
     stats = dict(n=state.n, m=state.m, undirected=state.undirected,
                  dtype=np.dtype(getattr(cfg, "dtype", state.dtype)).name)
     price = _price(state.step_impl, stats, cfg, batch=B)

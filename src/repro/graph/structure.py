@@ -99,6 +99,39 @@ class Graph:
         return cached
 
     @property
+    def reference_levels(self) -> np.ndarray:
+        """int32[n] — each vertex's weak-unreferenced level (paper §III),
+        -1 for the referenced core.
+
+        Level 0 is an unreferenced vertex (no in-edges); level k a vertex
+        whose in-neighbours all have levels below k.  Under ITA a vertex of
+        level k receives nothing after round k, whatever h₀ is.  Vertices
+        of no finite level (on a cycle or a self-loop, or reached from one)
+        form the referenced core, which is closed under out-edges.  Peeled
+        Kahn-style on the host: each pass takes off the vertices whose
+        in-edges all come from vertices already peeled, in K + 1 passes of
+        O(m) for a deepest level K.  Cached outside the pytree like
+        :attr:`is_undirected`.
+        """
+        cached = getattr(self, "_levels_cache", None)
+        if cached is None:
+            src, dst = np.asarray(self.src), np.asarray(self.dst)
+            remaining = np.asarray(self.in_deg, np.int64).copy()
+            cached = np.full(self.n, -1, np.int32)
+            peel = np.flatnonzero(remaining == 0)
+            level = 0
+            while peel.size:
+                cached[peel] = level
+                peeled_now = np.zeros(self.n, bool)
+                peeled_now[peel] = True
+                remaining -= np.bincount(dst[peeled_now[src]],
+                                         minlength=self.n)
+                peel = np.flatnonzero((remaining == 0) & (cached < 0))
+                level += 1
+            object.__setattr__(self, "_levels_cache", cached)
+        return cached
+
+    @property
     def graph_version(self) -> int:
         """Monotone edge-set version, bumped by :func:`apply_edge_delta`.
 

@@ -266,7 +266,7 @@ def sharded_round_step(
         nd = jnp.logical_not(g.dangling_mask)
 
         def local(H, PiBar):
-            H2, PiBar2, n_loc, _ = _batch_ita_step(
+            H2, PiBar2, n_loc, *_ = _batch_ita_step(
                 backend, g, bctx, H, PiBar, float(c), float(xi), inv_deg, nd
             )
             return H2, PiBar2, jax.lax.psum(n_loc, "data")

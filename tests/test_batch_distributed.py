@@ -246,7 +246,7 @@ def test_make_ita_batch_step_single_round(small_graph):
     H1, Pi1, n1 = step(H0, jnp.zeros_like(H0),
                        jnp.asarray(part.src_local[0]),
                        jnp.asarray(part.dst_local[0]), inv, nd)
-    H2, Pi2, n2, _ = _batch_ita_step(get_step_impl("dense"), g, None, H0,
+    H2, Pi2, n2, *_ = _batch_ita_step(get_step_impl("dense"), g, None, H0,
                                      jnp.zeros_like(H0), 0.85, 1e-10, inv, nd)
     assert jnp.array_equal(H1, H2) and jnp.array_equal(Pi1, Pi2)
     assert int(n1) == int(n2)

@@ -165,7 +165,7 @@ def test_make_ita_batch_ell_step_single_round_bitwise():
     H1, Pi1, n1 = step(H0, jnp.zeros_like(H0), inv, nd,
                        *_ell_leaf_list(ellc))
     backend = get_step_impl("ell")
-    H2, Pi2, n2, _ = _batch_ita_step(backend, g, backend.prepare(g), H0,
+    H2, Pi2, n2, *_ = _batch_ita_step(backend, g, backend.prepare(g), H0,
                                      jnp.zeros_like(H0), 0.85, 1e-10, inv, nd)
     assert jnp.array_equal(H1, H2) and jnp.array_equal(Pi1, Pi2)
     assert int(n1) == int(n2)

@@ -33,6 +33,8 @@ from repro.kernels.spmv_ell.kernel import TPU_REFUSAL, spmv_ell_bucket
 
 N = TABLE3_PRESETS["web-Google"]["n"]
 M = TABLE3_PRESETS["web-Google"]["m"]
+# the referenced core's out-edges on the benchmark's web-Google stand-in
+M_CORE = 3_449_864
 B = 16
 DTYPE = jnp.float64
 
@@ -74,11 +76,20 @@ def graph(one_chip):
                  in_deg=_struct((N,), jnp.int32, one_chip), n=N, m=M)
 
 
+def _runs(sharding):
+    """Abstract ``DenseBackend.prepare`` context of that graph: the full
+    edge list and the referenced core's, so a push compiles both."""
+    def edge_list(e):
+        return DenseRuns(src=_struct((e,), jnp.int32, sharding),
+                         start=_struct((e,), jnp.bool_, sharding),
+                         last=_struct((N,), jnp.int32, sharding))
+    return edge_list(M)._replace(core=edge_list(M_CORE),
+                                 in_core=_struct((N,), jnp.bool_, sharding))
+
+
 @pytest.fixture(scope="module")
 def runs(one_chip):
-    """Abstract ``DenseBackend.prepare`` context of that graph."""
-    return DenseRuns(start=_struct((M,), jnp.bool_, one_chip),
-                     last=_struct((N,), jnp.int32, one_chip))
+    return _runs(one_chip)
 
 
 def _compile(fn, *args):
@@ -101,6 +112,8 @@ def test_dense_push_compiles(graph, runs, one_chip, batch):
     assert mem.temp_size_in_bytes < 16e9
     # no float64 scatter on TPU: XLA runs it one update at a time
     assert "scatter" not in compiled.as_text()
+    # the full and the core edge list, chosen on the device
+    assert "conditional" in compiled.as_text()
 
 
 def test_ita_solve_compiles(graph, runs, one_chip):
@@ -137,8 +150,7 @@ def test_batch_parallel_loop_compiles_on_4x1(topo):
               dst=_struct((M,), jnp.int32, rep),
               out_deg=_struct((N,), jnp.int32, rep),
               in_deg=_struct((N,), jnp.int32, rep), n=N, m=M)
-    runs = DenseRuns(start=_struct((M,), jnp.bool_, rep),
-                     last=_struct((N,), jnp.int32, rep))
+    runs = _runs(rep)
     run = _batch_dp_loop(mesh, get_step_impl("dense"), 0.85, 1e-10, 10_000,
                          "data")
     compiled = run.lower(

@@ -1,8 +1,9 @@
 """The program's own spans, scopes and counters.
 
   * **device scopes** — the ITA round's HLO names its stages with
-    ``jax.named_scope``: ``ita_round/push/{gather,scan,readout}`` in the
-    single-vector loop and in the batched loop alike;
+    ``jax.named_scope``: ``push/{gather,scan,readout}`` under
+    ``ita_round/push``, in both edge lists' branches of the dense push, in
+    the single-vector loop and in the batched loop alike;
   * **the batched edge counter** — ``BatchSolverResult.ops`` is Formula 15
     summed over the rows: equal to the sum of each row's own ``ita`` solve,
     on the plain batched loop and on the engine's donated path;
@@ -14,6 +15,7 @@
 
 import glob
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -38,8 +40,7 @@ from repro.serve import (
 from repro.serve.service import NullExecutor
 
 XI = 1e-8
-SCOPES = ("ita_round/push/gather", "ita_round/push/scan",
-          "ita_round/push/readout")
+STAGES = ("gather", "scan", "readout")
 
 
 @pytest.fixture(scope="module")
@@ -56,6 +57,7 @@ def seeds(g):
 def _compiled_text(g, seeds, loop: str) -> str:
     backend = get_step_impl("dense")
     ctx = backend.prepare(g)
+    assert ctx.core is not None  # the push chooses between two edge lists
     if loop == "rank":
         h0 = jnp.ones((g.n,), jnp.float64)
         lowered = _ita_loop_jit.lower(g, ctx, h0, jnp.zeros_like(h0), 0.85,
@@ -68,9 +70,16 @@ def _compiled_text(g, seeds, loop: str) -> str:
 
 @pytest.mark.parametrize("loop", ["rank", "batch"])
 def test_push_stages_named_in_compiled_hlo(g, seeds, loop):
-    text = _compiled_text(g, seeds, loop)
-    for scope in SCOPES:
-        assert f"/while/body/{scope}/" in text, scope
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           _compiled_text(g, seeds, loop)))
+    for stage in STAGES:
+        # each stage as a profile reads it (bench/program_trace.py: the
+        # scope's segments in a row), inside the loop's ITA round and push
+        under = {name[:name.index(f"/push/{stage}/")] for name in names
+                 if "/while/body/ita_round/push/" in name
+                 and f"/push/{stage}/" in name}
+        # once in the full list's branch, once in the core list's
+        assert len(under) == 2, (stage, under)
 
 
 @pytest.mark.parametrize("path", ["ita_batch", "donated"])
