@@ -401,6 +401,13 @@ class DenseRuns(NamedTuple):
     finite weak-unreferenced level, which are the tail of every run, since
     each run lists its edges from outside the core first.  ``in_core``
     marks those vertices.  Both are None when the core holds every edge.
+
+    ``carry`` is set on a list laid out for edge deltas
+    (``repro.core.live``): its last ``C = carry.size`` slots are an insert
+    region of runs of its own, and source ``n`` is a pad vertex that always
+    pushes zero (a deleted edge points there).  After the scan, an insert
+    run's last slot adds the sum at position ``carry[j]`` (its vertex's
+    run in the main list, -1 for none), and ``last`` points at that slot.
     """
 
     src: jnp.ndarray    # int32[e]
@@ -408,17 +415,42 @@ class DenseRuns(NamedTuple):
     last: jnp.ndarray   # int32[n]
     core: Optional["DenseRuns"] = None
     in_core: Optional[jnp.ndarray] = None  # bool[n]
+    carry: Optional[jnp.ndarray] = None    # int32[C]
 
 
-def _runs(src: np.ndarray, dst: np.ndarray, n: int) -> DenseRuns:
-    """:class:`DenseRuns` of a host edge list sorted by ``dst``."""
+def _runs(src: np.ndarray, dst: np.ndarray, n: int,
+          slack: Optional[int] = None) -> DenseRuns:
+    """:class:`DenseRuns` of a host edge list sorted by ``dst``; with
+    ``slack``, an empty insert region of that many slots after it."""
     start = np.ones(dst.shape, bool)
     start[1:] = dst[1:] != dst[:-1]
     in_deg = np.bincount(dst, minlength=n)
     last = np.where(in_deg > 0, np.cumsum(in_deg) - 1, -1)
+    carry = None
+    if slack is not None:
+        src = np.concatenate([src, np.full(slack, n, src.dtype)])
+        start = np.concatenate([start, np.ones(slack, bool)])
+        carry = jnp.full((slack,), -1, jnp.int32)
     return DenseRuns(src=jnp.asarray(src.astype(np.int32)),
                      start=jnp.asarray(start),
-                     last=jnp.asarray(last.astype(np.int32)))
+                     last=jnp.asarray(last.astype(np.int32)), carry=carry)
+
+
+def _core_order(g: Graph):
+    """The dense push's edge order, and the referenced core it serves.
+
+    Returns ``(order, from_core, in_core)``: ``order`` sorts the graph's
+    edges by ``dst`` with each run's edges from outside the core first,
+    ``from_core`` marks the sorted edges whose source is in the core, and
+    ``in_core`` the core's vertices; None when the core holds every edge.
+    """
+    src, dst = np.asarray(g.src), np.asarray(g.dst)
+    in_core = g.reference_levels < 0
+    from_core = in_core[src]
+    if from_core.all():
+        return None
+    order = np.argsort(2 * dst.astype(np.int64) + from_core, kind="stable")
+    return order, from_core[order], in_core
 
 
 def _dense_runs(g: Graph) -> DenseRuns:
@@ -426,13 +458,11 @@ def _dense_runs(g: Graph) -> DenseRuns:
     referenced core's list beside the full one when the core leaves some
     edges out."""
     src, dst = np.asarray(g.src), np.asarray(g.dst)
-    in_core = g.reference_levels < 0
-    from_core = in_core[src]
-    if from_core.all():
+    split = _core_order(g)
+    if split is None:
         return _runs(src, dst, g.n)
-    # within each run, the edges from outside the core first
-    order = np.argsort(2 * dst.astype(np.int64) + from_core, kind="stable")
-    src, dst, from_core = src[order], dst[order], from_core[order]
+    order, from_core, in_core = split
+    src, dst = src[order], dst[order]
     return _runs(src, dst, g.n)._replace(
         core=_runs(src[from_core], dst[from_core], g.n),
         in_core=jnp.asarray(in_core))
@@ -446,7 +476,7 @@ def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
     reads the edge axis log2(e) times but scatters nothing.  The sum at
     a run's last edge depends only on the run's values counted back from
     that edge, so exact zeros leading a run leave it bit for bit as the
-    run without them gives it.
+    run without them gives it, and so does the run's place in the list.
     """
     x, f = vals, runs.start
     lead = [(0, 0)] * (x.ndim - 1)
@@ -459,12 +489,22 @@ def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
     if x.shape[-1] == 0:
         return jnp.zeros(vals.shape[:-1] + runs.last.shape, vals.dtype)
     with jax.named_scope("readout"):
+        if runs.carry is not None:
+            # each insert run's last slot takes its vertex's main sum
+            e = x.shape[-1] - runs.carry.shape[0]
+            main = jnp.where(runs.carry >= 0,
+                             x[..., jnp.maximum(runs.carry, 0)], 0)
+            x = jax.lax.dynamic_update_slice_in_dim(
+                x, x[..., e:] + main, e, axis=x.ndim - 1)
         return jnp.where(runs.last >= 0, x[..., jnp.maximum(runs.last, 0)], 0)
 
 
 def _walk(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
     """Push ``vals`` (vertices on the last axis) along one edge list."""
     with jax.named_scope("gather"):
+        if runs.carry is not None:  # the pad vertex n pushes zero
+            vals = jnp.concatenate(
+                [vals, jnp.zeros(vals.shape[:-1] + (1,), vals.dtype)], -1)
         gathered = vals[..., runs.src]
     return _run_sums(gathered, runs)
 

@@ -60,6 +60,24 @@ def ita_residual_state(g: Graph, *, c: float = 0.85, xi: float = 1e-12,
     return pi_bar, h, float(ops), int(it)
 
 
+def _warm_start(g, ctx, pi_bar, p_vec, c, backend):
+    """The in-flight vector a warm start needs on the NEW graph.
+
+    Exact from the run invariant  π̄ + h = p + cP π̄  (which the converged
+    old state satisfies to ξ): under the new graph the required in-flight
+    vector is  h₀ = p + cP'π̄_old − π̄_old.  This form is exact across
+    dangling-status changes — the cancelled form c(P'−P)(π̄+h)+h is NOT: a
+    previously-dangling vertex gaining an edge carries O(1) parked mass in
+    h, and (P'−P) hits it at first order (caught by tests).
+    """
+    with jax.named_scope("delta_warm"):
+        w = pi_bar * g.inv_out_deg(pi_bar.dtype) * c
+        return p_vec + backend.push(g, ctx, w) - pi_bar
+
+
+_warm_start_jit = jax.jit(_warm_start, static_argnames=("backend",))
+
+
 def ita_incremental(
     g_old: Graph,
     g_new: Graph,
@@ -77,7 +95,9 @@ def ita_incremental(
     """Update PageRank after edge insertions/deletions.
 
     r' = c·(P' − P)·ū + h_old, supported on dst(changed edges); runs the
-    signed ITA from (π̄=ū_old, h=r') on the NEW graph.
+    signed ITA from (π̄=ū_old, h=r') on the NEW graph.  Only ``g_new`` is
+    read, and a :class:`~repro.graph.structure.Degrees` does for it where
+    ``ctx`` holds the new graph's edges (the engine's live layout).
 
     ``return_state=True`` returns ``(result, (pi_bar, h))`` — the same
     warm-start pair :func:`ita_residual_state` produces, so a session
@@ -96,21 +116,13 @@ def ita_incremental(
     if ctx is None:
         ctx = backend.prepare(g_new)  # ctx belongs to the NEW graph
     t0 = time.perf_counter()
-
-    def push(g: Graph, x):
-        return backend.push(g, ctx, x * g.inv_out_deg(dtype) * c)
-
-    # Exact warm-start from the run invariant  π̄ + h = p + cP π̄  (which the
-    # converged old state satisfies to ξ): under the NEW graph the required
-    # in-flight vector is  h₀ = p + cP'π̄_old − π̄_old.  This form is exact
-    # across dangling-status changes — the cancelled form c(P'−P)(π̄+h)+h is
-    # NOT: a previously-dangling vertex gaining an edge carries O(1) parked
-    # mass in h, and (P'−P) hits it at first order (caught by tests).
     if p is None:
         p_vec = jnp.ones((g_new.n,), dtype)  # paper scale: h₀ = n·(e/n) = 1
     else:
         p_vec = jnp.asarray(p, dtype)
-    r = p_vec + push(g_new, pi_bar_old) - pi_bar_old
+    warm = (_warm_start_jit if backend.capabilities().jittable
+            else _warm_start)
+    r = warm(g_new, ctx, pi_bar_old, p_vec, float(c), backend)
 
     h, pi_bar, n_active, ops, it, core = run_ita_loop(
         g_new, r, pi_bar_old, c=c, xi=xi, max_iter=max_iter, impl=step_impl,

@@ -26,7 +26,8 @@ D-Iteration and forward-push serving papers assume:
     tk = engine.topk(sources=[3, 17], k=10)         # bit-identical
     ru = engine.update(add=[(5, 9)])                # (tests/test_query_plan)
 
-Prepare phase (one-time, at construction and after a ``DeltaQuery``):
+Prepare phase (one-time, at construction; again per ``DeltaQuery`` only
+where the live layout below does not apply):
   * vertex classification per §III — dangling / unreferenced masks and
     counts, materialized on device, and the weak-unreferenced levels,
     whose deepest finite level is ``level_depth``;
@@ -57,7 +58,11 @@ tests/test_engine.py and tests/test_query_plan.py).
 unnormalized residual pair (π̄, h) across updates, so successive edge
 deltas each cost one *incremental* signed-ITA cascade instead of a
 from-scratch solve, and the state chains — update after update — without
-ever resolving globally.
+ever resolving globally.  On the dense backend without a mesh the first
+delta lays the edge lists out with slack (``core/live.py``); every later
+delta edits that layout in place on the device, compiling nothing and
+costing host work in proportion to the edges added since, and one that
+does not fit lays the graph out again (``relayouts``).
 """
 from __future__ import annotations
 
@@ -85,6 +90,7 @@ from .batch import (
 )
 from .distributed import ita_batch_distributed, resolve_mesh
 from .dynamic import ita_incremental, ita_residual_state
+from .live import LiveLayout
 from .metrics import SolverResult
 from .query import (
     BatchQuery,
@@ -161,6 +167,7 @@ class PageRankEngine:
         # (construction + each update), never per query.
         self.prepare_count = 0
         self._state = None        # (pi_bar, h) residual pair for DeltaQuery
+        self.relayouts = 0        # deltas that overflowed the live layout
         self._donate = jax.default_backend() != "cpu"
         policy = self.engine_plan.cache
         if policy is True:
@@ -182,9 +189,13 @@ class PageRankEngine:
         """One-time per-graph work: classify, bucket, build backend ctx,
         and (when the plan carries a mesh) lay the prepared state out on
         the device grid once so every query reuses the placement."""
-        self.graph = g
-        # the edge-set version cache entries are stamped with; bumped by
-        # apply_edge_delta, so each DeltaQuery advances it through here.
+        self._graph = g
+        self.n = g.n
+        # the dense push's layout for edge deltas, taken at the first
+        # DeltaQuery (core/live.py); None keeps the plain layout
+        self._live = None
+        # the edge-set version cache entries are stamped with; each
+        # DeltaQuery advances it.
         self.graph_version = g.graph_version
         plan = self.engine_plan
         # mesh geometry first: the backend choice is mesh-aware (an (R, C)
@@ -255,7 +266,7 @@ class PageRankEngine:
             # grid once; shard_map then never reshards them per query.
             rep = NamedSharding(self.mesh, PartitionSpec())
             self._ctx = jax.device_put(self._ctx, rep)
-            self.graph = jax.device_put(g, rep)
+            self._graph = jax.device_put(g, rep)
             # device_put builds a NEW Graph pytree, which would silently
             # drop the host-side layout caches (same edge set, so the
             # cached conversions stay valid) — transplant them so the
@@ -265,8 +276,25 @@ class PageRankEngine:
                          "_levels_cache", "_graph_version"):
                 cache = getattr(g, attr, None)
                 if cache is not None:
-                    object.__setattr__(self.graph, attr, cache)
+                    object.__setattr__(self._graph, attr, cache)
         self.prepare_count += 1
+
+    @property
+    def graph(self) -> Graph:
+        """The graph the engine serves.  After a ``DeltaQuery`` on the
+        live layout it is built from the host edge set on first use
+        (O(m)); the delta path itself never needs it."""
+        if self._graph is None:
+            self._graph = self._live.edges.graph()
+        return self._graph
+
+    @property
+    def m(self) -> int:
+        return self._live.edges.m if self._live is not None else self.graph.m
+
+    def _edge_devices(self) -> list:
+        edges = self._graph.src if self._graph is not None else self._ctx.src
+        return sorted(d.id for d in edges.devices())
 
     def describe(self, include_plan: bool = True) -> dict:
         """Prepared-state summary (serving logs, benchmarks).
@@ -277,7 +305,7 @@ class PageRankEngine:
         a query-specific plan themselves, or only read a field).
         """
         d = dict(
-            n=self.graph.n, m=self.graph.m,
+            n=self.n, m=self.m,
             n_dangling=self.n_dangling,
             n_unreferenced=self.n_unreferenced,
             level_depth=self.level_depth,
@@ -287,8 +315,12 @@ class PageRankEngine:
             capabilities=self.caps.summary(),
             mesh=self._mesh_shape,
             # ids of the devices holding the graph's edge arrays
-            devices=sorted(d.id for d in self.graph.src.devices()),
+            devices=self._edge_devices(),
             prepare_count=self.prepare_count,
+            # edge-list slots for added edges, from the first delta on
+            delta_capacity=(self._live.slack if self._live is not None
+                            else None),
+            relayouts=self.relayouts,
             has_residual_state=self._state is not None,
             graph_version=self.graph_version,
             cache=(self.result_cache.stats()
@@ -301,21 +333,23 @@ class PageRankEngine:
     # ------------------------------------------------------------------ #
     # the query plane: plan / run
     # ------------------------------------------------------------------ #
-    def _planner_state(self) -> PlannerState:
+    def _planner_state(self, query: Optional[Query] = None) -> PlannerState:
+        # a delta is planned without building the graph it changes
+        g = self._graph if isinstance(query, DeltaQuery) else self.graph
         return PlannerState(
             step_impl=self.step_impl,
             capabilities=self.caps,
             backend_reason=self._backend_reason,
             mesh_shape=self._mesh_shape,
             donate=self._donate,
-            n=self.graph.n,
-            m=self.graph.m,
+            n=self.n,
+            m=self.m,
             default_method=self.engine_plan.default_method,
             dtype=self.engine_plan.dtype,
             has_residual_state=self._state is not None,
             graph_version=self.graph_version,
             cache=self.cache_policy,
-            undirected=self.graph.is_undirected,
+            undirected=g is not None and g.is_undirected,
             core_edges=self.core_edges,
             level_depth=self.level_depth,
         )
@@ -328,7 +362,7 @@ class PageRankEngine:
         errors (``TypeError``/``ValueError``/``KeyError``) are raised
         here, before any device work.
         """
-        return plan_query(self._planner_state(), query)
+        return plan_query(self._planner_state(query), query)
 
     def run(self, query: Query) -> ResultEnvelope:
         """Execute ``query`` along its plan; the one entry point.
@@ -441,6 +475,44 @@ class PageRankEngine:
 
     def _exec_delta(self, q: DeltaQuery) -> SolverResult:
         plan = self.engine_plan
+        if self.step_impl != "dense" or self.mesh is not None:
+            return self._exec_delta_reprepared(q)
+        if self._live is None:
+            # take the slack: the layout every later delta edits in place
+            self._live = LiveLayout(self.graph)
+            self._ctx = self._live.ctx
+        live = self._live
+        if self._state is None:
+            pi_bar, h, _, _ = ita_residual_state(
+                live.degrees, c=plan.c, xi=plan.update_xi, dtype=plan.dtype,
+                step_impl="dense", ctx=live.ctx)
+            self._state = (pi_bar, h)
+        with TraceAnnotation("engine.delta.apply"):
+            relaid = live.apply(add=q.add, remove=q.remove)
+            self._ctx = jax.block_until_ready(live.ctx)
+        self._graph = None
+        self.graph_version = live.edges.version
+        self.dangling_mask = live.degrees.dangling_mask
+        self.unreferenced_mask = live.degrees.unreferenced_mask
+        self.n_dangling = live.edges.n_dangling
+        self.n_unreferenced = live.edges.n_unreferenced
+        self.core_edges = live.core_edges
+        if relaid:
+            self.relayouts += 1
+            self.level_depth = int(live.levels.max(initial=-1))
+        self.prepare_count += 1
+        pi_bar, h = self._state
+        result, self._state = ita_incremental(
+            live.degrees, live.degrees, pi_bar, h, c=plan.c,
+            xi=plan.update_xi, step_impl="dense", ctx=live.ctx,
+            return_state=True)
+        return dataclasses.replace(result, relayouts=int(relaid),
+                                   core_edges=live.core_edges)
+
+    def _exec_delta_reprepared(self, q: DeltaQuery) -> SolverResult:
+        """A delta on a layout that cannot take it in place: the new
+        graph built on the host and prepared whole."""
+        plan = self.engine_plan
         if self._state is None:
             pi_bar, h, _, _ = ita_residual_state(
                 self.graph, c=plan.c, xi=plan.update_xi,
@@ -529,7 +601,8 @@ class PageRankEngine:
         Maintains the unnormalized residual pair (π̄, h) across calls: the
         first update pays one from-scratch residual solve, every later one
         runs only the signed correction cascade of ``ita_incremental`` on
-        the changed support.  The engine re-prepares for the new structure
-        (masks, bucketing, backend ctx) before solving.
+        the changed support.  The backend ctx follows the new structure
+        first: edited in place on the dense live layout, re-prepared
+        whole elsewhere.
         """
         return self.run(DeltaQuery(add=add, remove=remove)).result

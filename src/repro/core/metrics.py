@@ -59,6 +59,11 @@ class SolverResult:
     ops_history: Optional[list] = None
     wall_time_s: Optional[float] = None
     core_rounds: Optional[int] = None
+    # an edge delta's refresh (DeltaQuery on the live layout): whether the
+    # delta overflowed the layout's slack and re-laid it out (0 or 1), and
+    # the core list's live edges after it
+    relayouts: Optional[int] = None
+    core_edges: Optional[int] = None
 
     def stats(self) -> dict:
         return dict(
@@ -69,4 +74,6 @@ class SolverResult:
             converged=bool(self.converged),
             wall_time_s=self.wall_time_s,
             core_rounds=self.core_rounds,
+            relayouts=self.relayouts,
+            core_edges=self.core_edges,
         )

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Graph", "graph_from_edges", "apply_edge_delta", "validate_graph"]
+__all__ = ["Graph", "Degrees", "LiveEdges", "graph_from_edges",
+           "apply_edge_delta", "validate_graph"]
 
 
 @jax.tree_util.register_dataclass
@@ -206,6 +207,26 @@ class Graph:
         return cache[key]
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Degrees:
+    """A graph's degrees without its edges.
+
+    What the ITA loop reads of a graph whose edges live in a prepared push
+    layout (the dense backend's layout for edge deltas,
+    ``repro.core.live``): it stands in for the :class:`Graph` there, with
+    the same fields and masks, and its shapes do not change when edges do.
+    """
+
+    out_deg: jnp.ndarray
+    in_deg: jnp.ndarray
+    n: int = dataclasses.field(metadata=dict(static=True))
+
+    dangling_mask = Graph.dangling_mask
+    unreferenced_mask = Graph.unreferenced_mask
+    inv_out_deg = Graph.inv_out_deg
+
+
 def graph_from_edges(
     src: np.ndarray,
     dst: np.ndarray,
@@ -251,59 +272,157 @@ def graph_from_edges(
     )
 
 
-def apply_edge_delta(g: Graph, add=(), remove=()) -> Graph:
-    """New :class:`Graph` = ``g`` plus ``add`` minus ``remove`` edge lists.
+def _pairs(edges) -> np.ndarray:
+    """``(src, dst)`` pairs as int64 [k, 2]."""
+    if not isinstance(edges, np.ndarray):
+        edges = list(edges)
+    return np.asarray(edges, dtype=np.int64).reshape(-1, 2)
 
-    ``add``/``remove`` are iterables of ``(src, dst)`` pairs (or empty).
-    Host-side by design, like :func:`graph_from_edges` — dynamic-graph
-    mutation is data-pipeline work; the incremental solver
-    (``repro.core.dynamic``) then corrects the ranking on device without a
-    from-scratch solve.  Removing an edge that is absent, or adding one
-    that already exists, raises ``ValueError`` (silent no-ops would
-    desynchronize a session's residual state from its graph).
+
+def _search(sorted_keys: np.ndarray, keys: np.ndarray):
+    """Position of each key in ``sorted_keys``, and whether it is there."""
+    if not sorted_keys.size:
+        return np.zeros(keys.shape, np.int64), np.zeros(keys.shape, bool)
+    pos = np.minimum(np.searchsorted(sorted_keys, keys), sorted_keys.size - 1)
+    return pos, sorted_keys[pos] == keys
+
+
+class LiveEdges:
+    """A graph's edge set as it takes edge deltas, in O(delta) host work.
+
+    The edges it started from stay in their sorted key array (``base``,
+    key ``dst * n + src``, the :class:`Graph` order); a deleted one is
+    only cleared in ``alive``.  Inserted edges are the sorted keys
+    ``inserted``.  The degrees, ``m`` and the dangling and unreferenced
+    counts are kept up to date.  :meth:`graph` materializes the current
+    edge set as a :class:`Graph`, which is O(m) and only on demand.
     """
-    src = np.asarray(g.src, dtype=np.int64)
-    dst = np.asarray(g.dst, dtype=np.int64)
-    key = dst * np.int64(g.n) + src  # sorted-unique by Graph invariant
-    add = np.asarray(list(add), dtype=np.int64).reshape(-1, 2)
-    remove = np.asarray(list(remove), dtype=np.int64).reshape(-1, 2)
-    for name, arr in (("add", add), ("remove", remove)):
-        if arr.size and (arr.min() < 0 or arr.max() >= g.n):
-            raise ValueError(f"{name} edge endpoint out of range for n={g.n}")
-    if remove.size:
-        rkey = remove[:, 1] * np.int64(g.n) + remove[:, 0]
+
+    def __init__(self, g: "Graph"):
+        self.n = g.n
+        src = np.asarray(g.src, dtype=np.int64)
+        self.base = np.asarray(g.dst, dtype=np.int64) * g.n + src
+        self.alive = np.ones(g.m, bool)
+        self.inserted = np.empty(0, np.int64)
+        self.out_deg = np.array(g.out_deg, dtype=np.int32)
+        self.in_deg = np.array(g.in_deg, dtype=np.int32)
+        self.m = g.m
+        self.n_dangling = int(np.count_nonzero(self.out_deg == 0))
+        self.n_unreferenced = int(np.count_nonzero(self.in_deg == 0))
+        self.version = g.graph_version
+        self._by_src = None
+
+    def _find(self, keys: np.ndarray):
+        """Base index of each key (-1 where none is alive), and whether
+        each key is an inserted edge."""
+        pos, hit = _search(self.base, keys)
+        if self.base.size:
+            hit &= self.alive[pos]
+        return np.where(hit, pos, -1), _search(self.inserted, keys)[1]
+
+    def apply(self, add=(), remove=()):
+        """Remove then add edges; returns ``(removed_base, removed,
+        added)``: the base indices deleted, and the keys removed and
+        added.  Removing an absent edge, adding a present one, a
+        duplicate in either list or an endpoint out of range raises
+        ``ValueError`` and changes nothing (a silent no-op would
+        desynchronize a session's residual state from its graph)."""
+        n = np.int64(self.n)
+        add, remove = _pairs(add), _pairs(remove)
+        for name, arr in (("add", add), ("remove", remove)):
+            if arr.size and (arr.min() < 0 or arr.max() >= n):
+                raise ValueError(f"{name} edge endpoint out of range for "
+                                 f"n={self.n}")
+        rkey = remove[:, 1] * n + remove[:, 0]
         if np.unique(rkey).size != rkey.size:
             raise ValueError("duplicate edges in remove list")
-        missing = ~np.isin(rkey, key)
+        rbase, rins = self._find(rkey)
+        missing = (rbase < 0) & ~rins
         if missing.any():
             raise ValueError(f"cannot remove absent edges: "
                              f"{remove[missing][:4].tolist()}")
-        key = key[~np.isin(key, rkey)]
-    if add.size:
-        akey = add[:, 1] * np.int64(g.n) + add[:, 0]
+        akey = add[:, 1] * n + add[:, 0]
         if np.unique(akey).size != akey.size:
             raise ValueError("duplicate edges in add list")
-        present = np.isin(akey, key)
+        abase, ains = self._find(akey)
+        present = ((abase >= 0) | ains) & ~np.isin(akey, rkey)
         if present.any():
             raise ValueError(f"cannot add existing edges: "
                              f"{add[present][:4].tolist()}")
-        key = np.concatenate([key, akey])
-    g_new = graph_from_edges((key % g.n), (key // g.n), g.n)
-    # Defensive pin, not a fix: graph_from_edges already returns a fresh
-    # Graph with no caches, so nothing can inherit the OLD edge set's ELL
-    # buckets (full-graph or column-partitioned) today.  Pinning empty
-    # caches here makes that invariant explicit and survivable if Graph
-    # construction ever starts copying cached layouts
-    # (tests/test_query_plan.py::TestDeltaEllCache,
-    # tests/test_ell_sharded.py::test_delta_pins_fresh_partition_cache).
+        removed_base = rbase[rbase >= 0]
+        self.alive[removed_base] = False
+        kept = self.inserted[~np.isin(self.inserted, rkey[rins])]
+        self.inserted = np.union1d(kept, akey)
+        touched = np.unique(np.concatenate([add.ravel(), remove.ravel()]))
+        was_dangling = np.count_nonzero(self.out_deg[touched] == 0)
+        was_unref = np.count_nonzero(self.in_deg[touched] == 0)
+        np.add.at(self.out_deg, add[:, 0], 1)
+        np.add.at(self.in_deg, add[:, 1], 1)
+        np.subtract.at(self.out_deg, remove[:, 0], 1)
+        np.subtract.at(self.in_deg, remove[:, 1], 1)
+        self.n_dangling += (np.count_nonzero(self.out_deg[touched] == 0)
+                            - was_dangling)
+        self.n_unreferenced += (np.count_nonzero(self.in_deg[touched] == 0)
+                                - was_unref)
+        self.m += akey.size - rkey.size
+        self.version += 1
+        return removed_base, rkey, akey
+
+    def out_base(self, vertices: np.ndarray) -> np.ndarray:
+        """Indices into ``base`` of the live base out-edges of
+        ``vertices``."""
+        if self._by_src is None:  # CSR by source over the base, once
+            order = np.argsort(self.base % self.n, kind="stable")
+            offsets = np.zeros(self.n + 1, np.int64)
+            np.cumsum(np.bincount(self.base % self.n, minlength=self.n),
+                      out=offsets[1:])
+            self._by_src = (order, offsets)
+        order, offsets = self._by_src
+        vertices = np.asarray(vertices, np.int64)
+        count = offsets[vertices + 1] - offsets[vertices]
+        shift = np.repeat(offsets[vertices] - np.cumsum(count) + count, count)
+        idx = order[np.arange(count.sum()) + shift]
+        return idx[self.alive[idx]]
+
+    def out_edges(self, vertices: np.ndarray) -> np.ndarray:
+        """Keys of the live out-edges of ``vertices``."""
+        ins = self.inserted[np.isin(self.inserted % self.n, vertices)]
+        return np.concatenate([self.base[self.out_base(vertices)], ins])
+
+    def graph(self) -> "Graph":
+        """The current edge set as a fresh :class:`Graph` (O(m)), stamped
+        with the version: one per delta applied since ``g``."""
+        key = np.union1d(self.base[self.alive], self.inserted)
+        g = graph_from_edges(key % self.n, key // self.n, self.n,
+                             dedup=False)
+        # Monotone version stamp: the engine and the result cache key
+        # prepared/cached state on it, so a delta'd graph is *visibly* a
+        # different edge set even to layers that never inspect src/dst
+        # (tests/test_cache.py::test_stale_entry_never_served_after_delta).
+        object.__setattr__(g, "_graph_version", self.version)
+        return g
+
+
+def apply_edge_delta(g: Graph, add=(), remove=()) -> Graph:
+    """New :class:`Graph` = ``g`` plus ``add`` minus ``remove`` edge lists.
+
+    ``add``/``remove`` are iterables of ``(src, dst)`` pairs (or empty),
+    validated as :meth:`LiveEdges.apply` validates them.  Host-side O(m)
+    by design, like :func:`graph_from_edges` — dynamic-graph mutation is
+    data-pipeline work; the incremental solver (``repro.core.dynamic``)
+    then corrects the ranking on device without a from-scratch solve.  A
+    session that takes many deltas keeps a :class:`LiveEdges` instead
+    (``repro.core.live``).  The new graph starts with no layout caches,
+    so nothing can inherit the OLD edge set's ELL buckets
+    (tests/test_query_plan.py::TestDeltaEllCache,
+    tests/test_ell_sharded.py::test_delta_pins_fresh_partition_cache).
+    """
+    live = LiveEdges(g)
+    live.apply(add=add, remove=remove)
+    g_new = live.graph()
     object.__setattr__(g_new, "_ell_cache", {})
     object.__setattr__(g_new, "_ell_part_cache", {})
     object.__setattr__(g_new, "_part_cols_cache", {})
-    # Monotone version stamp: the engine and the result cache key prepared/
-    # cached state on it, so a delta'd graph is *visibly* a different edge
-    # set even to layers that never inspect src/dst
-    # (tests/test_cache.py::test_stale_entry_never_served_after_delta).
-    object.__setattr__(g_new, "_graph_version", g.graph_version + 1)
     return g_new
 
 

@@ -27,7 +27,9 @@ from jax.sharding import PartitionSpec as P
 from repro.core.backends import DenseRuns, _ita_loop_jit, get_step_impl
 from repro.core.batch import _ita_batch_loop_donated
 from repro.core.distributed import _batch_2d_loop, _batch_dp_loop
-from repro.graph import Graph
+from repro.core.dynamic import _warm_start_jit
+from repro.core.live import SLACK, _ListUpdate, _relaid
+from repro.graph import Degrees, Graph
 from repro.graph.generators import TABLE3_PRESETS
 from repro.kernels.spmv_ell.kernel import TPU_REFUSAL, spmv_ell_bucket
 
@@ -37,6 +39,8 @@ M = TABLE3_PRESETS["web-Google"]["m"]
 M_CORE = 3_449_864
 B = 16
 DTYPE = jnp.float64
+# insert slots of each edge list of the live layout (core/live.py)
+C = int(np.ceil(M * SLACK))
 
 
 @pytest.fixture(scope="module")
@@ -137,6 +141,49 @@ def test_donated_batch_loop_compiles(graph, runs, one_chip):
     assert args[:2] == [((M,), False)] * 2  # src, dst are arguments
     assert ((B, N), True) in args           # the [B, n] buffer is donated
     assert "tf.aliasing_output" in lowered.as_text()
+    assert lowered.compile().memory_analysis() is not None
+
+
+def _live_runs(sharding):
+    """Abstract live layout: both edge lists with their insert regions."""
+    def edge_list(e):
+        return DenseRuns(src=_struct((e + C,), jnp.int32, sharding),
+                         start=_struct((e + C,), jnp.bool_, sharding),
+                         last=_struct((N,), jnp.int32, sharding),
+                         carry=_struct((C,), jnp.int32, sharding))
+    return edge_list(M)._replace(core=edge_list(M_CORE),
+                                 in_core=_struct((N,), jnp.bool_, sharding))
+
+
+def _degrees(sharding):
+    return Degrees(out_deg=_struct((N,), jnp.int32, sharding),
+                   in_deg=_struct((N,), jnp.int32, sharding), n=N)
+
+
+def test_live_refresh_compiles(one_chip):
+    """A DeltaQuery's device work on the live layout: the warm start's
+    push and the signed cascade, over the degrees alone."""
+    runs, degrees = _live_runs(one_chip), _degrees(one_chip)
+    h = _struct((N,), DTYPE, one_chip)
+    dense = get_step_impl("dense")
+    warm = _warm_start_jit.lower(degrees, runs, h, h, 0.85, backend=dense)
+    assert "scatter" not in warm.compile().as_text()
+    loop = _ita_loop_jit.lower(degrees, runs, h, h, 0.85, 1e-10,
+                               max_iter=100_000, backend=dense, signed=True)
+    compiled = loop.compile()
+    assert "scatter" not in compiled.as_text()
+    assert "conditional" in compiled.as_text()
+
+
+def test_live_delta_update_compiles(one_chip):
+    """The delta's update of the live layout, in its fixed shapes."""
+    def ints(size):
+        return _struct((size,), jnp.int32, one_chip)
+
+    update = _ListUpdate(src=ints(C), dst=ints(C), kill=ints(C))
+    lowered = _relaid.lower(_live_runs(one_chip), ints(N), ints(N), update,
+                            update, ints(C), _degrees(one_chip), ints(2 * C),
+                            ints(2 * C), ints(2 * C))
     assert lowered.compile().memory_analysis() is not None
 
 
