@@ -468,6 +468,46 @@ def _dense_runs(g: Graph) -> DenseRuns:
         in_core=jnp.asarray(in_core))
 
 
+def _split_gather(vals: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``vals[..., idx]`` for 64-bit floats, both float32 halves of each
+    value fetched with one index.
+
+    A TPU holds a float64 as a pair of float32 words, and its compiler
+    gathers each word on its own: two 1-D gathers over the same indices.
+    On a v5e one gather of two-word rows costs less than either of them
+    (PERF.md, "Where the time goes").  Here each value is split by value
+    into ``hi = f32(v)`` and ``lo = f32(v - hi)``, the halves stacked as
+    rows (``[2, n]``, or ``[2B, n]`` for B rows), gathered once, and
+    added back.  That is exact where a float64 is a float32 pair, as on
+    the TPU; a true float64 loses the bits below ``lo``.  A zero ``lo``
+    takes the sign of ``hi``, so ±0 and infinities come back as they went
+    in.  (The TPU compiler refuses a bitcast of float64 to
+    ``u32[..., 2]``, the other way to one index.)
+    """
+    lead, n = vals.shape[:-1], vals.shape[-1]
+    hi = vals.astype(jnp.float32)
+    lo = jnp.where(hi == vals, jnp.copysign(jnp.zeros_like(hi), hi),
+                   (vals - hi.astype(vals.dtype)).astype(jnp.float32))
+    rows = jnp.concatenate([hi.reshape(math.prod(lead), n),
+                            lo.reshape(math.prod(lead), n)])
+    got = rows[:, idx]
+    k = got.shape[0] // 2
+    out = got[:k].astype(vals.dtype) + got[k:].astype(vals.dtype)
+    return out.reshape(lead + idx.shape)
+
+
+def _gather(vals: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``vals[..., idx]``: one index for both halves of a float64 where it
+    is compiled for the TPU (:func:`_split_gather`); the plain gather
+    elsewhere, and for narrower values, so those keep their bits."""
+    def plain(vals, idx):
+        return vals[..., idx]
+    if vals.dtype != jnp.float64:
+        return plain(vals, idx)
+    return jax.lax.platform_dependent(vals, idx, tpu=_split_gather,
+                                      default=plain)
+
+
 def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
     """Sum ``vals`` (edges on the last axis) over each vertex's run.
 
@@ -477,6 +517,9 @@ def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
     a run's last edge depends only on the run's values counted back from
     that edge, so exact zeros leading a run leave it bit for bit as the
     run without them gives it, and so does the run's place in the list.
+    The readout gather, and the live layout's carry gather, go through
+    :func:`_gather`: on a TPU one index fetches both float32 words of a
+    float64 sum.
     """
     x, f = vals, runs.start
     lead = [(0, 0)] * (x.ndim - 1)
@@ -493,19 +536,25 @@ def _run_sums(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
             # each insert run's last slot takes its vertex's main sum
             e = x.shape[-1] - runs.carry.shape[0]
             main = jnp.where(runs.carry >= 0,
-                             x[..., jnp.maximum(runs.carry, 0)], 0)
+                             _gather(x, jnp.maximum(runs.carry, 0)), 0)
             x = jax.lax.dynamic_update_slice_in_dim(
                 x, x[..., e:] + main, e, axis=x.ndim - 1)
-        return jnp.where(runs.last >= 0, x[..., jnp.maximum(runs.last, 0)], 0)
+        return jnp.where(runs.last >= 0,
+                         _gather(x, jnp.maximum(runs.last, 0)), 0)
 
 
 def _walk(vals: jnp.ndarray, runs: DenseRuns) -> jnp.ndarray:
-    """Push ``vals`` (vertices on the last axis) along one edge list."""
+    """Push ``vals`` (vertices on the last axis) along one edge list.
+
+    The edge gather ``vals[..., src]`` goes through :func:`_gather`: where
+    float64 is a float32 pair (the TPU), both words of each value come
+    with one index, in one gather in place of two.
+    """
     with jax.named_scope("gather"):
         if runs.carry is not None:  # the pad vertex n pushes zero
             vals = jnp.concatenate(
                 [vals, jnp.zeros(vals.shape[:-1] + (1,), vals.dtype)], -1)
-        gathered = vals[..., runs.src]
+        gathered = _gather(vals, runs.src)
     return _run_sums(gathered, runs)
 
 
@@ -555,6 +604,14 @@ class DenseBackend(StepBackend):
     (:func:`_dense_push`).  On a rank solve that holds from round K + 2
     for a deepest weak-unreferenced level K, and on a PPR row seeded in
     the core from round 1.  The result is the same bit for bit either way.
+
+    Each float64 gather of the push (the edge gather, the readout and the
+    live layout's carry) fetches both float32 words of a value with one
+    index where it is compiled for a TPU, which holds a float64 as such a
+    pair and would otherwise gather each word on its own
+    (:func:`_split_gather`).  Only there: on a true float64, as on
+    the CPU, the split would drop bits, so every other platform, and
+    every narrower dtype, keeps the plain gather.
 
     In float64 both agree with a numpy sum to 1e-12 relative on a v5e.
     In float32 there, a ``[16, n]`` push_batch at web-Google size

@@ -446,3 +446,93 @@ class TestTpuRefusal:
         g = web_graph(60, 400, dangling_frac=0.1, seed=13)
         with pytest.raises(NotImplementedError, match="does not lower on TPU"):
             spmv_ell(g.ell(), jnp.ones((g.n,)))
+
+
+def _float32_pairs(kind, shape, rng):
+    """Float64 values that are float32 pairs ``hi + lo``, |lo| ≤ ½ ulp(hi),
+    as a TPU holds them: magnitudes ``10**kind`` (signed), or ±0 and ±inf
+    among such values for ``"specials"``."""
+    mag = 1.0 if kind == "specials" else 10.0 ** kind
+    hi = (mag * rng.uniform(1, 2, shape)
+          * rng.choice([-1, 1], shape)).astype(np.float32)
+    half_ulp = np.spacing(np.abs(hi)) / 2
+    frac = rng.uniform(0.25, 1, shape).astype(np.float32)
+    frac[rng.random(shape) < 0.1] = 0           # hi alone
+    frac[rng.random(shape) < 0.05] = 1          # a tie: lo = ½ ulp(hi)
+    lo = (frac * half_ulp * rng.choice([-1, 1], shape)).astype(np.float32)
+    v = hi.astype(np.float64) + lo.astype(np.float64)
+    if kind == "specials":
+        flat = v.reshape(-1)
+        flat[:8] = [0.0, -0.0, np.inf, -np.inf, 0.0, -0.0, 1.0, -1.0]
+    return v
+
+
+class TestFloat64Gather:
+    """The dense push's float64 gather (``backends._gather``): on the TPU
+    both float32 words of a value come with one index, by a split into
+    ``hi + lo`` and a recombine (``_split_gather``); elsewhere the plain
+    gather."""
+
+    @pytest.mark.parametrize("rows", [None, 3], ids=["vector", "rows"])
+    @pytest.mark.parametrize("kind", [-30, -20, -10, 0, "specials"])
+    def test_split_gather_returns_float32_pairs_bit_for_bit(self, kind, rows):
+        from repro.core.backends import _split_gather
+
+        rng = np.random.default_rng(31)
+        n = 500
+        v = _float32_pairs(kind, (n,) if rows is None else (rows, n), rng)
+        idx = rng.integers(0, n, 2000).astype(np.int32)
+        got = np.asarray(jax.jit(_split_gather)(jnp.asarray(v),
+                                                jnp.asarray(idx)))
+        assert got.shape == v[..., idx].shape
+        np.testing.assert_array_equal(got.view(np.uint64),
+                                      v[..., idx].view(np.uint64))
+
+    @pytest.mark.parametrize("dtype", [np.float64, np.float32])
+    @pytest.mark.parametrize("jit", [True, False], ids=["jit", "eager"])
+    def test_cpu_gather_is_the_plain_gather(self, dtype, jit):
+        from repro.core.backends import _gather, _split_gather
+
+        rng = np.random.default_rng(32)
+        v = rng.standard_normal((4, 300)).astype(dtype)
+        idx = rng.integers(0, 300, 1000).astype(np.int32)
+        gather = jax.jit(_gather) if jit else _gather
+        got = np.asarray(gather(jnp.asarray(v), jnp.asarray(idx)))
+        assert got.dtype == dtype
+        words = np.dtype(f"u{v.itemsize}")
+        np.testing.assert_array_equal(got.view(words),
+                                      v[..., idx].view(words))
+        if dtype == np.float64:
+            # a true float64 is no float32 pair: the split would drop bits
+            split = np.asarray(_split_gather(jnp.asarray(v), jnp.asarray(idx)))
+            assert not np.array_equal(split, v[..., idx])
+
+    @pytest.mark.parametrize("layout", ["plain", "live"])
+    @pytest.mark.parametrize("rows", [None, 5], ids=["push", "push_batch"])
+    @pytest.mark.parametrize("off_core", [False, True],
+                             ids=["core-list", "full-list"])
+    def test_cpu_push_equals_plain_gather_push(self, monkeypatch, layout,
+                                               rows, off_core):
+        """A CPU push gives, in every bit, what the push gives with the
+        plain float64 gather put back in every place."""
+        from repro.core import backends
+        from repro.core.live import LiveLayout
+
+        g = GRAPHS["unref"]()
+        dense = get_step_impl("dense")
+        ctx = dense.prepare(g) if layout == "plain" else LiveLayout(g).ctx
+        assert ctx.core is not None
+        rng = np.random.default_rng(33)
+        shape = (g.n,) if rows is None else (rows, g.n)
+        w = rng.standard_normal(shape) * (rng.random(shape) < 0.6)
+        if not off_core:
+            w = np.where(np.asarray(ctx.in_core), w, 0.0)
+        counted = dense.push_counted if rows is None else \
+            dense.push_batch_counted
+        y, core = counted(g, ctx, jnp.asarray(w))
+        assert bool(core) == (not off_core)
+        monkeypatch.setattr(backends, "_gather",
+                            lambda vals, idx: vals[..., idx])
+        y_plain, _ = counted(g, ctx, jnp.asarray(w))
+        np.testing.assert_array_equal(np.asarray(y).view(np.uint64),
+                                      np.asarray(y_plain).view(np.uint64))

@@ -11,11 +11,16 @@ only one process at a time may load the TPU library, and every test worker
 imports this file.  The persistent compile cache is off around these
 compiles, since a compile for a described chip cannot be read back here.
 
+The dense push's float64 gathers are held to one gather of two-word rows
+each (``backends._split_gather``), read from the compiled program's gather
+operand shapes; a float32 push keeps the plain gather.
+
 The bucketed-ELL kernel is refused by Mosaic today; its test is a strict
 xfail, so a change that makes it lower must flip it (and lift
 ``EllBackend.refused_on``).
 """
-from functools import partial
+import re
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -103,21 +108,56 @@ def _compile(fn, *args):
     return compiled, mem
 
 
-@pytest.mark.parametrize("batch", [1, B], ids=["push", "push_batch"])
-def test_dense_push_compiles(graph, runs, one_chip, batch):
+def _gathers(compiled):
+    """The operand shape of each gather in a compiled program, sorted."""
+    return sorted(re.findall(r"= (\w+\[[\d,]*\])\S* gather\(",
+                             compiled.as_text()))
+
+
+@pytest.fixture(scope="module")
+def dense_push(graph, runs, one_chip):
+    """The dense push compiled once per (rows, dtype): ``push`` for one
+    row, ``push_batch`` for more."""
     dense = get_step_impl("dense")
-    if batch == 1:
-        compiled, mem = _compile(lambda g, r, w: dense.push(g, r, w), graph,
-                                 runs, _struct((N,), DTYPE, one_chip))
-    else:
-        compiled, mem = _compile(lambda g, r, W: dense.push_batch(g, r, W),
-                                 graph, runs, _struct((B, N), DTYPE, one_chip))
+
+    @cache
+    def compile_push(batch, dtype=DTYPE):
+        if batch == 1:
+            return _compile(lambda g, r, w: dense.push(g, r, w), graph, runs,
+                            _struct((N,), dtype, one_chip))
+        return _compile(lambda g, r, W: dense.push_batch(g, r, W), graph,
+                        runs, _struct((batch, N), dtype, one_chip))
+    return compile_push
+
+
+@pytest.mark.parametrize("batch", [1, B], ids=["push", "push_batch"])
+def test_dense_push_compiles(dense_push, batch):
+    compiled, mem = dense_push(batch)
     # the [B, m] gather is the largest buffer: it must fit one chip
     assert mem.temp_size_in_bytes < 16e9
     # no float64 scatter on TPU: XLA runs it one update at a time
     assert "scatter" not in compiled.as_text()
     # the full and the core edge list, chosen on the device
     assert "conditional" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch", [1, B], ids=["push", "push_batch"])
+def test_float64_push_gathers_both_words_with_one_index(dense_push, batch):
+    """A TPU holds a float64 as two float32 words and would gather each on
+    its own; the push stacks them as rows and gathers once per list walk
+    (``_split_gather``): one edge gather per list, one readout each."""
+    rows = 2 * batch
+    assert _gathers(dense_push(batch)[0]) == sorted(
+        [f"f32[{rows},{M}]", f"f32[{rows},{M_CORE}]"]
+        + [f"f32[{rows},{N}]"] * 2)
+
+
+@pytest.mark.parametrize("batch", [1, B], ids=["push", "push_batch"])
+def test_float32_push_keeps_the_plain_gather(dense_push, batch):
+    shape = "" if batch == 1 else f"{batch},"
+    assert _gathers(dense_push(batch, jnp.float32)[0]) == sorted(
+        [f"f32[{shape}{M}]", f"f32[{shape}{M_CORE}]"]
+        + [f"f32[{shape}{N}]"] * 2)
 
 
 def test_ita_solve_compiles(graph, runs, one_chip):
@@ -160,19 +200,35 @@ def _degrees(sharding):
                    in_deg=_struct((N,), jnp.int32, sharding), n=N)
 
 
-def test_live_refresh_compiles(one_chip):
-    """A DeltaQuery's device work on the live layout: the warm start's
-    push and the signed cascade, over the degrees alone."""
+@pytest.fixture(scope="module")
+def live_refresh(one_chip):
+    """A DeltaQuery's device work on the live layout, compiled: the warm
+    start's push and the signed cascade, over the degrees alone."""
     runs, degrees = _live_runs(one_chip), _degrees(one_chip)
     h = _struct((N,), DTYPE, one_chip)
     dense = get_step_impl("dense")
     warm = _warm_start_jit.lower(degrees, runs, h, h, 0.85, backend=dense)
-    assert "scatter" not in warm.compile().as_text()
     loop = _ita_loop_jit.lower(degrees, runs, h, h, 0.85, 1e-10,
                                max_iter=100_000, backend=dense, signed=True)
-    compiled = loop.compile()
+    return {"warm": warm.compile(), "loop": loop.compile()}
+
+
+def test_live_refresh_compiles(live_refresh):
+    assert "scatter" not in live_refresh["warm"].as_text()
+    compiled = live_refresh["loop"]
     assert "scatter" not in compiled.as_text()
     assert "conditional" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["warm", "loop"])
+def test_live_refresh_gathers_both_words_with_one_index(live_refresh,
+                                                        program):
+    """On the live layout too, each float64 gather is one: the edge gather
+    of each padded list, its carry gather over the insert region and its
+    readout."""
+    assert _gathers(live_refresh[program]) == sorted(
+        [f"f32[2,{M + C}]", f"f32[2,{M_CORE + C}]"]
+        + [f"f32[2,{C}]"] * 2 + [f"f32[2,{N}]"] * 2)
 
 
 def test_live_delta_update_compiles(one_chip):
