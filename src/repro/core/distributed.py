@@ -52,9 +52,6 @@ Batched PPR (``ita_batch_distributed``): the serving shape.  A [B, n]
         with the single-device batch to ~xi.
 
     See docs/SHARDING.md for the layout diagrams and byte counts.
-
-``build_pagerank_job`` exposes the 2-D step as a LoweringJob so the
-paper's own workload participates in the multi-pod dry-run + roofline.
 """
 from __future__ import annotations
 
@@ -78,9 +75,8 @@ from .backends import (
 from .batch import BatchSolverResult, _batch_ita_step, normalize_rows
 from .metrics import SolverResult
 
-__all__ = ["ita_distributed_1d", "ita_distributed_2d", "build_pagerank_job",
-           "make_ita_2d_step", "make_ita_batch_step",
-           "make_ita_batch_ell_step", "ita_batch_distributed",
+__all__ = ["ita_distributed_1d", "ita_distributed_2d", "make_ita_2d_step",
+           "make_ita_batch_step", "make_ita_batch_ell_step", "ita_batch_distributed",
            "resolve_mesh"]
 
 
@@ -789,75 +785,6 @@ def ita_batch_distributed(
 
 
 # ---------------------------------------------------------------------------
-# dry-run job (abstract shapes — no edges materialised)
-# ---------------------------------------------------------------------------
-def build_pagerank_job(spec, cell, mesh: Mesh):
-    from ..launch.steps import LoweringJob  # local import to avoid cycle
-
-    meta = cell.meta
-    n, m = meta["n"], meta["m"]
-    row_axis, col_axis = "data", "model"
-    R, C = mesh.shape[row_axis], mesh.shape[col_axis]
-    if "pod" in mesh.axis_names:
-        # pod extends the row axis: 2 pods × 16 rows = 32 dst-block groups
-        row_axis = ("pod", "data")
-        R = mesh.shape["pod"] * mesh.shape["data"]
-
-    n_pad = ((n + R * C - 1) // (R * C)) * (R * C)
-    nr, nc, sub = n_pad // R, n_pad // C, n_pad // (R * C)
-    e_pad = ((int(m / (R * C) * 1.3) + 8 + 7) // 8) * 8
-
-    c, xi = 0.85, 1e-10
-    dtype = jnp.float32
-
-    col_spec = P(col_axis)
-    edge_spec = P(row_axis, col_axis, None)
-
-    def step(h, pi_bar, src_blk, dst_blk, inv_deg, nd):
-        src_blk, dst_blk = src_blk[0, 0], dst_blk[0, 0]
-        active = jnp.logical_and(h > xi, nd)
-        h_act = jnp.where(active, h, 0)
-        pi_bar = pi_bar + h_act
-        w = h_act * inv_deg * c
-        wp = jnp.concatenate([w, jnp.zeros((1,), w.dtype)])
-        contrib = wp[src_blk]
-        partial_r = jax.ops.segment_sum(contrib, dst_blk, num_segments=nr + 1)[:nr]
-        y_sub = jax.lax.psum_scatter(partial_r, col_axis, scatter_dimension=0,
-                                     tiled=True)
-        h_new = jax.lax.all_gather(y_sub, row_axis, axis=0, tiled=True)
-        h = jnp.where(active, 0, h) + h_new
-        n_active = jax.lax.psum(jnp.sum(active, dtype=jnp.int32), col_axis)
-        return h, pi_bar, n_active
-
-    sm = shard_map(step, mesh=mesh,
-                   in_specs=(col_spec, col_spec, edge_spec, edge_spec,
-                             col_spec, col_spec),
-                   out_specs=(col_spec, col_spec, P()),
-                   check_vma=False)
-
-    args = (
-        jax.ShapeDtypeStruct((n_pad,), dtype),
-        jax.ShapeDtypeStruct((n_pad,), dtype),
-        jax.ShapeDtypeStruct((R, C, e_pad), jnp.int32),
-        jax.ShapeDtypeStruct((R, C, e_pad), jnp.int32),
-        jax.ShapeDtypeStruct((n_pad,), dtype),
-        jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
-    )
-    ns = lambda spec_: NamedSharding(mesh, spec_)
-    in_sh = (ns(col_spec), ns(col_spec), ns(edge_spec), ns(edge_spec),
-             ns(col_spec), ns(col_spec))
-    return LoweringJob(
-        name=f"pagerank:{cell.name}",
-        step_fn=sm,
-        args=args,
-        in_shardings=in_sh,
-        rules=None,
-        donate_argnums=(0, 1),
-        static_meta=dict(n=n, m=m, n_pad=n_pad, e_pad=e_pad, R=R, C=C),
-    )
-
-
-# ---------------------------------------------------------------------------
 # beyond-paper: compressed-exchange 2-D ITA (bf16 wire + error feedback)
 # ---------------------------------------------------------------------------
 def make_ita_2d_step_compressed(mesh: Mesh, part_shapes: dict, c: float,
@@ -865,8 +792,8 @@ def make_ita_2d_step_compressed(mesh: Mesh, part_shapes: dict, c: float,
                                 col_axis: str = "model"):
     """2-D ITA step with HALF the wire bytes: the pushed partials cross the
     ICI in bfloat16, while per-device state stays in full precision with a
-    local error-feedback accumulator (the same Seide/EF trick as the
-    gradient compressor in train/optimizer.py).
+    local error-feedback accumulator (Seide et al.'s error feedback for
+    1-bit gradient exchange).
 
     The paper's central systems claim is ITA's O(1)-scalar bandwidth; this
     variant halves that constant.  Quantisation noise does not bias the

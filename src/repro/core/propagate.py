@@ -1,5 +1,5 @@
-"""The single edge-propagation primitive shared by every solver and by GNN
-message passing: one application of the raw transition matrix ``P``.
+"""The single edge-propagation primitive shared by every solver: one
+application of the raw transition matrix ``P``.
 
     (P @ x)_i  =  sum_{j : (j->i) in E}  x_j / out_deg(j)
 
@@ -38,8 +38,8 @@ def spmv_p(g: Graph, x: jnp.ndarray, *, inv_deg: jnp.ndarray | None = None) -> j
 def push_weighted(g: Graph, per_src: jnp.ndarray) -> jnp.ndarray:
     """Scatter an arbitrary per-source scalar along edges (no 1/deg scale).
 
-    Used by GNN layers (messages already scaled) and by the forward-push
-    solver (residual already divided by degree).
+    For values already scaled per source, e.g. a residual already divided
+    by degree.
     """
     return jax.ops.segment_sum(per_src[g.src], g.dst, num_segments=g.n)
 

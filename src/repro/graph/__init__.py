@@ -1,4 +1,4 @@
-"""Graph substrate: containers, generators, partitioners, samplers."""
+"""Graph substrate: containers, generators, partitioners."""
 from .generators import TABLE3_PRESETS, erdos_renyi, paper_dataset, random_dag, web_graph
 from .structure import (
     Degrees,

@@ -119,7 +119,7 @@ def random_dag(n: int, m: int, *, seed: int = 0) -> Graph:
 
 
 # ---------------------------------------------------------------------------
-# Paper Table 3 presets — full-size stats for dry-run/roofline, and a
+# Paper Table 3 presets — full-size stats, and a
 # `scale` knob so CPU benchmarks run the same *shape* of graph smaller.
 # ---------------------------------------------------------------------------
 TABLE3_PRESETS: dict[str, dict] = {
@@ -136,7 +136,7 @@ def paper_dataset(name: str, *, scale: float = 1.0, seed: int = 0) -> Graph:
 
     ``scale`` shrinks n and m proportionally (dangling fraction preserved),
     so the CPU reproduction runs the paper's graph *shapes* at tractable
-    size while the dry-run exercises the full-size shapes symbolically.
+    size; ``scale=1.0`` is the published size.
     """
     p = TABLE3_PRESETS[name]
     n = max(int(p["n"] * scale), 64)

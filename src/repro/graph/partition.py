@@ -1,4 +1,4 @@
-"""Edge partitioners for distributed ITA / GNN full-graph training.
+"""Edge partitioners for distributed ITA.
 
 1-D: vertices split into R contiguous dst-blocks; device r owns all edges
 whose dst lands in block r.  h is replicated; the per-step collective is

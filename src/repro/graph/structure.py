@@ -5,7 +5,6 @@ metadata.  This single structure backs:
 
   * the paper's ITA / power-method / forward-push / Monte-Carlo solvers
     (``repro.core``),
-  * GNN message passing (``repro.models.gnn``),
   * the 1-D / 2-D edge partitioners used by the distributed runtime
     (``repro.graph.partition``).
 
@@ -167,7 +166,7 @@ class Graph:
         """Bucketed-ELL view of this graph (``repro.sparse.ell``), cached.
 
         Conversion is host-side O(m) work; solvers and kernels that consume
-        the ELL layout (the ``"ell"`` step backend, GNN aggregation) go
+        the ELL layout (the ``"ell"`` step backend) go
         through here so the cost is paid once per (graph, widths) pair.
         The cache lives outside the pytree: jit/vmap boundaries see only
         the edge arrays, and flattened copies simply rebuild on first use.
@@ -441,7 +440,7 @@ def csr_from_graph(g: Graph, by: str = "src") -> tuple[np.ndarray, np.ndarray]:
     """Host-side CSR (offsets, indices).
 
     ``by='src'`` gives out-neighbour lists (random-walk / Monte-Carlo use);
-    ``by='dst'`` gives in-neighbour lists (pull-style SpMV / samplers).
+    ``by='dst'`` gives in-neighbour lists (pull-style SpMV).
     """
     src, dst = np.asarray(g.src), np.asarray(g.dst)
     if by == "src":

@@ -1,14 +1,11 @@
-"""Pallas TPU kernels (compute hot-spots), each with ops.py + ref.py.
+"""Pallas TPU kernels, each with ops.py + ref.py.
 
-  spmv_ell         — the paper's push (bucketed-ELL SpMV) + fused ITA step
-  flash_attention  — decode (flash-decode) + causal prefill
+  spmv_ell  — the paper's push (bucketed-ELL SpMV) + fused ITA step
 
 CPU container note: kernels validate under interpret=True; the ops.py
 wrappers dispatch to the jnp oracle on non-TPU backends so every higher
 layer still compiles (DESIGN.md §2).
 """
-from .flash_attention import attention_decode, attention_prefill_causal
 from .spmv_ell import ita_step_ell, spmv_ell
 
-__all__ = ["attention_decode", "attention_prefill_causal", "ita_step_ell",
-           "spmv_ell"]
+__all__ = ["ita_step_ell", "spmv_ell"]

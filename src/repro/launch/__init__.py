@@ -1,1 +1,1 @@
-"""Launch layer: meshes, sharding rules, dry-run, drivers."""
+"""Launch layer: the command-line drivers and the compile cache."""

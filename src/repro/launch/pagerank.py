@@ -4,8 +4,7 @@
         --scale 0.05 --method ita --xi 1e-10 --step-impl ell
 
 Single-device by default; ``--partition 1d|2d`` runs the distributed
-solvers over whatever devices exist (the dry-run exercises the same code
-on the 512-device production mesh).  ``--batch B`` switches to the serving
+solvers over whatever devices exist.  ``--batch B`` switches to the serving
 shape: B one-hot personalized-PageRank queries solved in one device pass
 (a ``PPRQuery`` through ``PageRankEngine.run``; the request-loop driver
 around the same path is ``repro.launch.ppr_serve``).  ``--explain`` prints

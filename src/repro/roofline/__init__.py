@@ -1,8 +1,7 @@
 """Roofline analysis: hardware specs, HLO cost parsing, measured planner costs."""
 
-from .analysis import RooflineReport, analyze_compiled
 from .hlo_costs import HloCosts, parse_hlo_costs
-from .hw import HW, CPULowering, TPUv5e, device_kind, spec_for_device_kind
+from .hw import CPULowering, TPUv5e, device_kind, spec_for_device_kind
 from .planner_costs import (
     CostTable,
     PlanCost,
@@ -17,15 +16,12 @@ from .planner_costs import (
 )
 
 __all__ = [
-    "HW",
     "CPULowering",
     "CostTable",
     "HloCosts",
     "PlanCost",
-    "RooflineReport",
     "StepCostSample",
     "TPUv5e",
-    "analyze_compiled",
     "device_kind",
     "get_cost_table",
     "measure_sharded_step",

@@ -1,9 +1,9 @@
 """Loop-aware HLO cost model.
 
-``compiled.cost_analysis()`` counts every while-body ONCE — under a
-lax.scan-over-layers design (HLO size independent of depth, DESIGN.md) that
-undercounts a 24-layer model by ~24x.  This module re-derives costs from
-the optimized HLO text with loop-trip multipliers:
+``compiled.cost_analysis()`` counts every while-body ONCE — a loop of N
+rounds or a scan of N steps is undercounted N-fold (DESIGN.md §5).  This
+module re-derives costs from the optimized HLO text with loop-trip
+multipliers:
 
   * computations are parsed into (name -> ops);
   * every ``while`` op publishes ``"known_trip_count":{"n":"N"}`` in its

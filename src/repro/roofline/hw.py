@@ -21,7 +21,7 @@ import dataclasses
 
 import jax
 
-__all__ = ["TPUChip", "TPUv5e", "CPULowering", "HW", "SPECS",
+__all__ = ["TPUChip", "TPUv5e", "CPULowering", "SPECS",
            "device_kind", "spec_for_device_kind"]
 
 
@@ -49,8 +49,6 @@ CPULowering = TPUChip(
     ici_link_bandwidth=25e9,
     hbm_bytes=64e9,
 )
-
-HW = TPUv5e
 
 SPECS = {"TPU v5 lite": TPUv5e, "cpu": CPULowering}
 

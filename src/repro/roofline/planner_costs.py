@@ -314,7 +314,7 @@ def measure_sharded_step(
     Needs R*C live devices (``resolve_mesh`` raises otherwise).  For C > 1
     the parsed collective bytes are the per-device ``psum_scatter`` traffic
     the analytic table in docs/SHARDING.md predicts — the contract tests in
-    tests/test_roofline.py hold the two within a stated tolerance.  For
+    tests/test_cost_model.py hold the two within a stated tolerance.  For
     C == 1 the lowered round is the real batch-parallel schedule (each
     device runs the backend's own ``push_batch``; docs table: collective
     "none" beyond the scalar n_active psum).

@@ -1,4 +1,4 @@
-"""Sparse substrate: segment ops, embedding bag, bucketed-ELL layout."""
+"""Sparse substrate: the bucketed-ELL layout."""
 from .ell import (
     ELLBucket,
     ELLCols,
@@ -9,19 +9,8 @@ from .ell import (
     spmv_ell_cols_ref,
     spmv_ell_ref,
 )
-from .segment_ops import (
-    embedding_bag,
-    scatter_concat_stats,
-    segment_max,
-    segment_mean,
-    segment_min,
-    segment_softmax,
-    segment_sum,
-)
 
 __all__ = [
     "ELLBucket", "ELLCols", "ELLColsBucket", "ELLGraph",
-    "ell_cols_from_graph", "ell_from_graph", "embedding_bag",
-    "scatter_concat_stats", "segment_max", "segment_mean", "segment_min",
-    "segment_softmax", "segment_sum", "spmv_ell_cols_ref", "spmv_ell_ref",
+    "ell_cols_from_graph", "ell_from_graph", "spmv_ell_cols_ref", "spmv_ell_ref",
 ]

@@ -13,10 +13,9 @@ tiles.  We therefore re-block the dst-sorted edge list into ELL buckets:
     segment_sum (heavy-tail rows are rare but huge in web graphs — padding
     them would dominate the footprint).
 
-This is the layout consumed by the Pallas kernel ``repro.kernels.spmv_ell``
-and, shape-for-shape, by GNN neighbour aggregation.  Padding overhead is
-reported by ``ELLGraph.fill_stats`` and asserted < 2x in tests for
-power-law graphs.
+This is the layout consumed by the Pallas kernel ``repro.kernels.spmv_ell``.
+Padding overhead is reported by ``ELLGraph.fill_stats`` and asserted < 2x
+in tests for power-law graphs.
 """
 from __future__ import annotations
 
@@ -325,7 +324,7 @@ def spmv_ell_ref(ell: ELLGraph, w: jnp.ndarray) -> jnp.ndarray:
     """Pure-jnp oracle:  y[dst] = sum over in-edges of w[src].
 
     ``w`` is the *pre-scaled* per-source value (e.g. c*h*inv_deg for ITA,
-    or a message scalar for GNNs); shape [n].  Returns shape [n].
+    or any per-source scalar); shape [n].  Returns shape [n].
     """
     wp = jnp.concatenate([w, jnp.zeros((1,), w.dtype)])  # sentinel slot
     y = jnp.zeros((ell.n + 1,), w.dtype)

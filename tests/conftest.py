@@ -2,13 +2,11 @@
 
 x64 is enabled for the PageRank-solver numerics (the paper pushes xi to
 1e-15; float32 saturates near 1e-7 — the paper's own §VI.B(4) observation
-about double-precision limits, one tier up).  Model code specifies explicit
-float32/bfloat16 dtypes so it is unaffected.
+about double-precision limits, one tier up).
 
 NOTE: XLA_FLAGS / device-count forcing is deliberately NOT set here — smoke
-tests must see the real single CPU device.  Only launch/dryrun.py forces 512
-placeholder devices (and tests that need a small fake mesh spawn a
-subprocess).
+tests must see the real single CPU device; tests that need a small fake
+mesh spawn a subprocess.
 """
 import jax
 

@@ -180,7 +180,7 @@ def test_rl003_fires_on_literal_key_in_src_only():
     def init():
         return random.PRNGKey(42)
     """
-    assert "RL003" in codes(lint("src/repro/models/x.py", src))
+    assert "RL003" in codes(lint("src/repro/core/x.py", src))
     assert codes(lint("tests/x.py", src)) == []  # tests may pin keys
 
 
@@ -191,7 +191,7 @@ def test_rl003_clean_when_seed_is_threaded_in():
     def init(seed):
         return jax.random.PRNGKey(seed)
     """
-    assert codes(lint("src/repro/models/x.py", src)) == []
+    assert codes(lint("src/repro/core/x.py", src)) == []
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""Roofline infrastructure: HLO cost parser (loop multipliers, dot flops,
+"""Cost model: HLO cost parser (loop multipliers, dot flops,
 slice-aware bytes, collectives) against hand-written HLO snippets, an
 end-to-end check on a real compiled module, and the measured-cost contract:
 samples from ``measure_step`` / ``measure_sharded_step`` must agree with
@@ -14,9 +14,8 @@ import jax.numpy as jnp
 import pytest
 from _mesh_env import MESH, needs_devices, run_py
 
-from repro.roofline.analysis import analyze_compiled, parse_shape_bytes
 from repro.roofline.hlo_costs import parse_hlo_costs
-from repro.roofline.hw import HW, TPUv5e, spec_for_device_kind
+from repro.roofline.hw import TPUv5e, spec_for_device_kind
 from repro.roofline.planner_costs import measure_step, roofline_seconds
 
 SIMPLE_HLO = """
@@ -61,18 +60,6 @@ ENTRY %main.2 (x0: f32[64,64]) -> (s32[], f32[64,64]) {
 """
 
 
-class TestShapeParsing:
-    def test_basic_bytes(self):
-        assert parse_shape_bytes("f32[128,256]") == 128 * 256 * 4
-        assert parse_shape_bytes("bf16[10]") == 20
-        assert parse_shape_bytes("(f32[4,4], s32[2])") == 64 + 8
-        assert parse_shape_bytes("pred[8]") == 8
-
-    def test_scalar_and_empty(self):
-        assert parse_shape_bytes("f32[]") == 4
-        assert parse_shape_bytes("token[]") == 0
-
-
 class TestHloCosts:
     def test_simple_dot_flops(self):
         c = parse_hlo_costs(SIMPLE_HLO)
@@ -104,12 +91,6 @@ class TestHloCosts:
         c = parse_hlo_costs(hlo)
         expect = L * 2 * 8 * D * D
         assert abs(c.flops - expect) / expect < 0.01, (c.flops, expect)
-
-    def test_analyze_compiled_terms(self):
-        rep = analyze_compiled("t", "m", 4, {}, SIMPLE_HLO, model_flops=4 * 2 * 128 * 512 * 256)
-        assert rep.compute_s == pytest.approx(2 * 128 * 512 * 256 / HW.peak_bf16_flops)
-        assert rep.useful_ratio == pytest.approx(1.0)
-        assert rep.dominant in ("compute", "memory", "collective")
 
 
 # ---------------------------------------------------------------------------

@@ -5,8 +5,7 @@ device_count=8 (the main pytest process must keep seeing 1 device — see
 conftest).  Asserted:
 
   * ITA 1-D and 2-D shard_map solvers == the single-device reference pi;
-  * shard_map MoE == local sort-dispatch MoE (forward), and its grads flow;
-  * one LM train step under the (2,2,2) pod mesh == unsharded step.
+  * the bf16-wire 2-D solver stays within its precision floor.
 """
 import json
 import os
@@ -71,149 +70,6 @@ def test_ita_2d_matches_reference():
 
 
 @pytest.mark.slow
-def test_moe_sharded_matches_local():
-    out = run_py("""
-        import jax, json
-        import jax.numpy as jnp
-        import numpy as np
-        from repro.models.moe import MoEConfig, moe_init, moe_apply, moe_apply_sharded
-        from repro.launch.sharding import AxisRules
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        cfg = MoEConfig(n_experts=8, top_k=2, capacity_factor=8.0)  # high cf: no drops
-        key = jax.random.PRNGKey(0)
-        p = moe_init(key, 32, 64, cfg, "swiglu", dtype=jnp.float32)
-        T = 256
-        x = jax.random.normal(jax.random.PRNGKey(1), (T, 32), jnp.float32)
-        rules = AxisRules(mesh, {})
-        with mesh:
-            y_sh, aux_sh = jax.jit(lambda p_, x_: moe_apply_sharded(p_, x_, cfg, "swiglu", rules))(p, x)
-        y_loc, aux_loc = moe_apply(p, x, cfg, "swiglu")
-        err = float(jnp.max(jnp.abs(y_sh - y_loc)))
-        # grads flow through the sharded path
-        with mesh:
-            g = jax.jit(jax.grad(lambda p_: jnp.sum(moe_apply_sharded(p_, x, cfg, "swiglu", rules)[0]**2)))(p)
-        gn = float(sum(jnp.sum(jnp.abs(t)) for t in jax.tree_util.tree_leaves(g)))
-        print(json.dumps({"err": err, "grad_sum_finite": bool(np.isfinite(gn)), "gn": gn}))
-    """)
-    # capacity order can differ between global and per-shard slotting only
-    # when tokens drop; cf=8 makes dispatch lossless -> results identical
-    assert out["err"] < 1e-4, out
-    assert out["grad_sum_finite"] and out["gn"] > 0, out
-
-
-@pytest.mark.slow
-def test_lm_train_step_sharded_matches_single():
-    out = run_py("""
-        import jax, json
-        import jax.numpy as jnp
-        import numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.configs import get_config
-        from repro.models.lm import init_lm_params, lm_loss
-        from repro.launch.mesh import lm_axis_rules, lm_param_rules
-        from repro.launch.sharding import axis_rules, param_shardings
-        import dataclasses as dc
-
-        cfg = dc.replace(get_config("qwen1.5-0.5b", smoke=True), remat=True)
-        key = jax.random.PRNGKey(0)
-        params = init_lm_params(key, cfg)
-        batch = {"tokens": jax.random.randint(key, (4, 64), 0, cfg.vocab),
-                 "labels": jax.random.randint(key, (4, 64), 0, cfg.vocab)}
-        loss_single = float(jax.jit(lambda p, b: lm_loss(p, b, cfg)[0])(params, batch))
-
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-        rules = lm_axis_rules(mesh, cfg)
-        psh = param_shardings(params, mesh, lm_param_rules(mesh))
-        params_sh = jax.device_put(params, psh)
-        bsh = {k: jax.device_put(v, NamedSharding(mesh, P(("pod", "data"), None)))
-               for k, v in batch.items()}
-        with mesh, axis_rules(rules):
-            f = jax.jit(lambda p, b: lm_loss(p, b, cfg)[0], in_shardings=(psh, None))
-            loss_sh = float(f(params_sh, bsh))
-        print(json.dumps({"single": loss_single, "sharded": loss_sh,
-                          "diff": abs(loss_single - loss_sh)}))
-    """)
-    assert out["diff"] < 1e-3, out
-
-
-@pytest.mark.slow
-def test_gnn_train_step_sharded_matches_single():
-    out = run_py("""
-        import jax, json
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.configs import get_config
-        from repro.graph import web_graph
-        from repro.graph.batching import full_graph_batch
-        from repro.models.gnn import GNN_REGISTRY
-        from repro.launch.mesh import gnn_axis_rules
-        from repro.launch.sharding import axis_rules
-
-        init, fwd, loss_fn, _ = GNN_REGISTRY["graphcast"]
-        cfg = get_config("graphcast", smoke=True)
-        g = web_graph(512, 4096, dangling_frac=0.1, seed=0)
-        batch = full_graph_batch(g, d_feat=32, n_classes=7)
-        params = init(jax.random.PRNGKey(0), cfg, 32, 0, 7)
-        loss_single = float(jax.jit(lambda p, b: loss_fn(p, b, cfg)[0])(params, batch))
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        with mesh, axis_rules(gnn_axis_rules(mesh)):
-            loss_sh = float(jax.jit(lambda p, b: loss_fn(p, b, cfg)[0])(params, batch))
-        print(json.dumps({"diff": abs(loss_single - loss_sh), "single": loss_single}))
-    """)
-    assert out["diff"] < 1e-4, out
-
-
-@pytest.mark.slow
-def test_gc2d_matches_reference_graphcast():
-    """The ITA-2D-partition message passing (hillclimb path) must compute
-    the same loss as the GSPMD reference implementation."""
-    out = run_py("""
-        import jax, json
-        import jax.numpy as jnp
-        import numpy as np
-        from repro.configs import get_config
-        from repro.graph import web_graph
-        from repro.graph.batching import full_graph_batch
-        from repro.models.gnn import GNN_REGISTRY
-        from repro.models.gnn.graphcast import graphcast_init, graphcast_loss
-        from repro.models.gnn.sharded_mp import gc2d_loss, gc2d_prepare
-
-        cfg = get_config("graphcast", smoke=True)
-        g = web_graph(400, 3200, dangling_frac=0.1, seed=0)
-        rng = np.random.default_rng(0)
-        feats = rng.standard_normal((g.n, 24)).astype(np.float32)
-        pos = rng.standard_normal((g.n, 3)).astype(np.float32)
-        labels = rng.integers(0, 7, g.n).astype(np.int32)
-        lmask = rng.random(g.n) < 0.3
-
-        params = graphcast_init(jax.random.PRNGKey(0), cfg, 24, 4, 7)
-
-        # reference: single-device GraphBatch path (edge feats from pos)
-        import dataclasses
-        batch = full_graph_batch(g, d_feat=24, n_classes=7)
-        batch = dataclasses.replace(
-            batch, nodes=jnp.asarray(feats), pos=jnp.asarray(pos),
-            targets=jnp.asarray(labels), target_mask=jnp.asarray(lmask))
-        loss_ref = float(jax.jit(lambda p, b: graphcast_loss(p, b, cfg)[0])(params, batch))
-
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        geom, batch2, part = gc2d_prepare(g, feats, labels, lmask, pos, mesh)
-        with mesh:
-            loss_2d = float(jax.jit(
-                lambda p, b: gc2d_loss(p, cfg, geom, mesh, b)[0])(params, batch2))
-            # grads flow
-            gr = jax.jit(jax.grad(
-                lambda p: gc2d_loss(p, cfg, geom, mesh, batch2)[0]))(params)
-        gn = float(sum(jnp.sum(jnp.abs(t)) for t in jax.tree_util.tree_leaves(gr)))
-        print(json.dumps({"ref": loss_ref, "ita2d": loss_2d,
-                          "diff": abs(loss_ref - loss_2d),
-                          "grad_finite": bool(np.isfinite(gn)) and gn > 0}))
-    """)
-    assert out["diff"] < 1e-4, out
-    assert out["grad_finite"], out
-
-
-@pytest.mark.slow
 def test_ita_2d_compressed_bounded_error():
     """bf16-wire ITA with error feedback: half the ICI bytes for a bounded
     ~1e-3 relative precision floor (the bf16 mantissa), never divergence."""
@@ -232,29 +88,3 @@ def test_ita_2d_compressed_bounded_error():
         print(json.dumps({"rel": rel, "iters": r.iterations}))
     """)
     assert out["rel"] < 1e-2, out
-
-
-def test_checkpoint_elastic_reshard():
-    """Save on 1 device, restore onto an 8-device mesh with shardings
-    (elastic scaling posture: checkpoints are device-count independent)."""
-    out = run_py("""
-        import jax, json, tempfile
-        import jax.numpy as jnp
-        import numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.train import CheckpointManager
-
-        state = {"w": jnp.arange(64.0).reshape(8, 8),
-                 "step": jnp.asarray(7, jnp.int32)}
-        with tempfile.TemporaryDirectory() as d:
-            mgr = CheckpointManager(d)
-            mgr.save(7, state)
-            mesh = jax.make_mesh((8,), ("data",))
-            sh = {"w": NamedSharding(mesh, P("data", None)),
-                  "step": NamedSharding(mesh, P())}
-            got = mgr.restore(7, state, shardings=sh)
-            ok_val = bool(jnp.all(got["w"] == state["w"]))
-            n_shards = len(got["w"].sharding.device_set)
-        print(json.dumps({"ok_val": ok_val, "n_shards": n_shards}))
-    """)
-    assert out["ok_val"] and out["n_shards"] == 8, out

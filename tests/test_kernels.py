@@ -12,12 +12,6 @@ from _propcheck import strategies as st
 
 from repro.core import ita_step
 from repro.graph import web_graph
-from repro.kernels.flash_attention import (
-    decode_ref,
-    flash_decode,
-    flash_prefill_causal,
-    prefill_causal_ref,
-)
 from repro.kernels.spmv_ell import (
     ita_step_ell,
     spmv_ell,
@@ -84,56 +78,6 @@ class TestSpmvEll:
         stats = ell.fill_stats()
         assert stats["fill_ratio"] < 2.5, stats
         assert stats["overflow_edges"] < 0.25 * g.m, stats
-
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-class TestFlashAttention:
-    @pytest.mark.parametrize("B,Hq,Hk,S,D,bs", [
-        (1, 4, 4, 256, 64, 128),    # MHA
-        (2, 8, 2, 512, 64, 256),    # GQA 4:1
-        (1, 8, 1, 512, 128, 128),   # MQA (granite-34b pattern)
-        (2, 16, 16, 128, 64, 128),  # qwen-ish MHA
-    ])
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_decode_matches_ref(self, B, Hq, Hk, S, D, bs, dtype):
-        rng = np.random.default_rng(B * Hq + S)
-        q = jnp.asarray(rng.standard_normal((B, Hq, D)), dtype)
-        k = jnp.asarray(rng.standard_normal((B, Hk, S, D)), dtype)
-        v = jnp.asarray(rng.standard_normal((B, Hk, S, D)), dtype)
-        out = flash_decode(q, k, v, block_s=bs, interpret=True)
-        ref = decode_ref(q, k, v)
-        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-        np.testing.assert_allclose(np.asarray(out, np.float32),
-                                   np.asarray(ref, np.float32), rtol=tol, atol=tol)
-
-    @pytest.mark.parametrize("B,Hq,Hk,T,D,bq,bs", [
-        (1, 4, 4, 256, 64, 64, 64),
-        (2, 8, 2, 256, 64, 128, 64),
-        (1, 4, 1, 512, 128, 128, 128),
-    ])
-    def test_prefill_causal_matches_ref(self, B, Hq, Hk, T, D, bq, bs):
-        rng = np.random.default_rng(T + D)
-        q = jnp.asarray(rng.standard_normal((B, Hq, T, D)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((B, Hk, T, D)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((B, Hk, T, D)), jnp.float32)
-        out = flash_prefill_causal(q, k, v, block_q=bq, block_s=bs, interpret=True)
-        ref = prefill_causal_ref(q, k, v)
-        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
-
-    def test_causality(self):
-        """Changing future KV must not change past outputs."""
-        rng = np.random.default_rng(7)
-        B, H, T, D = 1, 2, 128, 64
-        q = jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
-        o1 = flash_prefill_causal(q, k, v, block_q=64, block_s=64, interpret=True)
-        k2 = k.at[:, :, T // 2:, :].set(0.0)
-        v2 = v.at[:, :, T // 2:, :].set(0.0)
-        o2 = flash_prefill_causal(q, k2, v2, block_q=64, block_s=64, interpret=True)
-        np.testing.assert_allclose(o1[:, :, : T // 2], o2[:, :, : T // 2], atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

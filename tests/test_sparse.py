@@ -1,122 +1,9 @@
-"""Sparse substrate: segment ops, embedding bag, samplers, partitioners."""
-import jax
-import jax.numpy as jnp
+"""Graph substrate: the 1-D and 2-D edge partitioners."""
 import numpy as np
 import pytest
-from _propcheck import given, settings
-from _propcheck import strategies as st
 
 from repro.graph import web_graph
 from repro.graph.partition import partition_1d, partition_2d
-from repro.graph.sampler import NeighborSampler, sampled_shapes
-from repro.sparse import (
-    embedding_bag,
-    scatter_concat_stats,
-    segment_mean,
-    segment_softmax,
-    segment_sum,
-)
-
-
-class TestSegmentOps:
-    def test_segment_sum_basic(self):
-        data = jnp.asarray([1.0, 2.0, 3.0, 4.0])
-        ids = jnp.asarray([0, 0, 1, 2])
-        out = segment_sum(data, ids, 3)
-        np.testing.assert_allclose(out, [3.0, 3.0, 4.0])
-
-    def test_segment_mean_2d(self):
-        data = jnp.ones((4, 5))
-        ids = jnp.asarray([0, 0, 0, 1])
-        out = segment_mean(data, ids, 2)
-        np.testing.assert_allclose(out, np.ones((2, 5)))
-
-    def test_segment_softmax_normalises(self):
-        logits = jnp.asarray([1.0, 2.0, 3.0, -1.0, 5.0])
-        ids = jnp.asarray([0, 0, 0, 1, 1])
-        p = segment_softmax(logits, ids, 2)
-        np.testing.assert_allclose(float(jnp.sum(p[:3])), 1.0, rtol=1e-6)
-        np.testing.assert_allclose(float(jnp.sum(p[3:])), 1.0, rtol=1e-6)
-
-    def test_scatter_concat_stats_shapes(self):
-        data = jnp.asarray(np.random.default_rng(0).random((10, 4)))
-        ids = jnp.asarray([0] * 5 + [1] * 5)
-        out = scatter_concat_stats(data, ids, 2)
-        assert out.shape == (2, 16)  # mean/max/min/std x 4
-
-    @settings(max_examples=20, deadline=None)
-    @given(n=st.integers(1, 50), k=st.integers(1, 200), seed=st.integers(0, 999))
-    def test_segment_sum_matches_numpy(self, n, k, seed):
-        rng = np.random.default_rng(seed)
-        data = rng.random(k)
-        ids = np.sort(rng.integers(0, n, k))
-        ref = np.zeros(n)
-        np.add.at(ref, ids, data)
-        out = segment_sum(jnp.asarray(data), jnp.asarray(ids), n)
-        np.testing.assert_allclose(out, ref, atol=1e-12)
-
-
-class TestEmbeddingBag:
-    def test_matches_manual(self):
-        rng = np.random.default_rng(1)
-        table = jnp.asarray(rng.random((20, 6)))
-        ids = jnp.asarray([3, 5, 7, 1, 1])
-        bags = jnp.asarray([0, 0, 1, 1, 2])
-        out = embedding_bag(table, ids, bags, 3)
-        np.testing.assert_allclose(out[0], table[3] + table[5], atol=1e-12)
-        np.testing.assert_allclose(out[2], table[1], atol=1e-12)
-
-    def test_weighted_mean_modes(self):
-        table = jnp.eye(4)
-        ids = jnp.asarray([0, 1])
-        bags = jnp.asarray([0, 0])
-        w = jnp.asarray([2.0, 4.0])
-        out = embedding_bag(table, ids, bags, 1, weights=w)
-        np.testing.assert_allclose(out[0], [2.0, 4.0, 0, 0])
-        out_mean = embedding_bag(table, ids, bags, 1, mode="mean")
-        np.testing.assert_allclose(out_mean[0], [0.5, 0.5, 0, 0])
-
-    def test_grad_flows_to_table(self):
-        table = jnp.ones((10, 3))
-        ids = jnp.asarray([2, 2, 5])
-        bags = jnp.asarray([0, 1, 1])
-        g = jax.grad(lambda t: float(jnp.sum(embedding_bag(t, ids, bags, 2) ** 2))
-                     if False else jnp.sum(embedding_bag(t, ids, bags, 2) ** 2))(table)
-        assert float(jnp.sum(jnp.abs(g[2]))) > 0
-        assert float(jnp.sum(jnp.abs(g[0]))) == 0
-
-
-class TestSampler:
-    def test_shapes_static(self):
-        n_pad, e_pad = sampled_shapes(8, (3, 2))
-        assert n_pad == 8 + 24 + 48 and e_pad == 24 + 48
-
-    def test_sampled_edges_are_real_in_edges(self):
-        g = web_graph(300, 2500, dangling_frac=0.1, seed=0)
-        s = NeighborSampler(g, (4, 3), seed=1)
-        blk = s.sample(np.arange(10))
-        real_edges = set(zip(np.asarray(g.src).tolist(), np.asarray(g.dst).tolist()))
-        gids = blk.node_ids
-        for k in range(blk.src.shape[0]):
-            if not blk.edge_mask[k]:
-                continue
-            u, v = gids[blk.src[k]], gids[blk.dst[k]]
-            assert (u, v) in real_edges, (u, v)
-
-    def test_fanout_bound(self):
-        g = web_graph(300, 6000, dangling_frac=0.0, seed=2)
-        s = NeighborSampler(g, (5,), seed=1)
-        blk = s.sample(np.arange(20))
-        # each root receives at most fanout in-edges
-        counts = np.bincount(blk.dst[blk.edge_mask], minlength=20)
-        assert counts[:20].max() <= 5
-
-    def test_deterministic_given_seed(self):
-        g = web_graph(200, 1500, seed=3)
-        b1 = NeighborSampler(g, (3, 2), seed=7).sample(np.arange(5))
-        b2 = NeighborSampler(g, (3, 2), seed=7).sample(np.arange(5))
-        np.testing.assert_array_equal(b1.node_ids, b2.node_ids)
-        np.testing.assert_array_equal(b1.src, b2.src)
 
 
 class TestPartition:
