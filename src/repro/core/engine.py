@@ -242,6 +242,7 @@ class PageRankEngine:
         else:
             self._ctx = self.backend.prepare(g)
         self.core_edges = self.backend.core_edges(self._ctx)
+        self.scan_edge_passes = self.backend.scan_edge_passes(self._ctx)
         if self.mesh is not None:
             if not self.caps.batch_parallel_mesh:
                 raise ValueError(
@@ -310,6 +311,8 @@ class PageRankEngine:
             n_unreferenced=self.n_unreferenced,
             level_depth=self.level_depth,
             core_edges=self.core_edges,
+            # the push's scan passes per edge slot, per edge list
+            scan_edge_passes=self.scan_edge_passes,
             step_impl=self.step_impl,
             jittable=self.caps.jittable,
             capabilities=self.caps.summary(),
@@ -497,6 +500,7 @@ class PageRankEngine:
         self.n_dangling = live.edges.n_dangling
         self.n_unreferenced = live.edges.n_unreferenced
         self.core_edges = live.core_edges
+        self.scan_edge_passes = self.backend.scan_edge_passes(live.ctx)
         if relaid:
             self.relayouts += 1
             self.level_depth = int(live.levels.max(initial=-1))

@@ -8,8 +8,12 @@ to m:
 
   * each of the push's two edge lists (all edges, and the out-edges of the
     set ``S`` the core list serves; :class:`~repro.core.backends.DenseRuns`)
-    keeps the order it was laid out in, followed by an insert region of
-    ``slack`` slots (:data:`SLACK`, a share of m);
+    keeps the order it was laid out in, its runs longest first, followed
+    by an insert region of ``slack`` slots (:data:`SLACK`, a share of m).
+    A delta never changes a main-region run's length (a deleted edge
+    keeps its slot), so the main region keeps the scan schedule it was
+    laid out with (:class:`~repro.core.backends.ScanPasses`); the insert
+    region, rebuilt on every delta, takes every pass over its slots;
   * a deleted edge's source becomes the pad vertex n, which pushes zero;
   * ``S`` starts as the referenced core (paper §III) and only grows.  The
     core list must hold every live out-edge of ``S``, and ``S`` must stay
@@ -31,7 +35,9 @@ So, once its input is zero off ``S``, each of the full list's runs, in the
 main region and in the insert region alike, is the core list's run behind
 exact zeros, and the two lists push the same sums bit for bit
 (:func:`~repro.core.backends._run_sums`), as on the plain layout: a row's
-result never depends on which list its batch walked.
+result never depends on which list its batch walked.  The host keeps each
+base edge's slot in either main region (``full_pos``, ``core_pos``) and
+each vertex's main run end (``main_last``), in that region's own order.
 
 A delta that does not fit lays the whole graph out again, once, with fresh
 slack: ``relayouts`` counts those.  A layout taken on a graph that never
@@ -124,23 +130,20 @@ class LiveLayout:
         self.levels = np.array(g.reference_levels)
         self.in_s = self.levels < 0
         src, dst = np.asarray(g.src), np.asarray(g.dst)
-        split = _core_order(g)
-        order = np.arange(g.m) if split is None else split[0]
+        order, core_order, _ = _core_order(g)
         self.full_pos = np.empty(g.m, np.int32)
         self.full_pos[order] = np.arange(g.m, dtype=np.int32)
         full = _runs(src[order], dst[order], n, self.slack)
         self.main_last = full.last
         self.core_main_last = None
         self.core_pos = None
-        if split is not None:
-            from_core = split[1]
-            core = _runs(src[order][from_core], dst[order][from_core], n,
-                         self.slack)
+        if core_order is not None:
+            core = _runs(src[core_order], dst[core_order], n, self.slack)
             self.core_main_last = core.last
             self.core_pos = np.full(g.m, -1, np.int32)
-            self.core_pos[order[from_core]] = np.arange(
-                from_core.sum(), dtype=np.int32)
-            self.core_alive = int(from_core.sum())
+            self.core_pos[core_order] = np.arange(core_order.size,
+                                                  dtype=np.int32)
+            self.core_alive = core_order.size
             full = full._replace(core=core,
                                  in_core=jnp.asarray(self.in_s.copy()))
         # keys of the base edges whose source joined S: they left the full

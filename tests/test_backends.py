@@ -30,6 +30,8 @@ from repro.core.backends import (STEP_IMPLS, StepBackend, choose_backend,
                                  register_step_impl)
 from repro.graph import graph_from_edges, web_graph
 
+from _propcheck import given, settings, strategies
+
 ALL_IMPLS = available_step_impls()
 JITTABLE_IMPLS = available_step_impls(jittable_only=True)
 
@@ -536,3 +538,247 @@ class TestFloat64Gather:
         y_plain, _ = counted(g, ctx, jnp.asarray(w))
         np.testing.assert_array_equal(np.asarray(y).view(np.uint64),
                                       np.asarray(y_plain).view(np.uint64))
+
+
+def _full_pass_run_sums(vals, start, last, carry=None):
+    """The segmented scan as it stood before its pass schedule: a pass
+    for every shift below the list's length, each over the whole list,
+    then the live layout's carry and the readout.  The scheduled scan
+    must give these bits."""
+    x, f = vals, start
+    lead = [(0, 0)] * (x.ndim - 1)
+    k = 1
+    while k < x.shape[-1]:
+        x = jnp.where(f, x, x + jnp.pad(x[..., :-k], lead + [(k, 0)]))
+        f = f | jnp.pad(f[:-k], (k, 0), constant_values=True)
+        k *= 2
+    if x.shape[-1] == 0:
+        return jnp.zeros(vals.shape[:-1] + last.shape, vals.dtype)
+    if carry is not None:
+        e = x.shape[-1] - carry.shape[0]
+        main = jnp.where(carry >= 0, x[..., jnp.maximum(carry, 0)], 0)
+        x = x.at[..., e:].add(main)
+    return jnp.where(last >= 0, x[..., jnp.maximum(last, 0)], 0)
+
+
+def _by_destination_push(g, w):
+    """The dense push of ``w`` over both edge lists laid out as before
+    the runs were ordered by length: sorted by destination, each run's
+    sources outside the referenced core first, every pass over the whole
+    list.  Returns ``(full, core)``; ``core`` is None where the core
+    holds every edge."""
+    src, dst = np.asarray(g.src), np.asarray(g.dst)
+    from_core = (g.reference_levels < 0)[src]
+    order = np.argsort(2 * dst.astype(np.int64) + from_core, kind="stable")
+
+    def walk(keep):
+        s, d = src[order][keep], dst[order][keep]
+        start = np.ones(d.shape, bool)
+        start[1:] = d[1:] != d[:-1]
+        deg = np.bincount(d, minlength=g.n)
+        last = np.where(deg > 0, np.cumsum(deg) - 1, -1)
+        return _full_pass_run_sums(jnp.asarray(w)[..., s], jnp.asarray(start),
+                                   jnp.asarray(last))
+
+    full = walk(np.ones(g.m, bool))
+    return full, None if from_core.all() else walk(from_core[order])
+
+
+def _run_length_graph(lengths, n, seed):
+    """A graph whose in-degrees are ``lengths`` (one run each, on random
+    distinct destinations; the other vertices have none), with random
+    distinct sources for each run."""
+    rng = np.random.default_rng(seed)
+    dst_v = rng.choice(n, len(lengths), replace=False)
+    src = np.concatenate([rng.choice(n, r, replace=False) for r in lengths])
+    dst = np.repeat(dst_v, lengths)
+    return graph_from_edges(src, dst, n)
+
+
+def _signed_decades(shape, rng):
+    """Signed values over sixty decades, a third of them exact zeros."""
+    v = rng.choice([-1.0, 1.0], shape) * 10.0 ** rng.uniform(-30, 30, shape)
+    return np.where(rng.random(shape) < 1 / 3, 0.0, v)
+
+
+SCAN_GRAPHS = {
+    # runs of 1, 2**k and 2**k + 1 edges, several of each
+    "powers": lambda: _run_length_graph(
+        [r for k in range(8) for r in (2 ** k, 2 ** k + 1)] * 3 + [1] * 20,
+        400, seed=41),
+    "one-run": lambda: graph_from_edges(np.arange(300), np.full(300, 7), 300),
+    "no-edges": lambda: graph_from_edges(np.zeros(0, int), np.zeros(0, int),
+                                         12),
+    "web": lambda: web_graph(600, 4800, dangling_frac=0.2, unref_boost=0.3,
+                             seed=42),
+}
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint64)
+
+
+class TestScanSchedule:
+    """The dense push's scan walks, in its pass of shift s, only the
+    prefix of its edge list that holds the runs longer than s, the runs
+    listed longest first (``backends.ScanPasses``).  Every sum must keep
+    the bits the full-pass scan over the list sorted by destination gave."""
+
+    def test_schedule_of_known_runs(self):
+        from repro.core.backends import ScanPasses
+
+        def starts(lengths):
+            return np.concatenate([np.eye(1, r, dtype=bool)[0]
+                                   for r in lengths])
+        # runs 5, 1, 3, 2: shift 1 keeps every run longer than 1, up to
+        # the end of the 2; shift 2 up to the 3; shift 4 the 5 alone
+        assert ScanPasses.of(starts([5, 1, 3, 2])).prefix == (11, 9, 5)
+        assert ScanPasses.of(starts([5, 3, 2, 1])).prefix == (10, 8, 5)
+        assert ScanPasses.of(starts([1, 1, 1])).prefix == ()
+        assert ScanPasses.of(np.zeros(0, bool)).prefix == ()
+        assert ScanPasses.of(starts([8])).prefix == (8, 8, 8)
+        assert ScanPasses.of(starts([9])).prefix == (9, 9, 9, 9)
+        assert ScanPasses.full(9).prefix == (9, 9, 9, 9)
+        assert ScanPasses.full(1).prefix == ScanPasses.full(0).prefix == ()
+
+    def test_runs_are_listed_longest_first(self):
+        """Each list holds its own runs longest first, by destination
+        within a length, and ``last`` points at each vertex's run end."""
+        g = SCAN_GRAPHS["web"]()
+        ctx = get_step_impl("dense").prepare(g)
+        src, dst = np.asarray(g.src), np.asarray(g.dst)
+        in_core = np.asarray(ctx.in_core)
+        for runs, keep in ((ctx, np.ones(g.m, bool)), (ctx.core,
+                                                       in_core[src])):
+            deg = np.bincount(dst[keep], minlength=g.n)
+            start, last = np.asarray(runs.start), np.asarray(runs.last)
+            first = np.flatnonzero(start)
+            ends = np.append(first[1:], start.size) - 1
+            has = last >= 0
+            assert np.array_equal(has, deg > 0)
+            assert np.array_equal(np.sort(last[has]), ends)
+            v = np.flatnonzero(has)[np.argsort(last[has])]  # in list order
+            assert np.array_equal(ends - first + 1, deg[v])
+            assert np.all((np.diff(deg[v]) < 0)
+                          | ((np.diff(deg[v]) == 0) & (np.diff(v) > 0)))
+
+    @pytest.mark.parametrize("rows", [None, 4], ids=["push", "push_batch"])
+    @pytest.mark.parametrize("name", sorted(SCAN_GRAPHS))
+    def test_push_keeps_the_full_pass_bits(self, name, rows):
+        from repro.core.backends import _walk
+
+        g = SCAN_GRAPHS[name]()
+        ctx = get_step_impl("dense").prepare(g)
+        rng = np.random.default_rng(43)
+        w = _signed_decades((g.n,) if rows is None else (rows, g.n), rng)
+        full, core = _by_destination_push(g, w)
+        assert np.array_equal(_bits(_walk(jnp.asarray(w), ctx)), _bits(full))
+        assert (core is None) == (ctx.core is None)
+        if core is not None:
+            w_core = jnp.asarray(np.where(np.asarray(ctx.in_core), w, 0.0))
+            _, core = _by_destination_push(g, np.asarray(w_core))
+            assert np.array_equal(_bits(_walk(w_core, ctx.core)),
+                                  _bits(core))
+
+    @settings(max_examples=8, deadline=None)
+    @given(seed=strategies.integers(0, 2 ** 31 - 1),
+           rows=strategies.integers(0, 3))
+    def test_random_power_law_runs_keep_their_bits(self, seed, rows):
+        from repro.core.backends import _walk
+
+        rng = np.random.default_rng(seed)
+        n = int(rng.integers(50, 400))
+        lengths = np.minimum(rng.zipf(1.6, int(rng.integers(1, n))), n)
+        g = _run_length_graph(lengths.tolist(), n, seed)
+        ctx = get_step_impl("dense").prepare(g)
+        w = _signed_decades((n,) if rows == 0 else (rows, n), rng)
+        full, _ = _by_destination_push(g, w)
+        assert np.array_equal(_bits(_walk(jnp.asarray(w), ctx)), _bits(full))
+
+    @pytest.mark.parametrize("rows", [None, 3], ids=["push", "push_batch"])
+    def test_live_layout_keeps_the_full_pass_bits_after_deltas(self, rows):
+        """The live layout's main regions keep their schedule through
+        deltas and its insert regions take every pass; together they give
+        what every pass over the whole padded list gives."""
+        from repro.core import DeltaQuery, EnginePlan, PageRankEngine
+        from repro.core.backends import _walk
+
+        g = SCAN_GRAPHS["web"]()
+        eng = PageRankEngine(g, EnginePlan(step_impl="dense"))
+        rng = np.random.default_rng(44)
+        src, dst = np.asarray(g.src), np.asarray(g.dst)
+        have = set(zip(src.tolist(), dst.tolist()))
+        for _ in range(2):
+            gone = rng.choice(len(src), 15, replace=False)
+            remove = [(int(src[i]), int(dst[i])) for i in gone]
+            add = []
+            while len(add) < 12:
+                e = tuple(int(v) for v in rng.integers(0, g.n, 2))
+                if e not in have:
+                    have.add(e)
+                    add.append(e)
+            eng.run(DeltaQuery(add=tuple(add), remove=tuple(remove)))
+            have -= set(remove)
+            keep = np.ones(len(src), bool)
+            keep[gone] = False
+            src, dst = src[keep], dst[keep]
+        ctx = eng._ctx
+        assert eng.relayouts == 0 and np.any(np.asarray(ctx.carry) >= 0)
+        w = _signed_decades((g.n,) if rows is None else (rows, g.n), rng)
+        padded = np.concatenate([w, np.zeros(w.shape[:-1] + (1,))], -1)
+        for runs in (ctx, ctx.core):
+            want = _full_pass_run_sums(jnp.asarray(padded)[..., runs.src],
+                                       runs.start, runs.last, runs.carry)
+            assert np.array_equal(_bits(_walk(jnp.asarray(w), runs)),
+                                  _bits(want))
+
+
+class TestScanEdgePasses:
+    """``scan_edge_passes``: the scan's passes per edge slot of each edge
+    list, beside ``core_edges`` on the engine and in ``describe()``."""
+
+    @staticmethod
+    def _graph():
+        return web_graph(3000, 30000, dangling_frac=0.15, unref_boost=0.3,
+                         seed=45)
+
+    def test_reported_per_list_on_the_plain_layout(self):
+        from repro.core import EnginePlan, PageRankEngine
+
+        eng = PageRankEngine(self._graph(), EnginePlan(step_impl="dense"))
+        passes = eng.describe(include_plan=False)["scan_edge_passes"]
+        assert passes == eng.scan_edge_passes
+        assert set(passes) == {"full", "core"}
+        ctx = eng._ctx
+        for name, runs in (("full", ctx), ("core", ctx.core)):
+            e = runs.src.shape[0]
+            assert passes[name] == sum(runs.passes.prefix) / e
+            # a power-law graph's runs need far fewer passes than log2 e
+            assert 1 < passes[name] < np.ceil(np.log2(e)) / 2
+
+    def test_reported_per_list_on_the_live_layout(self):
+        from repro.core import DeltaQuery, EnginePlan, PageRankEngine
+
+        g = self._graph()
+        eng = PageRankEngine(g, EnginePlan(step_impl="dense"))
+        have = set(zip(np.asarray(g.src).tolist(), np.asarray(g.dst).tolist()))
+        add = next((0, v) for v in range(1, g.n) if (0, v) not in have)
+        eng.run(DeltaQuery(add=(add,),
+                           remove=((int(g.src[0]), int(g.dst[0])),)))
+        passes = eng.describe(include_plan=False)["scan_edge_passes"]
+        assert passes == eng.scan_edge_passes
+        assert set(passes) == {"full", "core"}
+        ctx = eng._ctx
+        for name, runs in (("full", ctx), ("core", ctx.core)):
+            slots, cap = runs.src.shape[0], runs.carry.shape[0]
+            # the main region's schedule, and every pass over the slack
+            assert passes[name] == (sum(runs.passes.prefix)
+                                    + cap * (cap - 1).bit_length()) / slots
+            assert 1 < passes[name] < (slots - 1).bit_length()
+
+    def test_none_off_the_dense_push(self):
+        from repro.core import EnginePlan, PageRankEngine
+
+        eng = PageRankEngine(GRAPHS["web"](), EnginePlan(step_impl="ell"))
+        assert eng.scan_edge_passes is None
+        assert eng.describe(include_plan=False)["scan_edge_passes"] is None

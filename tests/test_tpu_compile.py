@@ -13,12 +13,16 @@ compiles, since a compile for a described chip cannot be read back here.
 
 The dense push's float64 gathers are held to one gather of two-word rows
 each (``backends._split_gather``), read from the compiled program's gather
-operand shapes; a float32 push keeps the plain gather.
+operand shapes; a float32 push keeps the plain gather.  Its ``[16, n]``
+scan is held to its pass schedule (``backends.ScanPasses``): the float32
+words its passes write add up to the prefixes the schedule walks, and no
+pass copies a whole edge list; a single vector takes every pass.
 
 The bucketed-ELL kernel is refused by Mosaic today; its test is a strict
 xfail, so a change that makes it lower must flip it (and lift
 ``EllBackend.refused_on``).
 """
+import math
 import re
 from functools import cache, partial
 
@@ -29,7 +33,8 @@ import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.backends import DenseRuns, _ita_loop_jit, get_step_impl
+from repro.core.backends import (DenseRuns, ScanPasses, _ita_loop_jit,
+                                 get_step_impl)
 from repro.core.batch import _ita_batch_loop_donated
 from repro.core.distributed import _batch_2d_loop, _batch_dp_loop
 from repro.core.dynamic import _warm_start_jit
@@ -42,6 +47,16 @@ N = TABLE3_PRESETS["web-Google"]["n"]
 M = TABLE3_PRESETS["web-Google"]["m"]
 # the referenced core's out-edges on the benchmark's web-Google stand-in
 M_CORE = 3_449_864
+# the scan's schedule of each list of that stand-in (ScanPasses.of, its
+# runs longest first; bench/generators/powerlaw_web.py, dataset_seed 0):
+# 7.01 and 6.49 passes an edge, where every pass over the list took 23, 22
+FULL_PASSES = ScanPasses((
+    4874330, 4552006, 4055995, 3575935, 3155416, 2775058, 2429050, 2109952,
+    1817822, 1552960, 1300255, 1069885, 855182, 661136, 490522, 332500,
+    200773))
+CORE_PASSES = ScanPasses((
+    3197134, 2920934, 2571982, 2260091, 1987521, 1740229, 1517690, 1313162,
+    1119678, 945972, 786636, 640593, 502517, 378463, 263018, 171734, 85076))
 B = 16
 DTYPE = jnp.float64
 # insert slots of each edge list of the live layout (core/live.py)
@@ -88,12 +103,14 @@ def graph(one_chip):
 def _runs(sharding):
     """Abstract ``DenseBackend.prepare`` context of that graph: the full
     edge list and the referenced core's, so a push compiles both."""
-    def edge_list(e):
+    def edge_list(e, passes):
         return DenseRuns(src=_struct((e,), jnp.int32, sharding),
                          start=_struct((e,), jnp.bool_, sharding),
-                         last=_struct((N,), jnp.int32, sharding))
-    return edge_list(M)._replace(core=edge_list(M_CORE),
-                                 in_core=_struct((N,), jnp.bool_, sharding))
+                         last=_struct((N,), jnp.int32, sharding),
+                         passes=passes)
+    return edge_list(M, FULL_PASSES)._replace(
+        core=edge_list(M_CORE, CORE_PASSES),
+        in_core=_struct((N,), jnp.bool_, sharding))
 
 
 @pytest.fixture(scope="module")
@@ -112,6 +129,45 @@ def _gathers(compiled):
     """The operand shape of each gather in a compiled program, sorted."""
     return sorted(re.findall(r"= (\w+\[[\d,]*\])\S* gather\(",
                              compiled.as_text()))
+
+
+def _f32_elements(shapes: str) -> int:
+    return sum(math.prod(int(d) for d in dims.split(",") if d)
+               for dims in re.findall(r"f32\[([\d,]*)\]", shapes))
+
+
+def _scan_writes(compiled):
+    """Float32 words written in a compiled push by the scan's operations
+    (op names under ``push/scan``): by its passes (the fusions rooted at
+    their select), and by all of them, with the slices XLA materializes
+    for a pass's shifted operand and for each finished piece.  The
+    dynamic-update-slices that set the pieces side by side write in
+    place and are left out.  Also the widths of the float32 copies
+    anywhere in the program."""
+    text = compiled.as_text()
+    fused = set(re.findall(r"calls=%([\w.-]+)", text))
+    passes, scan, copies, comp = 0, 0, [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        op = re.match(r"\s+(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", line)
+        if comp in fused or op is None:
+            continue
+        shapes, kind = op.groups()
+        if kind in ("copy", "copy-start"):
+            # a copy-start's shape names its source and its destination
+            copies += [int(w) for w in re.findall(r"f32\[[\d,]*?(\d+)\]",
+                                                  shapes)][:1]
+        name = re.search(r'op_name="[^"]*/push/scan/([^"]*)"', line)
+        if name is None or kind in ("dynamic-update-slice",
+                                    "get-tuple-element", "bitcast"):
+            continue
+        scan += _f32_elements(shapes)
+        if kind == "fusion" and name.group(1).endswith("select_n"):
+            passes += _f32_elements(shapes)
+    return passes, scan, copies
 
 
 @pytest.fixture(scope="module")
@@ -152,6 +208,31 @@ def test_float64_push_gathers_both_words_with_one_index(dense_push, batch):
         + [f"f32[{rows},{N}]"] * 2)
 
 
+def test_scan_walks_only_its_prefixes(dense_push):
+    """Each pass of the ``[16, n]`` scan writes the prefix its schedule
+    gives, both float32 words of each float64: Σ P_j words a row and
+    list, where a pass over each whole list for every shift wrote
+    ⌈log2 e⌉ · e (23 and 22).  With the slices XLA materializes, the scan
+    writes under three times its passes' words (the full-pass scan wrote
+    5.9 times these).  No copy in the push is an edge list wide, so no
+    pass copies the suffix it leaves (one per pass would be 34 a list)."""
+    passes, scan, copies = _scan_writes(dense_push(B)[0])
+    walked = 2 * B * (sum(FULL_PASSES.prefix) + sum(CORE_PASSES.prefix))
+    assert passes == walked
+    assert walked < 0.31 * 2 * B * (23 * M + 22 * M_CORE)
+    assert scan < 3 * walked
+    assert not {M, M_CORE} & set(copies)
+
+
+def test_one_vector_scan_takes_every_pass(dense_push):
+    """A single vector's scan takes every pass over each whole list, as
+    before the schedule, and copies no list."""
+    passes, _, copies = _scan_writes(dense_push(1)[0])
+    assert passes == 2 * (sum(ScanPasses.full(M).prefix)
+                          + sum(ScanPasses.full(M_CORE).prefix))
+    assert not {M, M_CORE} & set(copies)
+
+
 @pytest.mark.parametrize("batch", [1, B], ids=["push", "push_batch"])
 def test_float32_push_keeps_the_plain_gather(dense_push, batch):
     shape = "" if batch == 1 else f"{batch},"
@@ -186,13 +267,15 @@ def test_donated_batch_loop_compiles(graph, runs, one_chip):
 
 def _live_runs(sharding):
     """Abstract live layout: both edge lists with their insert regions."""
-    def edge_list(e):
+    def edge_list(e, passes):
         return DenseRuns(src=_struct((e + C,), jnp.int32, sharding),
                          start=_struct((e + C,), jnp.bool_, sharding),
                          last=_struct((N,), jnp.int32, sharding),
+                         passes=passes,
                          carry=_struct((C,), jnp.int32, sharding))
-    return edge_list(M)._replace(core=edge_list(M_CORE),
-                                 in_core=_struct((N,), jnp.bool_, sharding))
+    return edge_list(M, FULL_PASSES)._replace(
+        core=edge_list(M_CORE, CORE_PASSES),
+        in_core=_struct((N,), jnp.bool_, sharding))
 
 
 def _degrees(sharding):
