@@ -91,13 +91,15 @@ def one_hot_personalizations(g: Graph, seeds, dtype=jnp.float64) -> jnp.ndarray:
     return jax.nn.one_hot(seeds, g.n, dtype=dtype)
 
 
+@jax.jit
 def normalize_rows(U: jnp.ndarray) -> jnp.ndarray:
     """``U`` with each row scaled to sum to 1.
 
     Each row is summed as its own 1-D array, so a row's answer does not
     depend on how many rows share its batch: a TPU tiles a 2-D reduction
     by the row count, and the (R, 1) mesh must match one device bit for
-    bit with a quarter of the rows per chip.
+    bit with a quarter of the rows per chip.  Compiled once per shape: an
+    eager ``lax.map`` traces and compiles its loop again on every call.
     """
     return U / jax.lax.map(jnp.sum, U)[:, None]
 

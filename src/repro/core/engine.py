@@ -62,7 +62,11 @@ ever resolving globally.  On the dense backend without a mesh the first
 delta lays the edge lists out with slack (``core/live.py``); every later
 delta edits that layout in place on the device, compiling nothing and
 costing host work in proportion to the edges added since, and one that
-does not fit lays the graph out again (``relayouts``).
+does not fit lays the graph out again (``relayouts``).  ``PPRQuery`` and
+``TopKQuery`` on that layout read the graph's ``Degrees``, not a
+``Graph``, so they neither rebuild the graph after a delta nor compile
+again when m changes.  ``DeltaQuery(rank=False)`` applies a delta with no
+re-rank: the write of a deployment that serves PPR.
 """
 from __future__ import annotations
 
@@ -156,6 +160,15 @@ class TopKResult(NamedTuple):
     indices: jnp.ndarray   # int32 [B, k]
     scores: jnp.ndarray    # [B, k]
     result: BatchSolverResult
+
+
+def _acknowledged(graph_version: int, relayouts: Optional[int] = None,
+                  core_edges: Optional[int] = None) -> SolverResult:
+    """A ``DeltaQuery(rank=False)``'s result: the delta applied, no pi."""
+    return SolverResult(pi=None, iterations=0, residual=0.0, ops=0.0,
+                        converged=True, method="delta_apply",
+                        relayouts=relayouts, core_edges=core_edges,
+                        graph_version=graph_version)
 
 
 class PageRankEngine:
@@ -284,7 +297,8 @@ class PageRankEngine:
     def graph(self) -> Graph:
         """The graph the engine serves.  After a ``DeltaQuery`` on the
         live layout it is built from the host edge set on first use
-        (O(m)); the delta path itself never needs it."""
+        (O(m)); the delta path and the PPR and top-k paths never need
+        it."""
         if self._graph is None:
             self._graph = self._live.edges.graph()
         return self._graph
@@ -292,6 +306,18 @@ class PageRankEngine:
     @property
     def m(self) -> int:
         return self._live.edges.m if self._live is not None else self.graph.m
+
+    @property
+    def live(self) -> bool:
+        """Whether the engine serves from the layout for edge deltas
+        (``core/live.py``), taken at its first delta."""
+        return self._live is not None
+
+    def _loop_graph(self):
+        """What a batched loop reads of the graph: the :class:`Graph`, or
+        on the live layout its :class:`~repro.graph.structure.Degrees`,
+        whose shapes do not change with m and which needs no rebuild."""
+        return self._live.degrees if self._live is not None else self.graph
 
     def _edge_devices(self) -> list:
         edges = self._graph.src if self._graph is not None else self._ctx.src
@@ -337,8 +363,12 @@ class PageRankEngine:
     # the query plane: plan / run
     # ------------------------------------------------------------------ #
     def _planner_state(self, query: Optional[Query] = None) -> PlannerState:
-        # a delta is planned without building the graph it changes
-        g = self._graph if isinstance(query, DeltaQuery) else self.graph
+        # a delta, a PPR and a top-k query run without the graph, so they
+        # are planned without building it (on the live layout it is None
+        # after a delta until something asks for it, and is then not known
+        # to be undirected); every other query builds it to run anyway
+        lazy = isinstance(query, (DeltaQuery, PPRQuery, TopKQuery))
+        g = self._graph if lazy else self.graph
         return PlannerState(
             step_impl=self.step_impl,
             capabilities=self.caps,
@@ -467,10 +497,10 @@ class PageRankEngine:
                     "return_state=True needs the ITA batch family; "
                     f"cfg.batch_method={cfg.batch_method!r}")
             kw["return_state"] = True
-        return fn(self.graph, p_batch, **kw)
+        return fn(self._loop_graph(), p_batch, **kw)
 
     def _exec_topk(self, q: TopKQuery, ep: ExecutionPlan) -> TopKResult:
-        P = one_hot_personalizations(self.graph, q.sources,
+        P = one_hot_personalizations(self._loop_graph(), q.sources,
                                      dtype=self.engine_plan.dtype)
         rb = self._exec_ppr(P, ep)
         scores, indices = jax.lax.top_k(rb.pi, int(q.k))
@@ -485,14 +515,16 @@ class PageRankEngine:
             self._live = LiveLayout(self.graph)
             self._ctx = self._live.ctx
         live = self._live
-        if self._state is None:
+        if not q.rank:
+            self._state = None  # the ranking it held is of the old graph
+        elif self._state is None:
             pi_bar, h, _, _ = ita_residual_state(
                 live.degrees, c=plan.c, xi=plan.update_xi, dtype=plan.dtype,
                 step_impl="dense", ctx=live.ctx)
             self._state = (pi_bar, h)
         with TraceAnnotation("engine.delta.apply"):
             relaid = live.apply(add=q.add, remove=q.remove)
-            self._ctx = jax.block_until_ready(live.ctx)
+            self._ctx, _ = jax.block_until_ready((live.ctx, live.degrees))
         self._graph = None
         self.graph_version = live.edges.version
         self.dangling_mask = live.degrees.dangling_mask
@@ -505,19 +537,25 @@ class PageRankEngine:
             self.relayouts += 1
             self.level_depth = int(live.levels.max(initial=-1))
         self.prepare_count += 1
+        if not q.rank:
+            return _acknowledged(self.graph_version, int(relaid),
+                                 live.core_edges)
         pi_bar, h = self._state
         result, self._state = ita_incremental(
             live.degrees, live.degrees, pi_bar, h, c=plan.c,
             xi=plan.update_xi, step_impl="dense", ctx=live.ctx,
             return_state=True)
         return dataclasses.replace(result, relayouts=int(relaid),
-                                   core_edges=live.core_edges)
+                                   core_edges=live.core_edges,
+                                   graph_version=self.graph_version)
 
     def _exec_delta_reprepared(self, q: DeltaQuery) -> SolverResult:
         """A delta on a layout that cannot take it in place: the new
         graph built on the host and prepared whole."""
         plan = self.engine_plan
-        if self._state is None:
+        if not q.rank:
+            self._state = None
+        elif self._state is None:
             pi_bar, h, _, _ = ita_residual_state(
                 self.graph, c=plan.c, xi=plan.update_xi,
                 dtype=plan.dtype, step_impl=self.step_impl,
@@ -526,11 +564,14 @@ class PageRankEngine:
         g_old = self.graph
         g_new = apply_edge_delta(g_old, add=q.add, remove=q.remove)
         self._prepare(g_new)  # ctx must belong to the NEW graph
+        if not q.rank:
+            jax.block_until_ready(self._ctx)
+            return _acknowledged(graph_version=self.graph_version)
         pi_bar, h = self._state
         result, self._state = ita_incremental(
             g_old, g_new, pi_bar, h, c=plan.c, xi=plan.update_xi,
             step_impl=self.step_impl, ctx=self._ctx, return_state=True)
-        return result
+        return dataclasses.replace(result, graph_version=self.graph_version)
 
     def _solve_batch_donated(self, p_batch, cfg: BatchConfig,
                              return_state: bool = False):
@@ -541,9 +582,9 @@ class PageRankEngine:
         ``ita_batch`` bit for bit.
         """
         t0 = time.perf_counter()
-        H0 = (p_batch.astype(cfg.dtype) * self.graph.n).astype(cfg.dtype)
+        H0 = (p_batch.astype(cfg.dtype) * self.n).astype(cfg.dtype)
         H, PiBar, n_active, it, ops, core_rounds = _ita_batch_loop_donated(
-            self.graph, self._ctx, H0, float(cfg.c), float(cfg.xi),
+            self._loop_graph(), self._ctx, H0, float(cfg.c), float(cfg.xi),
             int(cfg.max_iter), self.backend)
         Pi = normalize_rows(PiBar + H)
         with TraceAnnotation("solve.wait"):

@@ -64,6 +64,8 @@ class SolverResult:
     # the core list's live edges after it
     relayouts: Optional[int] = None
     core_edges: Optional[int] = None
+    # an edge delta's: the edge-set version it made
+    graph_version: Optional[int] = None
 
     def stats(self) -> dict:
         return dict(
@@ -76,4 +78,5 @@ class SolverResult:
             core_rounds=self.core_rounds,
             relayouts=self.relayouts,
             core_edges=self.core_edges,
+            graph_version=self.graph_version,
         )

@@ -110,11 +110,17 @@ class DeltaQuery(Query):
     """An edge delta plus the incremental re-rank it triggers.
 
     ``add``/``remove`` are iterables of ``(src, dst)`` pairs, the
-    :func:`repro.graph.apply_edge_delta` contract.
+    :func:`repro.graph.apply_edge_delta` contract.  ``rank=False`` applies
+    the delta and acknowledges it without re-ranking: the result carries
+    the new ``graph_version`` and no pi, and the engine drops the global
+    ranking's residual state, which no longer matches the graph (a later
+    ranked delta solves it again from scratch).  That is the write of a
+    deployment that serves PPR and keeps no global ranking.
     """
 
     add: tuple = ()
     remove: tuple = ()
+    rank: bool = True
 
     kind = "delta"
 
@@ -159,6 +165,8 @@ class ExecutionPlan:
                                 (``core/distributed.py``);
       * ``"incremental"``       signed correction cascade
                                 (``core/dynamic.py``);
+      * ``"layout"``            an edge delta applied with no re-rank
+                                (``DeltaQuery(rank=False)``);
       * ``"composite"``         a :class:`BatchQuery` of sub-plans.
 
     ``cfg`` is the *resolved* config the execution will use (defaults
@@ -493,16 +501,22 @@ def _plan_delta(state: PlannerState, q: DeltaQuery) -> ExecutionPlan:
             f"backend {state.step_impl!r} does not declare dynamic_update; "
             f"prepare the engine with a backend that does")
     reasons = [f"engine prepared step_impl={state.step_impl!r} "
-               f"({state.backend_reason})",
-               "signed incremental cascade (core/dynamic.py) on the "
-               "changed support",
-               "warm (π̄, h) residual state reused" if
-               state.has_residual_state else
-               "cold start: one residual solve establishes (π̄, h), later "
-               "deltas are incremental"]
+               f"({state.backend_reason})"]
+    if q.rank:
+        path, method = "incremental", "ita_incremental"
+        reasons += ["signed incremental cascade (core/dynamic.py) on the "
+                    "changed support",
+                    "warm (π̄, h) residual state reused" if
+                    state.has_residual_state else
+                    "cold start: one residual solve establishes (π̄, h), "
+                    "later deltas are incremental"]
+    else:
+        path, method = "layout", "-"
+        reasons += ["rank=False: the edge set and the push's layout change, "
+                    "no re-rank; any (π̄, h) residual state is dropped"]
     n_delta = len(tuple(q.add)) + len(tuple(q.remove))
     return ExecutionPlan(query=q.kind, backend=state.step_impl,
-                         path="incremental", method="ita_incremental",
+                         path=path, method=method,
                          mesh=None, micro_batch=None, cost=float("nan"),
                          cfg=None,
                          reasons=tuple(reasons) + (

@@ -8,12 +8,13 @@ difference between a benchmark loop and a service.  The pipeline is
 
 with each stage a module: :mod:`clock` (virtual/wall time so every policy
 is testable without sleeps), :mod:`workload` (open/closed-loop
-Poisson+Zipf request generators), :mod:`admission` (token-bucket
-throttling + cache-aware bypass), :mod:`queue` (bounded FIFO
+Poisson+Zipf request generators, timed edge writes), :mod:`admission`
+(token-bucket throttling + cache-aware bypass), :mod:`queue` (bounded FIFO
 load-leveling with typed ``Overload`` rejections), :mod:`batcher`
 (deadline-aware batch formation against the planner's cost estimates),
 :mod:`degrade` (hysteretic graceful degradation under sustained queue
-growth) and :mod:`service` (the loop tying them together).
+growth) and :mod:`service` (the loop tying them together, with a write
+lane that applies edge deltas between micro-batches).
 ``launch/ppr_serve.py`` is the CLI over this package; docs/SERVING.md
 has the architecture and the overload state machine.
 """
@@ -24,11 +25,12 @@ from .clock import Clock, VirtualClock, WallClock
 from .degrade import DegradeLevel, DegradePolicy
 from .metrics import latency_summary
 from .queue import BoundedQueue, Overload
-from .service import PPRService, Served, ServiceConfig, ServiceReport
+from .service import PPRService, Served, ServiceConfig, ServiceReport, WriteAck
 from .workload import (
     ClosedLoopWorkload,
     OpenLoopWorkload,
     Request,
+    Write,
     zipf_seeds,
 )
 
@@ -52,6 +54,8 @@ __all__ = [
     "TokenBucket",
     "VirtualClock",
     "WallClock",
+    "Write",
+    "WriteAck",
     "latency_summary",
     "zipf_seeds",
 ]

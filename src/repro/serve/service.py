@@ -13,16 +13,29 @@ answers served through the tier are **bit-identical** to direct
 ``engine.run`` whenever no degradation is active (the tier decides when
 and what to run, never how; tests/test_serving.py pins it).
 
+Writes share the loop (the write lane): ``serve(reads, writes=...)``
+takes timed edge deltas (:class:`~repro.serve.workload.Write`), and
+before each dispatch the loop applies every write that is due, in arrival
+order, as ``engine.run(DeltaQuery(..., rank=False))``.  A write is
+acknowledged once its layout is on the device
+(:class:`WriteAck` in ``ServiceReport.writes``).  The guarantee is
+read-your-writes per micro-batch: a micro-batch dispatched after a write
+was acknowledged answers on that version or a newer one, and all answers
+of one micro-batch come from one version, which ``Served.graph_version``
+states.
+
 Latency is accounted **per request** (arrival to completion, queue wait
 included), not per batch: ``Served.t_dispatch`` splits it into the wait
 in the queue and the service of the request's micro-batch.  Under a
 running ``jax.profiler`` trace the loop's stages show as host spans:
 ``serve.ingest``, ``serve.batch`` (one per micro-batch, with its index)
-and, inside it, ``serve.assemble``.
+and, inside it, ``serve.assemble``; and ``serve.write``, one per write,
+with the engine's ``engine.delta.apply`` inside.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import Any, List, Optional
@@ -43,6 +56,7 @@ __all__ = [
     "PPRService",
     "Served",
     "ServiceReport",
+    "WriteAck",
     "EngineExecutor",
     "NullExecutor",
 ]
@@ -85,7 +99,8 @@ class Served:
     ``t_dispatch`` is the service clock's time when the request's
     micro-batch went to the executor: ``t_dispatch - req.t_arrival`` is
     its wait in the queue and ``latency_s`` that wait plus
-    ``t_done - t_dispatch``.
+    ``t_done - t_dispatch``.  ``graph_version`` is the engine's edge-set
+    version its micro-batch ran on.
     """
 
     req: Request
@@ -98,6 +113,21 @@ class Served:
     cache_hit: bool = False
     indices: Any = None
     scores: Any = None
+    graph_version: Optional[int] = None
+
+
+@dataclasses.dataclass
+class WriteAck:
+    """One write the lane applied: when it arrived, when the lane began
+    applying it and when its layout was on the device (all on the service
+    clock), the version it made, and whether it laid the graph out again
+    (None off the live layout)."""
+
+    t_arrival: float
+    t_start: float
+    t_ack: float
+    graph_version: int
+    relayouts: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -113,6 +143,7 @@ class ServiceReport:
     admission_stats: dict
     batcher_stats: dict
     degrade_stats: Optional[dict]
+    writes: List[WriteAck] = dataclasses.field(default_factory=list)
 
     def summary(self) -> dict:
         """Aggregate view (serving logs, the benchmark record)."""
@@ -193,6 +224,8 @@ class PPRService:
         self.admission = AdmissionController(self.config.admission, engine)
         self.queue = BoundedQueue(self.config.queue_cap)
         self.degrade = self.config.degrade
+        if engine.live:
+            self._check_rungs_take_writes()
         # per-level serving state: (engine, cfg, plan-cost units); level 0
         # is the prepared engine at full fidelity.
         self._levels: dict = {}
@@ -213,6 +246,19 @@ class PPRService:
             batch_cost_units=units0,
             safety_s=self.config.safety_s,
         )
+
+    def _check_rungs_take_writes(self):
+        """Refuse a rung that serves a fallback engine of its own: the
+        writes reach only ``self.engine``, so it would answer on a graph
+        they never change."""
+        rungs = self.degrade.levels if self.degrade is not None else ()
+        other = sorted({r.step_impl for r in rungs if r.step_impl
+                        and r.step_impl != self.engine.step_impl})
+        if other:
+            raise ValueError(
+                f"degrade rungs with step_impl {other} serve a fallback "
+                f"engine that edge writes never reach; an engine that takes "
+                f"writes can only degrade by xi_scale")
 
     # ------------------------------------------------------------------ #
     # per-level engines/configs (the degrade ladder's serving state)
@@ -280,14 +326,24 @@ class PPRService:
     # ------------------------------------------------------------------ #
     # the event loop
     # ------------------------------------------------------------------ #
-    def serve(self, workload) -> ServiceReport:
+    def serve(self, workload, writes=()) -> ServiceReport:
+        """Serve ``workload``'s reads until it drains.  ``writes`` is a
+        sequence of :class:`~repro.serve.workload.Write`, applied through
+        the write lane in arrival order; writes still to come when the
+        reads drain stay unapplied."""
+        pending = collections.deque(sorted(writes, key=lambda w: w.t_arrival))
+        if pending:
+            self._check_rungs_take_writes()
         if not self._calibrated:
             self.calibrate()
         served: List[Served] = []
         shed: List[Overload] = []
         batches: List[tuple] = []
+        acks: List[WriteAck] = []
         t_start = self.clock.now()
         while True:
+            if pending:
+                self._apply_writes(pending, acks)
             now = self.clock.now()
             for req in workload.take_due(now):
                 self._ingest(req, now, workload, served, shed)
@@ -317,11 +373,30 @@ class PPRService:
             admission_stats=self.admission.stats(),
             batcher_stats=self.batcher.stats(),
             degrade_stats=degrade_stats,
+            writes=acks,
         )
 
     # ------------------------------------------------------------------ #
     # stages
     # ------------------------------------------------------------------ #
+    def _apply_writes(self, pending: collections.deque,
+                      acks: List[WriteAck]):
+        """The write lane: every write due now, in arrival order, each
+        acknowledged once the engine has its layout on the device (the
+        engine blocks on it before ``run`` returns)."""
+        from ..core import DeltaQuery
+
+        while pending and pending[0].t_arrival <= self.clock.now():
+            w = pending.popleft()
+            t_start = self.clock.now()
+            with TraceAnnotation("serve.write"):
+                env = self.engine.run(DeltaQuery(add=w.add, remove=w.remove,
+                                                 rank=False))
+            acks.append(WriteAck(
+                t_arrival=w.t_arrival, t_start=t_start, t_ack=self.clock.now(),
+                graph_version=self.engine.graph_version,
+                relayouts=env.result.relayouts))
+
     def _ingest(self, req: Request, now: float, workload, served, shed):
         with TraceAnnotation("serve.ingest"):
             decision = self.admission.admit(req, now, self.cfg)
@@ -345,6 +420,7 @@ class PPRService:
         time passes on its own under a WallClock)."""
         eng, cfg, _ = self._level_state(0)
         t_dispatch = self.clock.now()
+        version = eng.graph_version
         env = self.executor(eng, np.asarray([req.seed]), self.config.k, cfg)
         t_done = self.clock.now()
         if env is not None:
@@ -363,6 +439,7 @@ class PPRService:
             cache_hit=True,
             indices=indices,
             scores=scores,
+            graph_version=version,
         )
         served.append(s)
         workload.on_complete(req, t_done)
@@ -386,6 +463,7 @@ class PPRService:
             pad = np.full(self.config.batch_size - n_real, sources[-1], dtype=np.int64)
             sources = np.concatenate([sources, pad])
         t_dispatch = self.clock.now()
+        version = eng.graph_version
         t0 = time.perf_counter()
         env = self.executor(eng, sources, self.config.k, cfg)
         wall = time.perf_counter() - t0
@@ -418,6 +496,7 @@ class PPRService:
                     cache_hit=False,
                     indices=indices,
                     scores=scores,
+                    graph_version=version,
                 )
                 served.append(s)
                 workload.on_complete(req, t_done)

@@ -14,10 +14,13 @@ Two standard load shapes drive every serving experiment:
     capacity; with zero think time this is the saturating drain loop the
     old benchmark driver ran.
 
-Both are deterministic functions of an explicit seed: the arrival gaps,
-the Zipf seed stream and the client interleaving all come from one
-``numpy.random.Generator``, so identical seeds give identical request
-streams — the property the drift-checked serving benchmark stands on.
+Beside the reads, :class:`Write` is one timed edge delta for the
+service's write lane.
+
+Both read loads are deterministic functions of an explicit seed: the
+arrival gaps, the Zipf seed stream and the client interleaving all come
+from one ``numpy.random.Generator``, so identical seeds give identical
+request streams — the property the drift-checked serving benchmark stands on.
 
 :func:`zipf_seeds` (moved here from ``launch/ppr_serve.py``) carries the
 determinism contract: the RNG is **required** (no module-global state),
@@ -29,12 +32,13 @@ platform and numpy version.
 from __future__ import annotations
 
 import dataclasses
-from typing import List
+from typing import Any, List
 
 import numpy as np
 
 __all__ = [
     "Request",
+    "Write",
     "OpenLoopWorkload",
     "ClosedLoopWorkload",
     "zipf_seeds",
@@ -247,3 +251,16 @@ class ClosedLoopWorkload:
     @property
     def drained(self) -> bool:
         return self._issued >= self.n_queries and self._inflight == 0
+
+
+@dataclasses.dataclass(frozen=True)
+class Write:
+    """One edge delta offered to the serving tier at ``t_arrival``.
+
+    ``add``/``remove`` are ``(src, dst)`` pairs, as ``DeltaQuery`` takes
+    them.
+    """
+
+    t_arrival: float
+    add: Any = ()
+    remove: Any = ()

@@ -314,6 +314,21 @@ def test_live_refresh_gathers_both_words_with_one_index(live_refresh,
         + [f"f32[2,{C}]"] * 2 + [f"f32[2,{N}]"] * 2)
 
 
+def test_live_donated_batch_loop_compiles(one_chip):
+    """PPR served on the live layout: the donated batched loop reads the
+    graph's degrees, so its only edge-sized arguments are the padded
+    lists, whose shapes no delta changes."""
+    H0 = _struct((B, N), DTYPE, one_chip)
+    lowered = _ita_batch_loop_donated.lower(
+        _degrees(one_chip), _live_runs(one_chip), H0, 0.85, 1e-10,
+        max_iter=10_000, backend=get_step_impl("dense"))
+    args = [(a.shape, a.donated) for a in jax.tree.leaves(lowered.args_info)]
+    assert args[:2] == [((N,), False)] * 2  # out_deg, in_deg
+    assert ((M,), False) not in args         # no Graph's src or dst
+    assert ((B, N), True) in args
+    assert lowered.compile().memory_analysis() is not None
+
+
 def test_live_delta_update_compiles(one_chip):
     """The delta's update of the live layout, in its fixed shapes."""
     def ints(size):
